@@ -10,8 +10,8 @@ applied as annotates, and removal keeps begin/end tag PAIRS consistent
 (removing a begin tag removes its paired end tag and vice versa —
 index.ts:248-270's remove walk). Next to ``richtext.py`` (the
 prosemirror-class binding) this adds the marker-pair machinery and a
-much annotate/marker-heavier op mix, which is exactly what VERDICT r4
-next #9 wants as a second kernel workload generator.
+much annotate/marker-heavier op mix: a second kernel workload
+generator.
 
 Model:
 

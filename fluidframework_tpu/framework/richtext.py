@@ -9,8 +9,7 @@ selection mapped through concurrent inserts and removes), formatting
 as annotations, comments as interval collections, and reconnect
 without losing anything. This module is that binding rebuilt for the
 TPU repo's SharedString, plus a deterministic workload generator so
-the same surface doubles as a merge-kernel stress source (VERDICT r3
-next-round #10).
+the same surface doubles as a merge-kernel stress source.
 
 Model (what a view layer consumes):
 

@@ -3,13 +3,15 @@
 The service plane's hot loops live here; JAX/XLA owns the device
 compute path, C++ owns the host sequencing path (deli ticket —
 SURVEY §3.1 marks it one of the three hot loops). The shared library
-builds on demand with g++ and caches beside the source; every native
+builds on demand with g++ and caches beside the source under a key
+hashed from the source (``build_key``); every native
 component keeps a pure-Python twin as both fallback and differential
 oracle.
 """
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -18,37 +20,62 @@ from typing import Optional
 
 _HERE = Path(__file__).parent
 _SRC = _HERE / "sequencer.cpp"
-_LIB = _HERE / "_sequencer.so"
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _build_error: Optional[str] = None
+# per library: "built" (compiled by this process) or "reused" (a build
+# whose key matches the source was already on disk)
+_build_status: dict[str, str] = {}
+
+_CXX = ["g++", "-O2", "-std=c++17", "-shared", "-fPIC"]
 
 
-def _compile(src: Path, out: Path) -> Optional[str]:
-    """g++ -O2 build with mtime caching; returns an error string or
-    None on success."""
-    if out.exists() and out.stat().st_mtime >= src.stat().st_mtime:
-        return None
-    cmd = [
-        "g++", "-O2", "-std=c++17", "-shared", "-fPIC",
-        str(src), "-o", str(out),
-    ]
+def build_key(src: Path) -> str:
+    """Content key of a native build: the compiler command and the
+    source bytes. A ``.so`` is found only under its key, so one built
+    from other source (a copied tree, a stale checkout) is never
+    loaded."""
+    digest = hashlib.sha256(" ".join(_CXX).encode())
+    digest.update(src.read_bytes())
+    return digest.hexdigest()[:16]
+
+
+def _compile(src: Path, stem: str) -> tuple[Optional[Path], Optional[str]]:
+    """Build ``src`` into ``<stem>.<key>.so`` beside it unless that
+    file exists; returns ``(path, None)`` or ``(None, error)``."""
+    out = src.parent / f"{stem}.{build_key(src)}.so"
+    if out.exists():
+        _build_status[stem] = "reused"
+        return out, None
+    # compile to a private name and rename: concurrent processes (test
+    # workers) never see a half-written library under the keyed name
+    tmp = out.with_name(f"{out.name}.{os.getpid()}.tmp")
     try:
-        proc = subprocess.run(cmd, capture_output=True, text=True)
+        proc = subprocess.run(_CXX + [str(src), "-o", str(tmp)],
+                              capture_output=True, text=True)
     except FileNotFoundError:
-        return "g++ not found"
+        return None, "g++ not found"
     if proc.returncode != 0:
-        return proc.stderr[-2000:]
-    return None
+        return None, proc.stderr[-2000:]
+    os.replace(tmp, out)
+    _build_status[stem] = "built"
+    for stale in src.parent.glob(f"{stem}.*.so"):
+        if stale != out:
+            stale.unlink(missing_ok=True)
+    return out, None
+
+
+def native_build_status() -> dict[str, str]:
+    """Which native libraries this process built or reused."""
+    return dict(_build_status)
 
 
 def _build() -> Optional[Path]:
     global _build_error
-    err = _compile(_SRC, _LIB)
+    path, err = _compile(_SRC, "_sequencer")
     if err is not None:
         _build_error = err
-        return None
-    return _LIB
+    return path
 
 
 def load_native_sequencer() -> Optional[ctypes.CDLL]:
@@ -107,7 +134,6 @@ def native_build_error() -> Optional[str]:
 
 
 _REPLAY_SRC = _HERE / "merge_replay.cpp"
-_REPLAY_LIB = _HERE / "_merge_replay.so"
 _replay_lib: Optional[ctypes.CDLL] = None
 _replay_error: Optional[str] = None
 
@@ -123,12 +149,12 @@ def load_merge_replay() -> Optional[ctypes.CDLL]:
             return None
         if os.environ.get("FFTPU_DISABLE_NATIVE") == "1":
             return None
-        err = _compile(_REPLAY_SRC, _REPLAY_LIB)
+        path, err = _compile(_REPLAY_SRC, "_merge_replay")
         if err is not None:
             _replay_error = err
             return None
         try:
-            lib = ctypes.CDLL(str(_REPLAY_LIB))
+            lib = ctypes.CDLL(str(path))
         except OSError as e:  # truncated/wrong-arch cached build
             _replay_error = f"CDLL load failed: {e}"
             return None
@@ -172,4 +198,5 @@ __all__ = [
     "load_merge_replay",
     "merge_replay_error",
     "native_build_error",
+    "native_build_status",
 ]

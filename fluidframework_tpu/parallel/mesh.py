@@ -52,11 +52,9 @@ def global_window_floor(min_seq, mesh: Mesh):
     """
     import jax.numpy as jnp
 
-    from .seq_shard import shard_map  # top-level/experimental shim
-
     def local(ms):  # [docs_shard] on each device
         return jax.lax.pmin(jnp.min(ms), DOC_AXIS)
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh, in_specs=P(DOC_AXIS), out_specs=P(),
     )(min_seq)

@@ -54,7 +54,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..obs import metrics as obs_metrics
@@ -85,7 +85,6 @@ from ..ops.segment_table import (
 from ..ops.shard_moves import migrate_rows
 from ..qos.faults import KIND_DEFER, PLANE as _CHAOS
 from .mesh import DOC_AXIS
-from .seq_shard import _SHARD_MAP_CHECK_KW, shard_map
 
 # chaos seams (docs/ROBUSTNESS.md), shared by NAME with the seq tier
 # (tpu_sidecar registers the same sites): a deferred pool dispatch
@@ -159,7 +158,7 @@ def _compiled_window(mesh: Mesh, doc_axis: str, field_names: tuple):
         run = shard_map(
             _window_body(), mesh=mesh,
             in_specs=(state_specs, op_spec), out_specs=state_specs,
-            **_SHARD_MAP_CHECK_KW,
+            check_vma=False,
         )
         _compiled_cache[key] = jax.jit(run)
     return _compiled_cache[key]

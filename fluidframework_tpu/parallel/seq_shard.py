@@ -28,38 +28,11 @@ from __future__ import annotations
 
 from typing import Optional, Sequence
 
-import inspect
-
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
-
-try:  # jax >= 0.5 exports it at the top level
-    from jax import shard_map
-except ImportError:  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-def _axis_size(axis: str) -> int:
-    """Static mapped-axis size. ``lax.axis_size`` only exists in newer
-    jax; on 0.4.x the axis frame carries the same static value."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis)
-    from jax import core as _core
-
-    frame = _core.axis_frame(axis)
-    # 0.4.37 returns the size directly; earlier 0.4.x return the
-    # AxisEnvFrame carrying it
-    return getattr(frame, "size", frame)
-
-
-# the replication-check kwarg was renamed check_rep -> check_vma
-_SHARD_MAP_CHECK_KW = (
-    {"check_vma": False}
-    if "check_vma" in inspect.signature(shard_map).parameters
-    else {"check_rep": False}
-)
 
 from ..ops.merge_step import (
     AxisPrims,
@@ -101,7 +74,7 @@ def seq_prims(axis: str = SEQ_AXIS) -> AxisPrims:
         incl = jnp.cumsum(x, axis=-1)
         totals = lax.all_gather(incl[..., -1], axis)      # [n, D]
         i = lax.axis_index(axis)
-        n = _axis_size(axis)
+        n = lax.axis_size(axis)
         k = lax.broadcasted_iota(jnp.int32, (n,), 0)
         offset = jnp.sum(
             jnp.where((k < i)[:, None], totals, 0), axis=0
@@ -111,7 +84,7 @@ def seq_prims(axis: str = SEQ_AXIS) -> AxisPrims:
     def shift_right(arr, k: int):
         # boundary exchange: my left neighbor's last k slots become my
         # first k (shard 0 zero-fills — ppermute drops non-targets)
-        n = _axis_size(axis)
+        n = lax.axis_size(axis)
         recv = lax.ppermute(
             arr[..., arr.shape[-1] - k:], axis,
             [(s, s + 1) for s in range(n - 1)],
@@ -123,7 +96,7 @@ def seq_prims(axis: str = SEQ_AXIS) -> AxisPrims:
         # every field's k-column tail into a single ppermute payload
         # (32-bit fields bitcast to int32), then unstack — the per-op
         # collective count drops from O(fields) to 1 per shift distance
-        n = _axis_size(axis)
+        n = lax.axis_size(axis)
         tails = []
         for a in arrs:
             t = a[..., a.shape[-1] - k:]
@@ -166,7 +139,7 @@ def seq_prims(axis: str = SEQ_AXIS) -> AxisPrims:
         )
 
     def global_capacity(C):
-        return C * _axis_size(axis)
+        return C * lax.axis_size(axis)
 
     return AxisPrims(
         iota_j=iota_j, excl_cumsum=excl_cumsum, shift_right=shift_right,
@@ -210,7 +183,7 @@ def _compiled_window(mesh: Mesh, seq_axis: str,
         run = shard_map(
             _window_body(seq_axis), mesh=mesh,
             in_specs=(state_specs, op_spec), out_specs=state_specs,
-            **_SHARD_MAP_CHECK_KW,
+            check_vma=False,
         )
         _compiled_cache[key] = jax.jit(run)
     return _compiled_cache[key]

@@ -136,18 +136,8 @@ def default_tree_executor() -> str:
         return env
     import jax
 
-    try:
-        backend = jax.default_backend()
-    except RuntimeError as e:  # pragma: no cover - backend init failure
-        import sys
-
-        print(
-            "default_tree_executor: jax backend init failed "
-            f"({e}); routing as cpu",
-            file=sys.stderr,
-        )
-        backend = "cpu"
-    return "macro" if backend == "tpu" else "atom"
+    # a backend that fails to initialise raises, as in default_executor
+    return "macro" if jax.default_backend() == "tpu" else "atom"
 
 
 def _fresh_replica(slot: int) -> EditManager:

@@ -12,6 +12,7 @@ from .compat import (
 )
 from .fuzz import (
     FuzzConfig,
+    record_corpus_stream,
     record_flow_stream,
     record_op_stream,
     record_sequential_stream,
@@ -26,6 +27,7 @@ __all__ = [
     "compat_matrix",
     "downgrade_channel_summary",
     "import_as_fresh_document",
+    "record_corpus_stream",
     "record_flow_stream",
     "record_op_stream",
     "record_sequential_stream",

@@ -99,8 +99,8 @@ def _fuzz_string(rng, s, cid):
     if roll < 0.02:
         # local compaction interleaving: zamboni drops aged
         # tombstones and transfers interval refs; it must never
-        # change the convergence signature (VERDICT r4 next #7 —
-        # intervalCollection.fuzz.spec.ts crosses stickiness with
+        # change the convergence signature
+        # (intervalCollection.fuzz.spec.ts crosses stickiness with
         # compaction)
         s.client.mergetree.zamboni()
         return "zamboni"

@@ -132,8 +132,7 @@ def record_flow_stream(seed: int = 0, n_clients: int = 3,
     """Record a webflow-mix sequenced stream at the merge level — the
     FlowDocument workload's op shape (tag-PAIR markers with pairId
     props, pair-consistent removes, css token-list annotate churn,
-    block tiles) expressed directly as kernel-encodable merge ops
-    (VERDICT r4 next #9: the editor workload joins the bench corpus).
+    block tiles) expressed directly as kernel-encodable merge ops.
     Uses exactly the four property channels the device carries
     (class/tag/pairId/heading). Returns (converged_text, stream)."""
     from ..framework.flowdoc import (
@@ -210,3 +209,19 @@ def record_flow_stream(seed: int = 0, n_clients: int = 3,
                            MARKER_LINEBREAK, None)
     session.process_all()
     return session.assert_converged(), stream
+
+
+def record_corpus_stream(index: int, seed: int, n_clients: int,
+                         n_steps: int):
+    """One stream of the bench corpus: every fourth stream (index % 4
+    == 3) is the webflow editor mix, the rest the generic fuzz mix.
+    ``bench.py`` and ``chip_smoke.py`` both draw from here. Returns
+    (converged_text, stream)."""
+    if index % 4 == 3:
+        return record_flow_stream(seed=seed + index, n_clients=n_clients,
+                                  n_steps=n_steps)
+    return record_op_stream(FuzzConfig(
+        n_clients=n_clients, n_steps=n_steps, seed=seed + index,
+        insert_weight=0.55, remove_weight=0.25,
+        annotate_weight=0.05, process_weight=0.15,
+    ))

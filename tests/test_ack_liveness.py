@@ -1,5 +1,5 @@
 """Submit->ack liveness over TCP (the round-5 ~1-in-3 whiteboard
-stall, VERDICT r5 headline #2).
+stall).
 
 Root cause, reproduced deterministically here: the driver used to send
 each op of a runtime batch as its own submitOp frame. Two sessions'

@@ -1,4 +1,4 @@
-"""bench.py run-status contract (VERDICT r4 next #8): a correctness-
+"""bench.py run-status contract: a correctness-
 stage failure must poison the run — top-level flag + nonzero exit —
 never hide in `failures` under rc 0."""
 import json
@@ -38,3 +38,41 @@ def test_seeded_fuzz_failure_flips_run_status():
     assert rc != 0
     assert out["correctness_failed"] is True
     assert "fuzz" in out["correctness_failures"]
+
+
+def test_chip_stage_failure_fails_the_run(monkeypatch, capsys):
+    """Outside --smoke a stage runs once, on the TPU: when it fails,
+    the run exits non-zero and reports no numbers for it — there is no
+    retry on the CPU."""
+    sys.path.insert(0, REPO)
+    import bench
+
+    calls = []
+
+    def failing_spawn(stage, backend, scale, reps, cooldown, timeout):
+        calls.append((stage, backend, scale))
+        return None, "rc=1: RuntimeError: no TPU"
+
+    monkeypatch.setattr(bench, "_spawn", failing_spawn)
+    with pytest.raises(SystemExit) as exc:
+        bench.main(["--stages", "config2,config7"])
+    assert exc.value.code == 1
+    out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert calls == [("config2", "tpu", "full"),
+                     ("config7", "tpu", "full")]
+    assert out["detail"]["stages"] == {}
+    assert out["stage_failures"] == ["config2", "config7"]
+    assert out["value"] == 0
+
+
+def test_tpu_stage_refuses_the_cpu():
+    """A stage child asked for the TPU fails when JAX finds the CPU."""
+    proc = subprocess.run(
+        [sys.executable, "bench.py", "--stage", "probe",
+         "--backend", "tpu"],
+        cwd=REPO, env=dict(os.environ, JAX_PLATFORMS="cpu"),
+        capture_output=True, text=True, timeout=120,
+    )
+    assert proc.returncode != 0
+    assert "does not fall back" in proc.stderr
+    assert proc.stdout == ""

@@ -39,7 +39,7 @@ def test_dds_fuzz_heavy_churn(channel_type):
 
 @pytest.mark.parametrize("seed", range(8))
 def test_sticky_intervals_fuzz_with_zamboni(seed):
-    """Stickiness x compaction x churn (VERDICT r4 next #7, mirrors
+    """Stickiness x compaction x churn (mirrors
     intervalCollection.fuzz.spec.ts): randomized endpoint stickiness
     rides the string mix, with in-run zamboni interleavings; the
     convergence signature includes resolved sticky endpoints, so any

@@ -227,7 +227,7 @@ def _run_worker(port, client_id, action):
 
 @pytest.mark.slow
 def test_service_survives_kill_and_restart(tmp_path):
-    """VERDICT r3 #5 done-criterion: the service resumes from durable
+    """The service resumes from durable
     state across a process restart (SIGKILL, no graceful shutdown)."""
     import re
 

@@ -1,5 +1,5 @@
-"""FlowDocument binding (the webflow-class example layer, VERDICT r4
-next #9): nested tag-pair markers, pair-consistent removal, css
+"""FlowDocument binding (the webflow-class example layer):
+nested tag-pair markers, pair-consistent removal, css
 token-list annotates, line breaks, comments — and the heavy
 marker/annotate workload that doubles as a kernel stress source.
 

@@ -582,7 +582,7 @@ def test_zamboni_preserves_endpoints_matrix(stickiness, removal):
     """Stickiness x anchor-removal x compaction: once an endpoint's
     anchor char is removed and the interval has settled, running
     zamboni (which drops the tombstone the ref sits on) must not move
-    either endpoint (VERDICT r4 next #2: full matrix, sequenced)."""
+    either endpoint."""
     from fluidframework_tpu.models.intervals import IntervalCollection
 
     s, clients = _mock_session(2)

@@ -1,5 +1,5 @@
-"""SharedMatrix batched path vs the scalar model (VERDICT r1 missing
-#5 / BASELINE config #3): two merge-kernel axes in one dispatch +
+"""SharedMatrix batched path vs the scalar model (BASELINE
+config #3): two merge-kernel axes in one dispatch +
 vectorized cell scatter must reproduce the converged to_lists() of the
 live SharedMatrix replicas."""
 import dataclasses

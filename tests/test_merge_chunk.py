@@ -330,7 +330,7 @@ def test_mid_chunk_tombstone_aging_breaks_chunk():
 def test_regression_seed_90007():
     """Driver-caught r4 divergence: 120-step stream whose min_seq
     advance mid-chunk aged a committed tombstone between two
-    same-position inserts (BENCH_r04 fuzz failure)."""
+    same-position inserts (found by the bench's fuzz stage)."""
     _, stream = record_op_stream(FuzzConfig(
         n_clients=4, n_steps=120, seed=90007,
         insert_weight=0.5, remove_weight=0.3,

@@ -3,7 +3,7 @@ branch/commit graph over the framed-TCP MH service.
 
 Mirrors server/routerlicious/packages/lambdas/src/moira/lambda.ts
 (handler/sendPending/processMoiraCore/createBranch/createCommit) and
-closes the last §2.7 service-inventory row (VERDICT r4 next #6).
+closes the last §2.7 service-inventory row.
 """
 import asyncio
 import os

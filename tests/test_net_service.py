@@ -132,7 +132,7 @@ def test_read_ops_and_nack_over_tcp(server):
 
 
 def test_multi_process_stress_converges():
-    """VERDICT r3 done-criterion: multiple OS processes over real
+    """Multiple OS processes over real
     sockets converge through the runnable dev service."""
     from fluidframework_tpu.tools.net_stress import run_net_stress
 

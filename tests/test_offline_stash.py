@@ -126,7 +126,7 @@ def test_stash_against_newer_summary_fails_clearly():
 
 
 def test_every_channel_type_survives_stash_cycle():
-    """VERDICT r3 weak #10: every shipped channel must rehydrate from
+    """Every shipped channel must rehydrate from
     an offline stash (apply_stashed_op), or offline sessions die on
     that channel. Drives each type through edit-offline -> stash ->
     rehydrate -> resubmit -> converge with a second client.

@@ -1,5 +1,4 @@
-"""Sidecar overflow recovery + insert-props kernel fidelity
-(VERDICT r1 weak #4/#5).
+"""Sidecar overflow recovery + insert-props kernel fidelity.
 
 A document that outgrows its device slab or exceeds the interned
 property channels must never be silently wrong: the sidecar regrows

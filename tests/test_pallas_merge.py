@@ -121,3 +121,15 @@ def test_pallas_interpret_doc_padding_path():
         np.testing.assert_array_equal(
             got_np[f], ref_np[f], err_msg=f"field {f}"
         )
+
+
+@pytest.mark.parametrize("capacity", [64, 128])
+def test_pallas_opt_in_it_cannot_serve_raises(monkeypatch, capacity):
+    """FFTPU_PALLAS=1 with a capacity off the 128 grid, or on a backend
+    without Mosaic, fails loudly instead of quietly taking the scan."""
+    from fluidframework_tpu.ops.merge_kernel import apply_window
+
+    monkeypatch.setenv("FFTPU_PALLAS", "1")
+    batch = _fuzz_batch(2, 7, steps=6)
+    with pytest.raises(ValueError, match="FFTPU_PALLAS=1"):
+        apply_window(make_table(2, capacity), batch)

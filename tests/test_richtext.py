@@ -1,5 +1,5 @@
-"""RichTextEditor binding (the prosemirror-class example layer,
-VERDICT r3 next-round #10): paragraphs/marks/comments/cursors over
+"""RichTextEditor binding (the prosemirror-class example
+layer): paragraphs/marks/comments/cursors over
 SharedString, concurrent editing convergence, cursor stability through
 remote edits, and the fuzz workload."""
 import random

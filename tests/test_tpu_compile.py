@@ -1,0 +1,174 @@
+"""Compile the main path's kernels for a described TPU v5e, with no chip.
+
+The TPU compiler is installed here and compiles for a topology that is
+described, not attached: what it refuses here (a layout, a VMEM budget,
+a kernel that cannot be partitioned) would be refused on the chip. A
+passing compile says nothing about results or times.
+
+The topology is described inside a module-scoped fixture, never at
+import: only one process at a time may load the TPU library, and every
+test worker imports this file. Keep these tests in this one file.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
+
+from fluidframework_tpu.ops import make_table
+from fluidframework_tpu.ops.host_bridge import pack_rows
+from fluidframework_tpu.ops.segment_table import KIND_NOOP, OpBatch
+
+NOOP = dict(kind=KIND_NOOP, pos1=0, pos2=0, seq=0, refseq=0, client=0,
+            op_id=0, length=0, is_marker=0, prop_key=0, prop_val=0,
+            min_seq=0)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    log_dir = os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    cache_was = jax.config.jax_enable_compilation_cache
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep the cache out of it
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield topologies.get_topology_desc(platform="tpu",
+                                           topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means no topology
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+        compilation_cache.reset_cache()
+        if log_dir == "disabled":
+            os.environ.pop("TPU_LOG_DIR", None)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def quad(topo):
+    return list(topo.devices)[:4]
+
+
+def _shapes(tree, sharding):
+    """Shape stand-ins of ``tree``'s leaves, placed by ``sharding``
+    (a Sharding, or a callable from leaf to Sharding)."""
+    def one(a):
+        s = sharding(a) if callable(sharding) else sharding
+        return jax.ShapeDtypeStruct(np.shape(a), a.dtype, sharding=s)
+
+    return jax.tree_util.tree_map(one, tree)
+
+
+def _window(docs, capacity, bucket):
+    table = jax.eval_shape(lambda: make_table(docs, capacity))
+    arrays = pack_rows(docs, {0: [NOOP]}, bucket_floor=bucket)
+    return table, arrays
+
+
+def test_scan_window_compiles_at_serving_size(one_chip):
+    from fluidframework_tpu.ops.merge_kernel import apply_window_impl
+
+    table, arrays = _window(1024, 1024, 64)
+    batch = OpBatch(**{f: arrays[f] for f in OpBatch._fields})
+    compiled = jax.jit(apply_window_impl).lower(
+        _shapes(table, one_chip), _shapes(batch, one_chip)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes > 1024 * 1024 * 4 * 12
+
+
+def test_pallas_window_compiles_to_a_mosaic_kernel(one_chip):
+    from fluidframework_tpu.ops.pallas_merge import apply_window_pallas
+
+    table, arrays = _window(1024, 1024, 16)
+    batch = OpBatch(**{f: arrays[f] for f in OpBatch._fields})
+    compiled = jax.jit(apply_window_pallas).lower(
+        _shapes(table, one_chip), _shapes(batch, one_chip)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("route", ["chunked", "egwalker"])
+def test_macro_step_routes_compile(one_chip, route):
+    """The smallest size that shows the macro-step lowering; at serving
+    size chunked takes over a minute (chip_smoke.py reports it)."""
+    from fluidframework_tpu.ops import event_graph, merge_chunk
+
+    table = make_table(16, 256)
+    arrays = pack_rows(16, {0: [NOOP]}, bucket_floor=16)
+    if route == "chunked":
+        jitted, args = merge_chunk.compiled_window(
+            table, merge_chunk.compile_chunks(
+                arrays, k_max=merge_chunk.CHUNK_K),
+            K=merge_chunk.CHUNK_K)
+    else:
+        jitted, args = event_graph.compiled_window(
+            table, event_graph.build_event_graph(
+                arrays, k_max=event_graph.EG_K)["prefix"])
+    jitted.lower(*_shapes(args, one_chip)).compile()
+
+
+def _pool_args(docs, capacity, bucket, mesh, state_spec, op_spec):
+    from fluidframework_tpu.ops.merge_step import (
+        batch_to_window,
+        table_to_state,
+    )
+
+    table, arrays = _window(docs, capacity, bucket)
+    batch = OpBatch(**{f: jnp.asarray(arrays[f])
+                       for f in OpBatch._fields})
+    st = jax.eval_shape(table_to_state, table)
+    ops = jax.eval_shape(batch_to_window, batch)
+    return (
+        _shapes(st, lambda a: NamedSharding(mesh, state_spec(a))),
+        _shapes(ops, NamedSharding(mesh, op_spec)),
+    ), st
+
+
+def test_mesh_pool_program_compiles_on_four_chips(quad):
+    from fluidframework_tpu.parallel.mesh import DOC_AXIS
+    from fluidframework_tpu.parallel.mesh_pool import _compiled_window
+
+    mesh = Mesh(np.array(quad), (DOC_AXIS,))
+    args, st = _pool_args(8, 4096, 16, mesh,
+                          lambda a: P(DOC_AXIS, None),
+                          P(None, DOC_AXIS, None))
+    compiled = _compiled_window(mesh, DOC_AXIS, tuple(sorted(st))) \
+        .lower(*args).compile()
+    # documents never read across the docs axis: no collectives
+    hlo = compiled.as_text()
+    assert "all-gather" not in hlo and "collective-permute" not in hlo
+
+
+def test_seq_pool_program_compiles_with_its_collectives(quad):
+    from fluidframework_tpu.ops.merge_step import DOC_FIELDS
+    from fluidframework_tpu.parallel.seq_shard import (
+        SEQ_AXIS,
+        _compiled_window,
+    )
+
+    mesh = Mesh(np.array(quad).reshape(1, 4), ("docs", SEQ_AXIS))
+    args, st = _pool_args(
+        4, 4096, 16, mesh,
+        lambda a: P("docs", None) if a.shape[-1] == 1
+        else P("docs", SEQ_AXIS),
+        P(None, "docs", None))
+    assert {f for f, a in st.items() if a.shape[-1] == 1} == \
+        set(DOC_FIELDS)
+    compiled = _compiled_window(mesh, SEQ_AXIS, "docs",
+                                tuple(sorted(st))).lower(*args).compile()
+    hlo = compiled.as_text()
+    # the cross-shard reductions (prefix-sum offsets, psum/pmin
+    # lookups; the compiler lowers the totals all_gather to an
+    # all-reduce too) and the ppermute boundary exchange
+    assert "all-reduce" in hlo
+    assert "collective-permute" in hlo

@@ -1,5 +1,4 @@
-"""Register field kind (modular-schema value/optional — VERDICT r3
-missing #2): per-kind compose/invert/rebase, cross-kind changesets,
+"""Register field kind (modular-schema value/optional): per-kind compose/invert/rebase, cross-kind changesets,
 algebra laws fuzzed per kind and mixed, and DDS-level LWW convergence
 (two clients filling one optional field merge to ONE winner)."""
 import copy
