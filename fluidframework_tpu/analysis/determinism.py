@@ -154,11 +154,14 @@ WALL_CLOCK_SINKS: dict[tuple[str, str], str] = {
     ("drivers/caching_driver.py",
      "CachingDocumentService.get_latest_summary"):
         "cache age check against max_age_s is wall-clock by design",
+    ("service/tpu_sidecar.py", "TpuMergeSidecar.apply"):
+        "stats compact_s counter (obs only; never feeds ordering)",
     ("service/tpu_sidecar.py", "TpuMergeSidecar.prewarm"):
         "prewarm returns measured warmup wall seconds (obs only)",
     ("service/tpu_sidecar.py", "TpuMergeSidecar._dispatch"):
-        "pack_ms histogram + sidecar:pack trace timestamp (obs only; "
-        "never feeds ordering)",
+        "pack_ms histogram, stats pack_s/graph_s/enqueue_s counters + "
+        "sidecar:pack trace timestamp (obs only; never feeds "
+        "ordering)",
     ("service/tpu_sidecar.py", "TpuMergeSidecar._settle"):
         "settle_ms histogram + sidecar:settle trace timestamp (obs "
         "only; never feeds ordering)",

@@ -18,7 +18,7 @@ Pillars (docs/OBSERVABILITY.md):
   graded with multi-window burn rates; breach dumps the recorders.
 - ``obs.profiler`` — always-on sampling host profiler with
   per-component (thread-name) attribution + opt-in jax device-trace
-  hooks.
+  hooks (``set_device_trace``).
 - ``obs.spans`` — the hop tables as OTLP-JSON span trees for
   standard trace viewers.
 - ``obs.federation`` — the FLEET half of the registry: merge leader /
@@ -40,7 +40,7 @@ import weakref
 from .federation import FederatedView
 from .flight_recorder import FlightRecorder
 from .metrics import REGISTRY, MetricsRegistry, get_registry
-from .profiler import ContinuousProfiler, device_trace
+from .profiler import ContinuousProfiler, device_trace, set_device_trace
 from .slo import Objective, SloEngine
 from .spans import (
     FileSpanExporter,
@@ -64,8 +64,8 @@ __all__ = [
     "MetricsRegistry", "Objective", "REGISTRY", "SloEngine",
     "TIMELINE_KINDS", "breakdown", "device_trace",
     "format_breakdown", "get_registry", "hop_name", "op_to_otlp",
-    "otlp_to_hops", "register_closeable", "shutdown", "stamp",
-    "timeline_to_otlp", "total_ms",
+    "otlp_to_hops", "register_closeable", "set_device_trace",
+    "shutdown", "stamp", "timeline_to_otlp", "total_ms",
 ]
 
 # ----------------------------------------------------------------------

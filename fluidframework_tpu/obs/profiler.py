@@ -21,17 +21,17 @@ second it spends sampling against the wall clock it ran for
 harness (tools/serve_bench.py / bench config9) pins the end-to-end
 cost under 2% by timing the same run with the profiler on and off.
 
-The device half is opt-in (``FFTPU_DEVICE_TRACE=1``):
-:func:`device_trace` annotates the sidecar's dispatch window with a
-``jax.profiler`` trace annotation so an XLA/TensorBoard trace shows
-serving rounds by name, and :func:`start_device_trace` /
-:func:`stop_device_trace` wrap the full device tracer. All hooks
-no-op (and import nothing) when the env var is unset — profiling
-must never add a host<->device sync or an import tax to the
-dispatch loop.
+The device half is opt-in (:func:`set_device_trace` or
+``FFTPU_DEVICE_TRACE=1``): :func:`device_trace` wraps a round stage of
+the sidecar in a ``jax.profiler.TraceAnnotation`` so a profiler trace
+shows serving rounds by stage and round on the device planes' clock.
+Whoever starts the trace (``jax.profiler.start_trace``) owns it; the
+hooks only annotate. Switched off they import nothing — profiling must
+never add a host<->device sync or an import tax to the dispatch loop.
 """
 from __future__ import annotations
 
+import functools
 import os
 import sys
 import threading
@@ -253,18 +253,72 @@ class ContinuousProfiler:
 
 
 # ======================================================================
+# always-on self-time counters for per-op methods
+
+
+# One call in this many is timed: two clock reads around every call
+# cost about a tenth of a ticket on a TPU v5e host; a count per call and
+# a timed sample keep the counters near a hundredth.
+SELF_TIME_EVERY = 16
+
+
+def self_timed(seconds_key: str, calls_key: str):
+    """Decorate a method of two positional arguments (the per-op
+    ``DocumentSequencer.ticket`` and ``TpuMergeSidecar.ingest``) with
+    always-on counters in ``self.stats``: every call adds one to
+    ``calls_key``; one call in ``SELF_TIME_EVERY`` is timed, and its
+    host seconds times ``SELF_TIME_EVERY`` go to ``seconds_key``. So
+    ``seconds / calls`` over a window estimates the mean self time per
+    call, and ``seconds`` the total. Plain adds, no lock, no registry
+    call. Observability only: nothing the method orders or returns
+    reads the counters."""
+    every = SELF_TIME_EVERY
+
+    def wrap(method):
+        @functools.wraps(method)
+        def timed(self, a, b):
+            stats = self.stats
+            n = stats[calls_key] = stats[calls_key] + 1
+            if n % every:
+                return method(self, a, b)
+            t0 = time.perf_counter()
+            try:
+                return method(self, a, b)
+            finally:
+                stats[seconds_key] += (time.perf_counter() - t0) * every
+        return timed
+    return wrap
+
+
+# ======================================================================
 # device-trace hooks (opt-in; never on the dispatch path by default)
 
+DEVICE_TRACE_ENV = "FFTPU_DEVICE_TRACE"
+_device_trace_on = False
+
+
+def set_device_trace(on: bool) -> None:
+    """Switch the round annotations on or off for this process, with no
+    need for the process environment (``FFTPU_DEVICE_TRACE=1`` still
+    switches them on as well)."""
+    global _device_trace_on
+    _device_trace_on = bool(on)
+
+
 def device_trace_enabled() -> bool:
-    return os.environ.get("FFTPU_DEVICE_TRACE") == "1"
+    """The switch: the module flag or ``FFTPU_DEVICE_TRACE=1``. The
+    sidecar reads it once per ``apply``/``sync`` and hands each stage's
+    span that one boolean."""
+    return _device_trace_on or os.environ.get(DEVICE_TRACE_ENV) == "1"
 
 
 @contextmanager
 def device_trace(name: str):
-    """Annotate a device-dispatch window in the jax profiler trace.
-    No-op (no jax import either) unless FFTPU_DEVICE_TRACE=1 — the
-    sidecar wraps every dispatch in this, so the disabled path costs
-    one env lookup per ms-scale round, nothing more."""
+    """Annotate a host span (a round stage) in the jax profiler trace.
+    Off, it is one read of the switch and imports nothing; on, it
+    forces no host<->device sync either. A loop that opens several
+    spans per round reads the switch once and skips this call when it
+    is off (``TpuMergeSidecar._span``)."""
     if not device_trace_enabled():
         yield
         return
@@ -275,30 +329,3 @@ def device_trace(name: str):
         return
     with TraceAnnotation(name):
         yield
-
-
-def start_device_trace(logdir: str) -> bool:
-    """Start the full jax device tracer writing to ``logdir``
-    (TensorBoard-loadable). Returns False when disabled/unavailable
-    instead of raising — tracing is an observer, never a fault."""
-    if not device_trace_enabled():
-        return False
-    try:
-        import jax
-
-        jax.profiler.start_trace(logdir)
-        return True
-    except Exception:  # noqa: BLE001 - see above
-        return False
-
-
-def stop_device_trace() -> bool:
-    if not device_trace_enabled():
-        return False
-    try:
-        import jax
-
-        jax.profiler.stop_trace()
-        return True
-    except Exception:  # noqa: BLE001 - see above
-        return False

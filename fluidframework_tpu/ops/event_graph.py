@@ -326,12 +326,38 @@ def _compile_span_row(out, chunk_start, pred, ev_cover, span_splits,
         ms_global = max(ms_global, int(out["min_seq"][d, w]))
 
 
+def walker_steps(chunk_start: np.ndarray, K: int) -> int:
+    """Trip count of the walker's ``while`` loop over a prefix program,
+    from the host's ``chunk_start`` alone (no device read).
+
+    The device's take rule (``_walker_step`` over ``_gather_ops``):
+    from a row's cursor, take up to the first lane k >= 1 that starts
+    a span, else K lanes; lanes past the window count as span starts.
+    So a span of L lanes (from one start to the next, or to the
+    window's end) costs its row ceil(L / K) steps, and the loop runs
+    until the row with the most steps has passed the window. Exact
+    unless a document overflows: an overflowed row parks at once, so
+    the count is then an upper bound."""
+    D, P = chunk_start.shape
+    if D == 0 or P == 0:
+        return 0
+    starts = chunk_start > 0
+    starts[:, 0] = True                 # every cursor starts at lane 0
+    rows, cols = np.nonzero(starts)     # row-major: cols ascend per row
+    ends = np.append(cols[1:], P)
+    ends[np.append(rows[1:] != rows[:-1], True)] = P
+    steps = np.zeros(D, np.int64)
+    np.add.at(steps, rows, -(-(ends - cols) // K))
+    return int(steps.max())
+
+
 def build_event_graph(arrays: dict, base_head=None, k_max: int = EG_K,
                       window_floor: int = 16) -> dict:
     """[D, W] OpBatch field arrays -> the egwalker dispatch program.
 
     Returns ``{"egwalker": True, "k": k_max, "prefix": ..., "suffix":
-    ..., "graph": EventGraph}``: ``prefix`` holds every document's
+    ..., "graph": EventGraph, "span_splits": ..., "walker_steps":
+    ...}``: ``prefix`` holds every document's
     critical prefix (positions rewritten to span-base coordinates +
     CHUNK_FIELDS, window pow2-bucketed through the BucketLadder so
     compile counts stay laddered), ``suffix`` the raw remainder from
@@ -339,7 +365,9 @@ def build_event_graph(arrays: dict, base_head=None, k_max: int = EG_K,
     None when every op is critical — the common case). ``base_head``
     [D] is the max sequence number already applied per row (0 /
     omitted = a fresh table); it only gates the criticality of ops
-    whose refseq predates the window, conservatively.
+    whose refseq predates the window, conservatively. ``walker_steps``
+    is the prefix walker's ``while`` trip count (:func:`walker_steps`;
+    0 without a prefix).
     """
     assert 1 <= k_max <= 31
     kind = np.array(arrays["kind"], np.int32)
@@ -366,7 +394,7 @@ def build_event_graph(arrays: dict, base_head=None, k_max: int = EG_K,
     span_splits = np.zeros(D, np.int32)
     program: dict = {"egwalker": True, "k": k_max, "graph": graph,
                      "prefix": None, "suffix": None,
-                     "span_splits": span_splits}
+                     "span_splits": span_splits, "walker_steps": 0}
     max_p = int(prefix_len.max()) if D else 0
     if max_p > 0:
         P = ladder.window_bucket(max_p)
@@ -393,6 +421,7 @@ def build_event_graph(arrays: dict, base_head=None, k_max: int = EG_K,
         pref["pred"] = pred
         pref["ev_cover"] = ev_cover
         program["prefix"] = pref
+        program["walker_steps"] = walker_steps(chunk_start, k_max)
 
     suf_len = (W - prefix_len).astype(np.int64)
     max_s = int(suf_len.max()) if D else 0
@@ -438,401 +467,409 @@ def _walker_step(st: dict, ops: dict, K: int):
     D, C = st["length"].shape
     kidx = jnp.arange(K, dtype=jnp.int32)[None]            # [1,K]
 
-    # ---- take: ops before the next span boundary --------------------
-    take_upto = jnp.min(
-        jnp.where((ops["chunk_start"] > 0) & (kidx > 0), kidx, K),
-        axis=-1,
-    )                                                      # [D]
-    taken = kidx < take_upto[:, None]                      # [D,K]
-    kind = jnp.where(taken, ops["kind"], KIND_NOOP)
-    is_ins = kind == KIND_INSERT
-    is_rem = kind == KIND_REMOVE
-    is_ann = kind == KIND_ANNOTATE
-    is_range = is_rem | is_ann
+    with jax.named_scope("walker_take"):
+        # ---- take: ops before the next span boundary --------------------
+        take_upto = jnp.min(
+            jnp.where((ops["chunk_start"] > 0) & (kidx > 0), kidx, K),
+            axis=-1,
+        )                                                      # [D]
+        taken = kidx < take_upto[:, None]                      # [D,K]
+        kind = jnp.where(taken, ops["kind"], KIND_NOOP)
+        is_ins = kind == KIND_INSERT
+        is_rem = kind == KIND_REMOVE
+        is_ann = kind == KIND_ANNOTATE
+        is_range = is_rem | is_ann
 
-    # ---- ONE shared view pass over S0 (the critical fast path) ------
-    # Every op in a critical span has seen every seq in S0, so its
-    # view is the full-visibility view: all inserts visible, all
-    # removals visible => vis = alive & ~removed, identical across
-    # lanes. One [D, C] pass + one cumsum replaces the chunked
-    # executor's per-lane [D, K, C] view stack. `stop` (insert
-    # tie-break eligibility) is the ONLY lane-dependent mask: a lane's
-    # `below` watermark is the exclusive running max of earlier taken
-    # lanes' min_seq (the chunked step's ms_pre cummax — the
-    # sequential executor applies an op's min_seq AFTER its view
-    # pass), so a committed tombstone aging MID-SPAN resolves exactly
-    # instead of forcing a span break (the event-splitting win). The
-    # mask stays a 1-byte bool [D, K, C]; E/vis stay shared [D, C].
-    j = lax.broadcasted_iota(jnp.int32, (D, C), 1)
-    count = st["count"][:, None]                           # [D,1]
-    alive = j < count
-    removed = st["removed_seq"] != NOT_REMOVED
-    ms0 = st["min_seq"][:, None]
-    inc_ms = lax.cummax(
-        jnp.where(taken, ops["min_seq"], 0), axis=1
-    )
-    ms_pre = jnp.maximum(
-        ms0, jnp.concatenate(
-            [jnp.zeros((D, 1), jnp.int32), inc_ms[:, :-1]], axis=1
+    with jax.named_scope("walker_view"):
+        # ---- ONE shared view pass over S0 (the critical fast path) ------
+        # Every op in a critical span has seen every seq in S0, so its
+        # view is the full-visibility view: all inserts visible, all
+        # removals visible => vis = alive & ~removed, identical across
+        # lanes. One [D, C] pass + one cumsum replaces the chunked
+        # executor's per-lane [D, K, C] view stack. `stop` (insert
+        # tie-break eligibility) is the ONLY lane-dependent mask: a lane's
+        # `below` watermark is the exclusive running max of earlier taken
+        # lanes' min_seq (the chunked step's ms_pre cummax — the
+        # sequential executor applies an op's min_seq AFTER its view
+        # pass), so a committed tombstone aging MID-SPAN resolves exactly
+        # instead of forcing a span break (the event-splitting win). The
+        # mask stays a 1-byte bool [D, K, C]; E/vis stay shared [D, C].
+        j = lax.broadcasted_iota(jnp.int32, (D, C), 1)
+        count = st["count"][:, None]                           # [D,1]
+        alive = j < count
+        removed = st["removed_seq"] != NOT_REMOVED
+        ms0 = st["min_seq"][:, None]
+        inc_ms = lax.cummax(
+            jnp.where(taken, ops["min_seq"], 0), axis=1
         )
-    )                                                      # [D,K]
-    below_lane = removed[:, None, :] & (
-        st["removed_seq"][:, None, :] <= ms_pre[..., None]
-    )                                                      # [D,K,C]
-    vis = alive & ~removed
-    stop3 = alive[:, None, :] & ~below_lane
-    vlen = jnp.where(vis, st["length"], 0)                 # [D,C]
-    E = jnp.cumsum(vlen, axis=-1) - vlen
-    incl = E + vlen
-    total = incl[:, -1]                                    # [D]
+        ms_pre = jnp.maximum(
+            ms0, jnp.concatenate(
+                [jnp.zeros((D, 1), jnp.int32), inc_ms[:, :-1]], axis=1
+            )
+        )                                                      # [D,K]
+        below_lane = removed[:, None, :] & (
+            st["removed_seq"][:, None, :] <= ms_pre[..., None]
+        )                                                      # [D,K,C]
+        vis = alive & ~removed
+        stop3 = alive[:, None, :] & ~below_lane
+        vlen = jnp.where(vis, st["length"], 0)                 # [D,C]
+        E = jnp.cumsum(vlen, axis=-1) - vlen
+        incl = E + vlen
+        total = incl[:, -1]                                    # [D]
 
-    # ---- batched resolve of all K lanes against the shared view -----
-    # All searches run on BOOLEAN [D, K, C] masks reduced by argmax
-    # (first-True index — exactly the chunked step's masked min-index,
-    # since XLA argmax breaks ties toward the lowest index) and the
-    # values at the found index come back through [D, K] gathers on
-    # the shared [D, C] prefix sums. The chunked step materializes
-    # int32 [D, K, C] `where` operands for every one of these; here
-    # the wide intermediates stay 1-byte bools.
-    E3 = E[:, None, :]                                     # [D,1,C]
-    incl3 = incl[:, None, :]
-    p1 = ops["pos1"][..., None]                            # [D,K,1]
-    p2 = ops["pos2"][..., None]
+        # ---- batched resolve of all K lanes against the shared view -----
+        # All searches run on BOOLEAN [D, K, C] masks reduced by argmax
+        # (first-True index — exactly the chunked step's masked min-index,
+        # since XLA argmax breaks ties toward the lowest index) and the
+        # values at the found index come back through [D, K] gathers on
+        # the shared [D, C] prefix sums. The chunked step materializes
+        # int32 [D, K, C] `where` operands for every one of these; here
+        # the wide intermediates stay 1-byte bools.
+        E3 = E[:, None, :]                                     # [D,1,C]
+        incl3 = incl[:, None, :]
+        p1 = ops["pos1"][..., None]                            # [D,K,1]
+        p2 = ops["pos2"][..., None]
 
-    def first_true(mask, default):
-        """[D,K,C] bool -> ([D,K] first-True index or default, any)."""
-        any_ = jnp.any(mask, axis=-1)
-        idx = jnp.argmax(mask, axis=-1).astype(jnp.int32)
-        return jnp.where(any_, idx, default), any_
+        def first_true(mask, default):
+            """[D,K,C] bool -> ([D,K] first-True index or default, any)."""
+            any_ = jnp.any(mask, axis=-1)
+            idx = jnp.argmax(mask, axis=-1).astype(jnp.int32)
+            return jnp.where(any_, idx, default), any_
 
-    def e_at(idx):
-        """E[d, idx[d,k]] — callers gate on the found flag."""
-        return jnp.take_along_axis(
-            E, jnp.minimum(idx, C - 1), axis=1)
+        def e_at(idx):
+            """E[d, idx[d,k]] — callers gate on the found flag."""
+            return jnp.take_along_axis(
+                E, jnp.minimum(idx, C - 1), axis=1)
 
-    inside = stop3 & (E3 <= p1) & (p1 < incl3)
-    target = inside | (stop3 & (E3 == p1))
-    idx_t, t_any = first_true(target, count)
-    E_t = e_at(idx_t)
-    t_found = t_any & (idx_t < count)
-    valid_ins = is_ins & (ops["pos1"] <= total[:, None])
-    a_slot = jnp.where(t_found, idx_t, count)              # [D,K]
-    a_off = jnp.where(t_found, ops["pos1"] - E_t, 0)
+        inside = stop3 & (E3 <= p1) & (p1 < incl3)
+        target = inside | (stop3 & (E3 == p1))
+        idx_t, t_any = first_true(target, count)
+        E_t = e_at(idx_t)
+        t_found = t_any & (idx_t < count)
+        valid_ins = is_ins & (ops["pos1"] <= total[:, None])
+        a_slot = jnp.where(t_found, idx_t, count)              # [D,K]
+        a_off = jnp.where(t_found, ops["pos1"] - E_t, 0)
 
-    strict1 = (E3 < p1) & (p1 < incl3)
-    i1, s1 = first_true(strict1, C)
-    E1 = e_at(i1)
-    strict2 = (E3 < p2) & (p2 < incl3)
-    i2, s2 = first_true(strict2, C)
-    E2 = e_at(i2)
-    # junction fallback: first row with E >= p (count if none)
-    jn1, _ = first_true(E3 >= p1, count)
-    jn2, _ = first_true(E3 >= p2, count)
-    r1s = jnp.where(s1, i1, jn1)
-    r1o = jnp.where(s1, ops["pos1"] - E1, 0)
-    r2s = jnp.where(s2, i2, jn2)
-    r2o = jnp.where(s2, ops["pos2"] - E2, 0)
+        strict1 = (E3 < p1) & (p1 < incl3)
+        i1, s1 = first_true(strict1, C)
+        E1 = e_at(i1)
+        strict2 = (E3 < p2) & (p2 < incl3)
+        i2, s2 = first_true(strict2, C)
+        E2 = e_at(i2)
+        # junction fallback: first row with E >= p (count if none)
+        jn1, _ = first_true(E3 >= p1, count)
+        jn2, _ = first_true(E3 >= p2, count)
+        r1s = jnp.where(s1, i1, jn1)
+        r1o = jnp.where(s1, ops["pos1"] - E1, 0)
+        r2s = jnp.where(s2, i2, jn2)
+        r2o = jnp.where(s2, ops["pos2"] - E2, 0)
 
-    # ---- event ranks: replay the walk's insertion order -------------
-    # (verbatim from the chunked macro-step: pred comes from the
-    # shared span chain instead of per-client chains, so same-anchor
-    # ordering composes across clients)
-    ev_valid = valid_ins & taken
-    rank = jnp.zeros((D, K), jnp.int32)
-    pred = ops["pred"]
-    same_anchor = (
-        (a_slot[:, :, None] == a_slot[:, None, :])
-        & (a_off[:, :, None] == a_off[:, None, :])
-    )                                                      # [D,e,t]
-    for t in range(K):
-        pr = pred[:, t]
-        pr_rank = jnp.where(
-            pr >= 0,
-            jnp.take_along_axis(
-                rank, jnp.maximum(pr, 0)[:, None], axis=1
-            )[:, 0] + 1,
-            0,
-        )                                                  # [D]
-        placing = ev_valid[:, t]
-        bump = (
-            same_anchor[:, :, t]
-            & ev_valid
-            & (jnp.arange(K)[None] < t)
-            & (rank >= pr_rank[:, None])
-            & placing[:, None]
+    with jax.named_scope("walker_rows"):
+        # ---- event ranks: replay the walk's insertion order -------------
+        # (verbatim from the chunked macro-step: pred comes from the
+        # shared span chain instead of per-client chains, so same-anchor
+        # ordering composes across clients)
+        ev_valid = valid_ins & taken
+        rank = jnp.zeros((D, K), jnp.int32)
+        pred = ops["pred"]
+        same_anchor = (
+            (a_slot[:, :, None] == a_slot[:, None, :])
+            & (a_off[:, :, None] == a_off[:, None, :])
+        )                                                      # [D,e,t]
+        for t in range(K):
+            pr = pred[:, t]
+            pr_rank = jnp.where(
+                pr >= 0,
+                jnp.take_along_axis(
+                    rank, jnp.maximum(pr, 0)[:, None], axis=1
+                )[:, 0] + 1,
+                0,
+            )                                                  # [D]
+            placing = ev_valid[:, t]
+            bump = (
+                same_anchor[:, :, t]
+                & ev_valid
+                & (jnp.arange(K)[None] < t)
+                & (rank >= pr_rank[:, None])
+                & placing[:, None]
+            )
+            rank = rank + bump.astype(jnp.int32)
+            rank = rank.at[:, t].set(jnp.where(placing, pr_rank, 0))
+
+        # ---- cuts (strictly-inside anchors) — verbatim ------------------
+        ins_cut = ev_valid & (a_off > 0)
+        r1_cut = is_range & taken & s1 & (r1o > 0)
+        r2_cut = is_range & taken & s2 & (r2o > 0)
+        cut_slot = jnp.concatenate([
+            jnp.where(ins_cut, a_slot, jnp.where(r1_cut, r1s, C)),
+            jnp.where(r2_cut, r2s, C),
+        ], axis=-1)                                            # [D,2K]
+        cut_off = jnp.concatenate([
+            jnp.where(ins_cut, a_off, jnp.where(r1_cut, r1o, 0)),
+            jnp.where(r2_cut, r2o, 0),
+        ], axis=-1)
+        cut_valid = jnp.concatenate(
+            [ins_cut | r1_cut, r2_cut], axis=-1
         )
-        rank = rank + bump.astype(jnp.int32)
-        rank = rank.at[:, t].set(jnp.where(placing, pr_rank, 0))
+        twoK = 2 * K
+        dup = (
+            (cut_slot[:, :, None] == cut_slot[:, None, :])
+            & (cut_off[:, :, None] == cut_off[:, None, :])
+            & cut_valid[:, :, None] & cut_valid[:, None, :]
+            & (jnp.arange(twoK)[None, :, None]
+               < jnp.arange(twoK)[None, None, :])
+        )                                                      # [D,i,j]
+        cut_valid = cut_valid & ~jnp.any(dup, axis=1)
+        cut_slot = jnp.where(cut_valid, cut_slot, C)
+        cut_off = jnp.where(cut_valid, cut_off, 0)
 
-    # ---- cuts (strictly-inside anchors) — verbatim ------------------
-    ins_cut = ev_valid & (a_off > 0)
-    r1_cut = is_range & taken & s1 & (r1o > 0)
-    r2_cut = is_range & taken & s2 & (r2o > 0)
-    cut_slot = jnp.concatenate([
-        jnp.where(ins_cut, a_slot, jnp.where(r1_cut, r1s, C)),
-        jnp.where(r2_cut, r2s, C),
-    ], axis=-1)                                            # [D,2K]
-    cut_off = jnp.concatenate([
-        jnp.where(ins_cut, a_off, jnp.where(r1_cut, r1o, 0)),
-        jnp.where(r2_cut, r2o, 0),
-    ], axis=-1)
-    cut_valid = jnp.concatenate(
-        [ins_cut | r1_cut, r2_cut], axis=-1
-    )
-    twoK = 2 * K
-    dup = (
-        (cut_slot[:, :, None] == cut_slot[:, None, :])
-        & (cut_off[:, :, None] == cut_off[:, None, :])
-        & cut_valid[:, :, None] & cut_valid[:, None, :]
-        & (jnp.arange(twoK)[None, :, None]
-           < jnp.arange(twoK)[None, None, :])
-    )                                                      # [D,i,j]
-    cut_valid = cut_valid & ~jnp.any(dup, axis=1)
-    cut_slot = jnp.where(cut_valid, cut_slot, C)
-    cut_off = jnp.where(cut_valid, cut_off, 0)
+        same_row = cut_slot[:, :, None] == cut_slot[:, None, :]
+        higher = cut_off[:, None, :] > cut_off[:, :, None]
+        next_off = jnp.min(
+            jnp.where(
+                same_row & higher & cut_valid[:, None, :],
+                cut_off[:, None, :], BIG,
+            ),
+            axis=-1,
+        )                                                      # [D,2K]
+        # parent-row fields for tails: a plain batched gather. The chunked
+        # step recovers these with [D, 2K, C] masked reduces (a
+        # Mosaic-safe idiom this XLA-only kernel does not need — it
+        # already gathers for rank/win_val); invalid cuts read garbage
+        # from a clamped row, which is fine: their sort keys (slot C+1)
+        # park them past every live row.
+        cut_clamped = jnp.minimum(cut_slot, C - 1)
 
-    same_row = cut_slot[:, :, None] == cut_slot[:, None, :]
-    higher = cut_off[:, None, :] > cut_off[:, :, None]
-    next_off = jnp.min(
-        jnp.where(
-            same_row & higher & cut_valid[:, None, :],
-            cut_off[:, None, :], BIG,
-        ),
-        axis=-1,
-    )                                                      # [D,2K]
-    # parent-row fields for tails: a plain batched gather. The chunked
-    # step recovers these with [D, 2K, C] masked reduces (a
-    # Mosaic-safe idiom this XLA-only kernel does not need — it
-    # already gathers for rank/win_val); invalid cuts read garbage
-    # from a clamped row, which is fine: their sort keys (slot C+1)
-    # park them past every live row.
-    cut_clamped = jnp.minimum(cut_slot, C - 1)
+        def row_at(field):
+            return jnp.take_along_axis(field, cut_clamped, axis=1)
 
-    def row_at(field):
-        return jnp.take_along_axis(field, cut_clamped, axis=1)
+        par_len = row_at(st["length"])
+        tail_len = jnp.minimum(next_off, par_len) - cut_off
+        # head shortening: base row's new length = min cut offset in it —
+        # a scatter-min (duplicate cut slots combine exactly like the
+        # masked [D, C, 2K] min-reduce they replace)
+        drow = jnp.arange(D, dtype=jnp.int32)[:, None]
+        head_len = st["length"].at[drow, cut_clamped].min(
+            jnp.where(cut_valid & (cut_slot < C), cut_off, BIG),
+            mode="drop",
+        )
 
-    par_len = row_at(st["length"])
-    tail_len = jnp.minimum(next_off, par_len) - cut_off
-    # head shortening: base row's new length = min cut offset in it —
-    # a scatter-min (duplicate cut slots combine exactly like the
-    # masked [D, C, 2K] min-reduce they replace)
-    drow = jnp.arange(D, dtype=jnp.int32)[:, None]
-    head_len = st["length"].at[drow, cut_clamped].min(
-        jnp.where(cut_valid & (cut_slot < C), cut_off, BIG),
-        mode="drop",
-    )
+        # ---- row tables: C base + 2K tails + K events — verbatim --------
+        def rows(base, tail, event):
+            return jnp.concatenate([base, tail, event], axis=-1)
 
-    # ---- row tables: C base + 2K tails + K events — verbatim --------
-    def rows(base, tail, event):
-        return jnp.concatenate([base, tail, event], axis=-1)
+        ev_row_valid = ev_valid
+        inval_t = jnp.where(cut_valid, cut_slot, C + 1)
+        inval_e = jnp.where(ev_row_valid, a_slot, C + 1)
 
-    ev_row_valid = ev_valid
-    inval_t = jnp.where(cut_valid, cut_slot, C + 1)
-    inval_e = jnp.where(ev_row_valid, a_slot, C + 1)
+        key_slot = rows(j, inval_t, inval_e)
+        key_off = rows(jnp.zeros((D, C), jnp.int32), cut_off,
+                       jnp.where(ev_row_valid, a_off, 0))
+        key_base = rows(jnp.ones((D, C), jnp.int32),
+                        jnp.ones((D, twoK), jnp.int32),
+                        jnp.zeros((D, K), jnp.int32))
+        key_rank = rows(jnp.zeros((D, C), jnp.int32),
+                        jnp.zeros((D, twoK), jnp.int32), rank)
 
-    key_slot = rows(j, inval_t, inval_e)
-    key_off = rows(jnp.zeros((D, C), jnp.int32), cut_off,
-                   jnp.where(ev_row_valid, a_off, 0))
-    key_base = rows(jnp.ones((D, C), jnp.int32),
-                    jnp.ones((D, twoK), jnp.int32),
-                    jnp.zeros((D, K), jnp.int32))
-    key_rank = rows(jnp.zeros((D, C), jnp.int32),
-                    jnp.zeros((D, twoK), jnp.int32), rank)
-
-    r_length = rows(head_len, tail_len,
-                    jnp.where(ev_row_valid, ops["length"], 0))
-    r_seq = rows(st["seq"], row_at(st["seq"]), ops["seq"])
-    r_client = rows(st["client"], row_at(st["client"]),
-                    ops["client"])
-    r_removed = rows(
-        st["removed_seq"],
-        jnp.where(cut_valid, row_at(st["removed_seq"]),
-                  NOT_REMOVED),
-        jnp.full((D, K), NOT_REMOVED, jnp.int32),
-    )
-    r_removers = rows(
-        st["removers"].astype(jnp.int32),
-        row_at(st["removers"].astype(jnp.int32)),
-        jnp.zeros((D, K), jnp.int32),
-    )
-    r_op_id = rows(st["op_id"], row_at(st["op_id"]), ops["op_id"])
-    r_op_off = rows(st["op_off"],
-                    row_at(st["op_off"]) + cut_off,
-                    jnp.zeros((D, K), jnp.int32))
-    r_marker = rows(st["is_marker"], row_at(st["is_marker"]),
-                    ops["is_marker"])
-    r_props = [
-        rows(st[f"prop{c}"], row_at(st[f"prop{c}"]),
-             jnp.zeros((D, K), jnp.int32))
-        for c in range(PROP_CHANNELS)
-    ]
-    r_frag_lo = rows(jnp.zeros((D, C), jnp.int32), cut_off,
-                     jnp.zeros((D, K), jnp.int32))
-    r_frag_hi = r_frag_lo + r_length
-    r_is_event = rows(jnp.zeros((D, C), jnp.int32),
+        r_length = rows(head_len, tail_len,
+                        jnp.where(ev_row_valid, ops["length"], 0))
+        r_seq = rows(st["seq"], row_at(st["seq"]), ops["seq"])
+        r_client = rows(st["client"], row_at(st["client"]),
+                        ops["client"])
+        r_removed = rows(
+            st["removed_seq"],
+            jnp.where(cut_valid, row_at(st["removed_seq"]),
+                      NOT_REMOVED),
+            jnp.full((D, K), NOT_REMOVED, jnp.int32),
+        )
+        r_removers = rows(
+            st["removers"].astype(jnp.int32),
+            row_at(st["removers"].astype(jnp.int32)),
+            jnp.zeros((D, K), jnp.int32),
+        )
+        r_op_id = rows(st["op_id"], row_at(st["op_id"]), ops["op_id"])
+        r_op_off = rows(st["op_off"],
+                        row_at(st["op_off"]) + cut_off,
+                        jnp.zeros((D, K), jnp.int32))
+        r_marker = rows(st["is_marker"], row_at(st["is_marker"]),
+                        ops["is_marker"])
+        r_props = [
+            rows(st[f"prop{c}"], row_at(st[f"prop{c}"]),
+                 jnp.zeros((D, K), jnp.int32))
+            for c in range(PROP_CHANNELS)
+        ]
+        r_frag_lo = rows(jnp.zeros((D, C), jnp.int32), cut_off,
+                         jnp.zeros((D, K), jnp.int32))
+        r_frag_hi = r_frag_lo + r_length
+        r_is_event = rows(jnp.zeros((D, C), jnp.int32),
+                          jnp.zeros((D, twoK), jnp.int32),
+                          ev_row_valid.astype(jnp.int32))
+        ev_bit = rows(jnp.zeros((D, C), jnp.int32),
                       jnp.zeros((D, twoK), jnp.int32),
-                      ev_row_valid.astype(jnp.int32))
-    ev_bit = rows(jnp.zeros((D, C), jnp.int32),
-                  jnp.zeros((D, twoK), jnp.int32),
-                  kidx + jnp.zeros((D, K), jnp.int32))
-    r_live = rows(
-        alive.astype(jnp.int32),
-        cut_valid.astype(jnp.int32),
-        ev_row_valid.astype(jnp.int32),
-    )
-
-    # ---- stamps -----------------------------------------------------
-    # The chunked executor computes per-(row, op) visibility and a
-    # lexicographic (slot, offset) interval test here; in a critical
-    # span every op sees every S0 row, so (a) a base/tail row is
-    # stampable iff it is live, not already removed in S0 (an
-    # always-visible removal), and non-empty — ONE [D, R] mask shared
-    # by every lane — and (b) the interval test collapses into the
-    # SHARED view's E-space: a stampable row's absolute extent is
-    # [E[slot]+frag_lo, E[slot]+frag_hi) and lane k stamps it iff that
-    # extent lies inside [pos1, pos2) (positions are span-base = this
-    # same E-space; visible extents partition [0, total], so the
-    # interval compare is exactly the chunked step's six-comparison
-    # lexicographic test at two comparisons). Event rows stamp only
-    # through the host cover bitmask, as in the chunked step.
-    row_E = jnp.take_along_axis(
-        E, jnp.minimum(key_slot, C - 1), axis=1)           # [D,R]
-    row_lo = (row_E + r_frag_lo)[:, :, None]               # [D,R,1]
-    row_hi = (row_E + r_frag_hi)[:, :, None]
-    in_interval = (row_lo >= p1[:, None, :, 0]) & \
-        (row_hi <= p2[:, None, :, 0])                      # [D,R,K]
-
-    row_stampable = (
-        (r_live > 0) & (r_removed == NOT_REMOVED)
-        & (r_length > 0) & (r_is_event == 0)
-    )                                                      # [D,R]
-    base_stamp = in_interval & row_stampable[:, :, None] & \
-        (is_range & taken)[:, None, :]                     # [D,R,K]
-    cover = (
-        (ops["ev_cover"][:, None, :]
-         >> ev_bit[:, :, None].astype(jnp.uint32)) & 1
-    ) > 0
-    ev_stamp = cover & (r_is_event[:, :, None] > 0) & \
-        (is_range & taken)[:, None, :]
-    raw_stamp = base_stamp | ev_stamp
-
-    # first-remover-wins: every op sees every earlier in-span remove,
-    # so the chunked step's K-iteration visibility replay collapses to
-    # "the first remove lane to stamp a row owns it; later range ops
-    # skip rows an earlier remove took" — one exclusive cumulative-or
-    # over the lane axis.
-    rm_lane = (is_rem & taken)[:, None, :]                 # [D,1,K]
-    rm_raw = raw_stamp & rm_lane                           # [D,R,K]
-    prior_rm = jnp.cumsum(
-        rm_raw.astype(jnp.int32), axis=-1
-    ) - rm_raw.astype(jnp.int32) > 0
-    eff = raw_stamp & ~prior_rm
-    rm_eff = eff & rm_lane
-    ann_eff = eff & (is_ann & taken)[:, None, :]
-
-    # at most ONE effective remove per row (first-wins) and lane
-    # order IS sequenced order within a span, so the stamping remove
-    # is simply the FIRST rm lane — one argmax + two [D, R] gathers
-    # replace the chunked step's masked [D, R, K] min/sum reduces
-    any_rm = jnp.any(rm_eff, axis=-1)                      # [D,R]
-    rm_k = jnp.argmax(rm_eff, axis=-1).astype(jnp.int32)
-    rm_k = jnp.minimum(rm_k, K - 1)
-
-    def lane_at(field, k):
-        return jnp.take_along_axis(field, k, axis=1)
-
-    new_removed = jnp.where(
-        (r_removed == NOT_REMOVED) & any_rm,
-        lane_at(ops["seq"], rm_k), r_removed,
-    )
-    rm_bit = jnp.left_shift(
-        jnp.uint32(1),
-        lane_at(ops["client"], rm_k).astype(jnp.uint32),
-    )
-    new_removers = r_removers.astype(jnp.uint32) | jnp.where(
-        any_rm, rm_bit, jnp.uint32(0)
-    )
-
-    new_props = []
-    for c in range(PROP_CHANNELS):
-        cand = ann_eff & (ops["prop_key"][:, None, :] == c)
-        # LWW winner = LAST candidate lane (lane order is sequenced
-        # order): argmax over the reversed lane axis
-        any_c = jnp.any(cand, axis=-1)                     # [D,R]
-        win_k = (K - 1) - jnp.argmax(
-            cand[..., ::-1], axis=-1
-        ).astype(jnp.int32)
-        win_val = lane_at(ops["prop_val"], jnp.minimum(win_k, K - 1))
-        new_props.append(
-            jnp.where(any_c, win_val, r_props[c])
+                      kidx + jnp.zeros((D, K), jnp.int32))
+        r_live = rows(
+            alive.astype(jnp.int32),
+            cut_valid.astype(jnp.int32),
+            ev_row_valid.astype(jnp.int32),
         )
 
-    # ---- overflow ----------------------------------------------------
-    adds = (
-        ev_valid.astype(jnp.int32)
-        + jnp.sum(
-            cut_valid.reshape(D, 2, K).astype(jnp.int32), axis=1
+    with jax.named_scope("walker_stamps"):
+        # ---- stamps -----------------------------------------------------
+        # The chunked executor computes per-(row, op) visibility and a
+        # lexicographic (slot, offset) interval test here; in a critical
+        # span every op sees every S0 row, so (a) a base/tail row is
+        # stampable iff it is live, not already removed in S0 (an
+        # always-visible removal), and non-empty — ONE [D, R] mask shared
+        # by every lane — and (b) the interval test collapses into the
+        # SHARED view's E-space: a stampable row's absolute extent is
+        # [E[slot]+frag_lo, E[slot]+frag_hi) and lane k stamps it iff that
+        # extent lies inside [pos1, pos2) (positions are span-base = this
+        # same E-space; visible extents partition [0, total], so the
+        # interval compare is exactly the chunked step's six-comparison
+        # lexicographic test at two comparisons). Event rows stamp only
+        # through the host cover bitmask, as in the chunked step.
+        row_E = jnp.take_along_axis(
+            E, jnp.minimum(key_slot, C - 1), axis=1)           # [D,R]
+        row_lo = (row_E + r_frag_lo)[:, :, None]               # [D,R,1]
+        row_hi = (row_E + r_frag_hi)[:, :, None]
+        in_interval = (row_lo >= p1[:, None, :, 0]) & \
+            (row_hi <= p2[:, None, :, 0])                      # [D,R,K]
+
+        row_stampable = (
+            (r_live > 0) & (r_removed == NOT_REMOVED)
+            & (r_length > 0) & (r_is_event == 0)
+        )                                                      # [D,R]
+        base_stamp = in_interval & row_stampable[:, :, None] & \
+            (is_range & taken)[:, None, :]                     # [D,R,K]
+        cover = (
+            (ops["ev_cover"][:, None, :]
+             >> ev_bit[:, :, None].astype(jnp.uint32)) & 1
+        ) > 0
+        ev_stamp = cover & (r_is_event[:, :, None] > 0) & \
+            (is_range & taken)[:, None, :]
+        raw_stamp = base_stamp | ev_stamp
+
+        # first-remover-wins: every op sees every earlier in-span remove,
+        # so the chunked step's K-iteration visibility replay collapses to
+        # "the first remove lane to stamp a row owns it; later range ops
+        # skip rows an earlier remove took" — one exclusive cumulative-or
+        # over the lane axis.
+        rm_lane = (is_rem & taken)[:, None, :]                 # [D,1,K]
+        rm_raw = raw_stamp & rm_lane                           # [D,R,K]
+        prior_rm = jnp.cumsum(
+            rm_raw.astype(jnp.int32), axis=-1
+        ) - rm_raw.astype(jnp.int32) > 0
+        eff = raw_stamp & ~prior_rm
+        rm_eff = eff & rm_lane
+        ann_eff = eff & (is_ann & taken)[:, None, :]
+
+        # at most ONE effective remove per row (first-wins) and lane
+        # order IS sequenced order within a span, so the stamping remove
+        # is simply the FIRST rm lane — one argmax + two [D, R] gathers
+        # replace the chunked step's masked [D, R, K] min/sum reduces
+        any_rm = jnp.any(rm_eff, axis=-1)                      # [D,R]
+        rm_k = jnp.argmax(rm_eff, axis=-1).astype(jnp.int32)
+        rm_k = jnp.minimum(rm_k, K - 1)
+
+        def lane_at(field, k):
+            return jnp.take_along_axis(field, k, axis=1)
+
+        new_removed = jnp.where(
+            (r_removed == NOT_REMOVED) & any_rm,
+            lane_at(ops["seq"], rm_k), r_removed,
         )
-    )                                                      # [D,K]
-    new_count = count[:, 0] + jnp.sum(adds, axis=-1)
-    overflow_now = new_count > C
-    keep = ~overflow_now
+        rm_bit = jnp.left_shift(
+            jnp.uint32(1),
+            lane_at(ops["client"], rm_k).astype(jnp.uint32),
+        )
+        new_removers = r_removers.astype(jnp.uint32) | jnp.where(
+            any_rm, rm_bit, jnp.uint32(0)
+        )
 
-    # ---- one stable multi-key sort ----------------------------------
-    # (off, is_base, rank) pack into ONE int32 minor key — all three
-    # are bounded (off < OPOFF_BOUND = 2^17, base 1 bit, rank < K), so
-    # the composite is lexicographically identical to the chunked
-    # step's three separate keys — and the sort carries only the keys
-    # plus an iota: the resulting PERMUTATION gathers the ten field
-    # arrays afterwards. XLA's stable sort moves every operand through
-    # every comparator, so a 12-operand sort (the chunked step's
-    # shape) costs ~4x this 3-operand one on CPU.
-    key_minor = (key_off * 2 + key_base) * K + key_rank
-    R = C + 3 * K
-    iota_r = jnp.broadcast_to(
-        jnp.arange(R, dtype=jnp.int32)[None], (D, R))
-    _, _, perm = jax.lax.sort(
-        [key_slot, key_minor, iota_r], dimension=-1, is_stable=True,
-        num_keys=2,
-    )
+        new_props = []
+        for c in range(PROP_CHANNELS):
+            cand = ann_eff & (ops["prop_key"][:, None, :] == c)
+            # LWW winner = LAST candidate lane (lane order is sequenced
+            # order): argmax over the reversed lane axis
+            any_c = jnp.any(cand, axis=-1)                     # [D,R]
+            win_k = (K - 1) - jnp.argmax(
+                cand[..., ::-1], axis=-1
+            ).astype(jnp.int32)
+            win_val = lane_at(ops["prop_val"], jnp.minimum(win_k, K - 1))
+            new_props.append(
+                jnp.where(any_c, win_val, r_props[c])
+            )
 
-    def permute(arr):
-        return jnp.take_along_axis(arr, perm, axis=1)
+    with jax.named_scope("walker_overflow"):
+        # ---- overflow ----------------------------------------------------
+        adds = (
+            ev_valid.astype(jnp.int32)
+            + jnp.sum(
+                cut_valid.reshape(D, 2, K).astype(jnp.int32), axis=1
+            )
+        )                                                      # [D,K]
+        new_count = count[:, 0] + jnp.sum(adds, axis=-1)
+        overflow_now = new_count > C
+        keep = ~overflow_now
 
-    s_len = permute(r_length)
-    s_seq = permute(r_seq)
-    s_cli = permute(r_client)
-    s_rem = permute(new_removed)
-    s_rrs = permute(new_removers.astype(jnp.int32))
-    s_oid = permute(r_op_id)
-    s_ooff = permute(r_op_off)
-    s_mark = permute(r_marker)
-    s_props = [permute(p) for p in new_props]
+    with jax.named_scope("walker_sort"):
+        # ---- one stable multi-key sort ----------------------------------
+        # (off, is_base, rank) pack into ONE int32 minor key — all three
+        # are bounded (off < OPOFF_BOUND = 2^17, base 1 bit, rank < K), so
+        # the composite is lexicographically identical to the chunked
+        # step's three separate keys — and the sort carries only the keys
+        # plus an iota: the resulting PERMUTATION gathers the ten field
+        # arrays afterwards. XLA's stable sort moves every operand through
+        # every comparator, so a 12-operand sort (the chunked step's
+        # shape) costs ~4x this 3-operand one on CPU.
+        key_minor = (key_off * 2 + key_base) * K + key_rank
+        R = C + 3 * K
+        iota_r = jnp.broadcast_to(
+            jnp.arange(R, dtype=jnp.int32)[None], (D, R))
+        _, _, perm = jax.lax.sort(
+            [key_slot, key_minor, iota_r], dimension=-1, is_stable=True,
+            num_keys=2,
+        )
 
-    def upd(old, new):
-        return jnp.where(keep[:, None], new[:, :C], old)
+    with jax.named_scope("walker_permute"):
+        def permute(arr):
+            return jnp.take_along_axis(arr, perm, axis=1)
 
-    out = {
-        "length": upd(st["length"], s_len),
-        "seq": upd(st["seq"], s_seq),
-        "client": upd(st["client"], s_cli),
-        "removed_seq": upd(st["removed_seq"], s_rem),
-        "removers": jnp.where(
-            keep[:, None], s_rrs[:, :C].astype(jnp.uint32),
-            st["removers"],
-        ),
-        "op_id": upd(st["op_id"], s_oid),
-        "op_off": upd(st["op_off"], s_ooff),
-        "is_marker": upd(st["is_marker"], s_mark),
-        "count": jnp.where(keep, new_count, st["count"]),
-        "min_seq": jnp.maximum(
-            st["min_seq"],
-            jnp.max(jnp.where(taken, ops["min_seq"], 0), axis=-1),
-        ),
-        "overflow": jnp.where(overflow_now, 1, st["overflow"]),
-    }
-    for c in range(PROP_CHANNELS):
-        out[f"prop{c}"] = upd(st[f"prop{c}"], s_props[c])
+        s_len = permute(r_length)
+        s_seq = permute(r_seq)
+        s_cli = permute(r_client)
+        s_rem = permute(new_removed)
+        s_rrs = permute(new_removers.astype(jnp.int32))
+        s_oid = permute(r_op_id)
+        s_ooff = permute(r_op_off)
+        s_mark = permute(r_marker)
+        s_props = [permute(p) for p in new_props]
+
+    with jax.named_scope("walker_update"):
+        def upd(old, new):
+            return jnp.where(keep[:, None], new[:, :C], old)
+
+        out = {
+            "length": upd(st["length"], s_len),
+            "seq": upd(st["seq"], s_seq),
+            "client": upd(st["client"], s_cli),
+            "removed_seq": upd(st["removed_seq"], s_rem),
+            "removers": jnp.where(
+                keep[:, None], s_rrs[:, :C].astype(jnp.uint32),
+                st["removers"],
+            ),
+            "op_id": upd(st["op_id"], s_oid),
+            "op_off": upd(st["op_off"], s_ooff),
+            "is_marker": upd(st["is_marker"], s_mark),
+            "count": jnp.where(keep, new_count, st["count"]),
+            "min_seq": jnp.maximum(
+                st["min_seq"],
+                jnp.max(jnp.where(taken, ops["min_seq"], 0), axis=-1),
+            ),
+            "overflow": jnp.where(overflow_now, 1, st["overflow"]),
+        }
+        for c in range(PROP_CHANNELS):
+            out[f"prop{c}"] = upd(st[f"prop{c}"], s_props[c])
     return out, take_upto, overflow_now
 
 

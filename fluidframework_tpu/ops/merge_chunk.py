@@ -391,17 +391,18 @@ def _gather_ops(ops_w: dict, cursor: jnp.ndarray, K: int) -> dict:
     """Slice the next K ops per doc from [D, W] arrays. Beyond-window
     lanes read as NOOP chunk starts (they stop the take)."""
     W = ops_w["kind"].shape[1]
-    idx = cursor[:, None] + jnp.arange(K, dtype=jnp.int32)[None]
-    cidx = jnp.minimum(idx, W - 1)
-    out = {
-        f: jnp.take_along_axis(a, cidx, axis=1)
-        for f, a in ops_w.items()
-    }
-    off_end = idx >= W
-    out["kind"] = jnp.where(off_end, KIND_NOOP, out["kind"])
-    out["chunk_start"] = jnp.where(
-        off_end, 1, out["chunk_start"]
-    )
+    with jax.named_scope("gather_ops"):
+        idx = cursor[:, None] + jnp.arange(K, dtype=jnp.int32)[None]
+        cidx = jnp.minimum(idx, W - 1)
+        out = {
+            f: jnp.take_along_axis(a, cidx, axis=1)
+            for f, a in ops_w.items()
+        }
+        off_end = idx >= W
+        out["kind"] = jnp.where(off_end, KIND_NOOP, out["kind"])
+        out["chunk_start"] = jnp.where(
+            off_end, 1, out["chunk_start"]
+        )
     return out
 
 

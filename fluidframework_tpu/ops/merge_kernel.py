@@ -87,7 +87,8 @@ def apply_window_impl(table: SegmentTable, batch: OpBatch) -> SegmentTable:
     ops_wd = batch_to_window(batch)
 
     def step(carry, op):
-        return fused_step(carry, op), None
+        with jax.named_scope("scan_step"):
+            return fused_step(carry, op), None
 
     unroll = _TPU_UNROLL if jax.default_backend() == "tpu" else 1
     st, _ = jax.lax.scan(step, st, ops_wd, unroll=unroll)
@@ -231,4 +232,5 @@ def compact(table: SegmentTable) -> SegmentTable:
             count=keep.sum().astype(jnp.int32),
         )
 
-    return jax.vmap(one)(table)
+    with jax.named_scope("compact"):
+        return jax.vmap(one)(table)

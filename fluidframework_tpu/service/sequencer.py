@@ -26,6 +26,7 @@ from dataclasses import dataclass
 from typing import Any, Callable, Optional
 
 from ..obs import metrics as _metrics
+from ..obs.profiler import self_timed
 from ..obs.trace import stamp as _stamp
 from ..protocol.messages import (
     ClientDetail,
@@ -90,6 +91,10 @@ class DocumentSequencer:
         # the wire MEAN server wall time)
         self._clock = clock or time.time
         self._clients: dict[str, _ClientState] = {}
+        # always-on counters of ticket() (obs.profiler.self_timed):
+        # every call, nacks and duplicate drops included, and the host
+        # seconds of one call in SELF_TIME_EVERY, scaled up
+        self.stats = {"ticket_s": 0.0, "tickets": 0}
 
     # ------------------------------------------------------------------
     # membership
@@ -130,6 +135,7 @@ class DocumentSequencer:
     # ------------------------------------------------------------------
     # op sequencing
 
+    @self_timed("ticket_s", "tickets")
     def ticket(self, client_id: str, op: DocumentMessage) -> TicketResult:
         """Assign seq + msn to one raw client op, or nack it."""
         client = self._clients.get(client_id)

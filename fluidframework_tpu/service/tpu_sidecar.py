@@ -42,6 +42,7 @@ bucket jumps nor regrows ever hit an XLA compile mid-serve.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import os
 import time
@@ -54,7 +55,11 @@ from ..models.mergetree import MergeTreeClient
 from ..obs import metrics as obs_metrics
 from ..obs.flight_recorder import FlightRecorder
 from ..obs.heat import HeatLedger, attribute_round
-from ..obs.profiler import device_trace
+from ..obs.profiler import (
+    device_trace,
+    device_trace_enabled,
+    self_timed,
+)
 from ..obs.trace import stamp as trace_stamp
 from ..ops import (
     DocStream,
@@ -162,6 +167,9 @@ _M_POOL_FAULTS = obs_metrics.REGISTRY.counter(
     "pool operations deferred or retried under a transient fault "
     "(shared by NAME across the seq and mesh tiers, like the "
     "sidecar.pool_* chaos sites)", labelnames=("tier", "op"))
+
+# the round span when the device-trace switch is off: one shared no-op
+_NO_SPAN = contextlib.nullcontext()
 
 # chaos seams (docs/ROBUSTNESS.md): the dispatch site fires BEFORE the
 # round mutates anything (queues intact, so a retry is exact); the
@@ -744,9 +752,33 @@ class TpuMergeSidecar:
         self._compact_every = compact_every
         self.grow_count = 0
         self.evict_count = 0
-        # pipeline instrumentation (bench config7 reads these):
-        # host-pack seconds vs settle (device-wait) seconds per round
-        self.stats = {"pack_s": 0.0, "settle_s": 0.0, "rounds": 0}
+        # round counters, always on: plain adds, no lock and no
+        # registry call on the per-op path (docs/OBSERVABILITY.md
+        # "Sidecar round telemetry"). Read their deltas over a window:
+        # benchmark/metrics/pack_ms_per_round.* and
+        # settle_ms_per_round.* read pack_s, settle_s and rounds.
+        # Seconds: ingest_s (inside ingest, timed on one call in
+        # obs.profiler.SELF_TIME_EVERY and scaled; ingest_msgs calls),
+        # pack_s (host half of a round), graph_s (its
+        # _compile_program, part of pack_s), enqueue_s (the round's
+        # own _apply_program call; a regrow re-apply runs in _settle),
+        # settle_s (the overflow-flag read, i.e. the wait on the
+        # device), compact_s. Lanes, summed over rounds: real_ops
+        # (real + pooled ops), active_docs (rows with a real op),
+        # walker_steps (the prefix walker's while trip count),
+        # scan_steps (lane width of each scan dispatched), lane_slots
+        # (walker_steps x docs x K + scan_steps x docs).
+        self.stats = {
+            "pack_s": 0.0, "settle_s": 0.0, "rounds": 0,
+            "ingest_s": 0.0, "ingest_msgs": 0, "graph_s": 0.0,
+            "enqueue_s": 0.0, "compact_s": 0.0, "real_ops": 0,
+            "active_docs": 0, "walker_steps": 0, "scan_steps": 0,
+            "lane_slots": 0,
+        }
+        # device-trace switch as the last apply/sync read it, and the
+        # round the in-flight dispatch belongs to (its settle span)
+        self._trace_spans = False
+        self._inflight_round = 0
         # device-time attribution plane (obs/heat.py, OPT-IN): when a
         # heat ledger is attached, each round's wall-ms (dispatch
         # start -> that round's settle; consecutive pipelined spans
@@ -804,6 +836,7 @@ class TpuMergeSidecar:
             lambda msg: self.ingest(document_id, msg),
         )
 
+    @self_timed("ingest_s", "ingest_msgs")
     def ingest(self, document_id: str, msg: SequencedMessage) -> None:
         """Consume one sequenced message of a document: channel ops for
         tracked channels encode as kernel ops; everything else becomes
@@ -900,6 +933,7 @@ class TpuMergeSidecar:
         the old synchronous contract (settle before returning)."""
         if not self._queued or self.queued_ops == 0:
             return 0
+        self._trace_spans = device_trace_enabled()
         if self.breaker is not None:
             if not self.breaker.allow():
                 # open (or out of probes): ops stay queued; the
@@ -915,7 +949,10 @@ class TpuMergeSidecar:
             real = self._dispatch()
         self._applies += 1
         if self._applies % self._compact_every == 0:
-            self._table = compact(self._table)
+            t0 = time.perf_counter()
+            with self._span("compact", self.stats["rounds"]):
+                self._table = compact(self._table)
+            self.stats["compact_s"] += time.perf_counter() - t0
         if not self.pipeline:
             self._settle()
         return real
@@ -926,7 +963,16 @@ class TpuMergeSidecar:
         inspect recovery counters (grow/evict/pool) right after an
         ``apply`` call this first — under the pipelined default those
         advance at the NEXT settle boundary, not inside ``apply``."""
+        self._trace_spans = device_trace_enabled()
         self._settle()
+
+    def _span(self, stage: str, rnd: int):
+        """The round span ``sidecar:<stage>:r<rnd>``: a profiler
+        annotation on the device trace's clock when the switch (as the
+        last ``apply``/``sync`` read it) is on; off, one boolean read."""
+        if not self._trace_spans:
+            return _NO_SPAN
+        return device_trace(f"sidecar:{stage}:r{rnd}")
 
     def prewarm(self, max_bucket: Optional[int] = None) -> float:
         """Compile every shape the (docs, window, capacity) ladder can
@@ -1052,86 +1098,108 @@ class TpuMergeSidecar:
             _M_DISPATCH_FAULTS.inc()
             raise _SITE_DISPATCH.transient(fault)
         docs = self.max_docs
+        rnd = self.stats["rounds"] + 1
         t0 = time.perf_counter()
         # attribution span opens at round start (host clock, opt-in)
         attr_t0 = self._attr_clock() if self.heat is not None else 0.0
-        # HOST HALF — runs while the device still computes the
-        # previous round. Coalesce noop runs at pack time (safe here:
-        # the queue is consumed whole), then pad the window to a
-        # ladder bucket: the executors compile per (docs, window)
-        # shape, and an exact-fit window would recompile on nearly
-        # every flush (20-40s each on the real chip). Pow2 bucketing
-        # bounds the shape count to log(n).
-        packed = [coalesce_noops(q) for q in self._queued]
-        attr_counts: dict[str, int] = {}
-        if self.heat is not None:
-            # per-document real-op counts off the pack metadata (host
-            # ints; BEFORE the pool tier zeroes its slots out of the
-            # primary window, so pooled docs attribute too). Committed
-            # to self._attr_* only after the in-flight round settles
-            # below — the mid-dispatch _settle charges the PREVIOUS
-            # round from the previous snapshot.
-            for slot, ops in enumerate(packed):
-                if not ops:
-                    continue
-                n = sum(1 for op in ops if op["kind"] != KIND_NOOP)
-                if n:
-                    doc = self._slot_doc.get(slot)
-                    if doc is not None:
-                        attr_counts[doc] = attr_counts.get(doc, 0) + n
-        pool_real = 0
-        if self._pool is not None:
-            # pooled docs dispatch from their canonical-stream tails at
-            # the settle boundary (watermarked, rebuild-proof — see
-            # SeqShardedPool.dispatch_pending); their queued copies are
-            # counted here and dropped from the primary window
-            for slot in list(self._pool.row_of):
-                if packed[slot]:
-                    pool_real += sum(
-                        1 for op in packed[slot]
-                        if op["kind"] != KIND_NOOP
-                    )
-                    packed[slot] = []
-        arrays = _pack_rows(
-            docs, {slot: ops for slot, ops in enumerate(packed) if ops},
-            bucket_floor=self.ladder.window_floor,
-        )
-        program = self._compile_program(
-            arrays, base_head=self._slot_head
-        )
-        if program.get("egwalker") and "span_splits" in program:
-            # host-side scalar (the compiler counts absorbed breaks on
-            # the way down); no device read
-            _M_SPAN_SPLITS.inc(int(program["span_splits"].sum()))
-        if self.executor == "egwalker":
-            # advance the applied-head watermarks AFTER compiling: the
-            # program's criticality was judged against the pre-window
-            # heads (a grow re-apply reuses the compiled program, so
-            # it never re-reads these)
-            for slot, ops in enumerate(packed):
-                for op in reversed(ops):
-                    if op["kind"] != KIND_NOOP:
-                        if op["seq"] > self._slot_head[slot]:
-                            self._slot_head[slot] = op["seq"]
-                        break
-        real = sum(
-            1 for ops in packed for op in ops
-            if op["kind"] != KIND_NOOP
-        )
-        for queue in self._queued:
-            queue.clear()
+        with self._span("pack", rnd):
+            # HOST HALF — runs while the device still computes the
+            # previous round. Coalesce noop runs at pack time (safe here:
+            # the queue is consumed whole), then pad the window to a
+            # ladder bucket: the executors compile per (docs, window)
+            # shape, and an exact-fit window would recompile on nearly
+            # every flush (20-40s each on the real chip). Pow2 bucketing
+            # bounds the shape count to log(n).
+            packed = [coalesce_noops(q) for q in self._queued]
+            attr_counts: dict[str, int] = {}
+            if self.heat is not None:
+                # per-document real-op counts off the pack metadata (host
+                # ints; BEFORE the pool tier zeroes its slots out of the
+                # primary window, so pooled docs attribute too). Committed
+                # to self._attr_* only after the in-flight round settles
+                # below — the mid-dispatch _settle charges the PREVIOUS
+                # round from the previous snapshot.
+                for slot, ops in enumerate(packed):
+                    if not ops:
+                        continue
+                    n = sum(1 for op in ops if op["kind"] != KIND_NOOP)
+                    if n:
+                        doc = self._slot_doc.get(slot)
+                        if doc is not None:
+                            attr_counts[doc] = attr_counts.get(doc, 0) + n
+            pool_real = 0
+            if self._pool is not None:
+                # pooled docs dispatch from their canonical-stream tails at
+                # the settle boundary (watermarked, rebuild-proof — see
+                # SeqShardedPool.dispatch_pending); their queued copies are
+                # counted here and dropped from the primary window
+                for slot in list(self._pool.row_of):
+                    if packed[slot]:
+                        pool_real += sum(
+                            1 for op in packed[slot]
+                            if op["kind"] != KIND_NOOP
+                        )
+                        packed[slot] = []
+            arrays = _pack_rows(
+                docs, {slot: ops for slot, ops in enumerate(packed) if ops},
+                bucket_floor=self.ladder.window_floor,
+            )
+            g0 = time.perf_counter()
+            with self._span("graph", rnd):
+                program = self._compile_program(
+                    arrays, base_head=self._slot_head
+                )
+            graph_s = time.perf_counter() - g0
+            if program.get("egwalker") and "span_splits" in program:
+                # host-side scalar (the compiler counts absorbed breaks on
+                # the way down); no device read
+                _M_SPAN_SPLITS.inc(int(program["span_splits"].sum()))
+            if self.executor == "egwalker":
+                # advance the applied-head watermarks AFTER compiling: the
+                # program's criticality was judged against the pre-window
+                # heads (a grow re-apply reuses the compiled program, so
+                # it never re-reads these)
+                for slot, ops in enumerate(packed):
+                    for op in reversed(ops):
+                        if op["kind"] != KIND_NOOP:
+                            if op["seq"] > self._slot_head[slot]:
+                                self._slot_head[slot] = op["seq"]
+                            break
+            real_lanes = arrays["kind"] != KIND_NOOP
+            real = int(np.count_nonzero(real_lanes))
+            active = int(np.count_nonzero(real_lanes.any(axis=1)))
+            for queue in self._queued:
+                queue.clear()
         pack_s = time.perf_counter() - t0
-        self.stats["pack_s"] += pack_s
-        self.stats["rounds"] += 1
+        # lanes the round dispatches: the walker's K lanes per row per
+        # step, the scan's one per row per window slot (the chunked
+        # route's loop is not counted)
+        if program.get("egwalker"):
+            walker = program["walker_steps"]
+            suffix = program["suffix"]
+            scan = 0 if suffix is None else suffix["kind"].shape[1]
+        elif "chunk_start" in program:
+            walker = scan = 0
+        else:
+            walker, scan = 0, arrays["kind"].shape[1]
+        stats = self.stats
+        stats["pack_s"] += pack_s
+        stats["rounds"] += 1
+        stats["graph_s"] += graph_s
+        stats["real_ops"] += real + pool_real
+        stats["active_docs"] += active
+        stats["walker_steps"] += walker
+        stats["scan_steps"] += scan
+        stats["lane_slots"] += docs * (walker * EG_K + scan)
         _M_ROUNDS.inc()
         _M_OPS.inc(real + pool_real)
         _M_PACK_MS.observe(pack_s * 1000.0)
         # host-side round record (timestamps + already-host scalars
         # only — nothing here may read the device)
         self.flight.record(
-            "dispatch", round=self.stats["rounds"], real_ops=real,
+            "dispatch", round=rnd, real_ops=real,
             pool_ops=pool_real, pack_ms=round(pack_s * 1000.0, 3),
-            capacity=self.capacity,
+            capacity=self.capacity, walker_steps=walker,
         )
         if self.trace_ops and self._round_msgs:
             pack_t = time.time()
@@ -1157,6 +1225,7 @@ class TpuMergeSidecar:
         self._prev_table = self._table
         self._last_program = program
         self._unsettled = True
+        self._inflight_round = rnd
         # commit this round's attribution snapshot now that the
         # previous round has been charged (in the _settle above)
         if self.heat is not None:
@@ -1167,14 +1236,16 @@ class TpuMergeSidecar:
         if self.trace_ops:
             self._inflight_msgs = self._round_msgs
             self._round_msgs = []
-        # opt-in device-trace annotation (FFTPU_DEVICE_TRACE=1): the
-        # dispatch window shows up by round in an XLA profiler trace;
-        # disabled it is one env lookup, and either way it forces no
-        # host<->device sync (the settle boundary stays the only one)
-        with device_trace(f"sidecar:dispatch:r{self.stats['rounds']}"):
+        # the round's enqueue (jit call + transfer of the program's
+        # arrays): sidecar:dispatch:r<n> when the device-trace switch
+        # is on; either way it forces no host<->device sync (the
+        # settle boundary stays the only one)
+        e0 = time.perf_counter()
+        with self._span("dispatch", rnd):
             self._table = self._apply_program(
                 self._prev_table, program, dead if self.donate else None
             )
+        stats["enqueue_s"] += time.perf_counter() - e0
         return real + pool_real
 
     def _settle(self) -> None:
@@ -1187,64 +1258,65 @@ class TpuMergeSidecar:
         a device->host transfer."""
         if self._unsettled:
             self._unsettled = False
-            t0 = time.perf_counter()
-            overflowed = bool(np.asarray(self._table.overflow).any())
-            settle_s = time.perf_counter() - t0
-            self.stats["settle_s"] += settle_s
-            _M_SETTLE_MS.observe(settle_s * 1000.0)
-            # `overflowed` is a pre-fetched host bool by now — the
-            # flight record costs no extra device read
-            self.flight.record(
-                "settle", settle_ms=round(settle_s * 1000.0, 3),
-                overflow=overflowed,
-            )
-            if self.heat is not None and self._attr_counts:
-                # the round's wall-ms (dispatch start -> here) splits
-                # across its active documents proportional to ops —
-                # host math over pre-captured ints at the sanctioned
-                # sync boundary (obs/heat.py owns the formula and the
-                # conservation invariant)
-                round_ms = (self._attr_clock() - self._attr_t0) * 1000.0
-                attribute_round(
-                    self.heat, self._attr_counts, round_ms,
-                    usage=self.usage, tenant_of=self.tenant_of,
+            with self._span("settle", self._inflight_round):
+                t0 = time.perf_counter()
+                overflowed = bool(np.asarray(self._table.overflow).any())
+                settle_s = time.perf_counter() - t0
+                self.stats["settle_s"] += settle_s
+                _M_SETTLE_MS.observe(settle_s * 1000.0)
+                # `overflowed` is a pre-fetched host bool by now — the
+                # flight record costs no extra device read
+                self.flight.record(
+                    "settle", settle_ms=round(settle_s * 1000.0, 3),
+                    overflow=overflowed,
                 )
-                self._attr_counts = {}
-            if self.trace_ops and self._inflight_msgs:
-                settle_t = time.time()
-                for m in self._inflight_msgs:
-                    trace_stamp(m.traces, "sidecar", "settle",
-                                timestamp=settle_t)
-                self.last_settled_msgs = self._inflight_msgs
-                self._inflight_msgs = []
-            if overflowed:
-                _M_RECOVER.inc()
-                # the automatic postmortem: what the dispatch loop did
-                # in the rounds leading up to the overflow
-                self.last_flight_dump = self.flight.dump_to(
-                    reason="_settle found the overflow flag set "
-                           "(recovery running)")
-                self._recover()
-                # recovery re-applied at a new capacity: retired
-                # buffers of the old shape are useless as fodder
-                self._dead = None
-            elif self.donate:
-                self._dead = self._prev_table
-            self._prev_table = None
-            self._last_program = None
-            if self._pool is not None and self._pool.members:
-                # pool tier: apply members' stream tails (the pool
-                # reads its overflow flags on the spot, which is why
-                # its dispatch lives at the sync boundary, not in
-                # _dispatch). Inside the _unsettled branch on purpose:
-                # the pool advances only when a flush is in flight, so
-                # reads stay side-effect-free (no per-read dispatch +
-                # compact) and tier-consistent — ingested-but-never-
-                # applied ops stay invisible on BOTH tiers until the
-                # next apply()
-                for slot in self._pool.dispatch_pending(self._streams):
-                    self._evict(slot)  # beyond even pooled capacity
-                    # (_evict rebuilds the pool for the survivors)
+                if self.heat is not None and self._attr_counts:
+                    # the round's wall-ms (dispatch start -> here) splits
+                    # across its active documents proportional to ops —
+                    # host math over pre-captured ints at the sanctioned
+                    # sync boundary (obs/heat.py owns the formula and the
+                    # conservation invariant)
+                    round_ms = (self._attr_clock() - self._attr_t0) * 1000.0
+                    attribute_round(
+                        self.heat, self._attr_counts, round_ms,
+                        usage=self.usage, tenant_of=self.tenant_of,
+                    )
+                    self._attr_counts = {}
+                if self.trace_ops and self._inflight_msgs:
+                    settle_t = time.time()
+                    for m in self._inflight_msgs:
+                        trace_stamp(m.traces, "sidecar", "settle",
+                                    timestamp=settle_t)
+                    self.last_settled_msgs = self._inflight_msgs
+                    self._inflight_msgs = []
+                if overflowed:
+                    _M_RECOVER.inc()
+                    # the automatic postmortem: what the dispatch loop did
+                    # in the rounds leading up to the overflow
+                    self.last_flight_dump = self.flight.dump_to(
+                        reason="_settle found the overflow flag set "
+                               "(recovery running)")
+                    self._recover()
+                    # recovery re-applied at a new capacity: retired
+                    # buffers of the old shape are useless as fodder
+                    self._dead = None
+                elif self.donate:
+                    self._dead = self._prev_table
+                self._prev_table = None
+                self._last_program = None
+                if self._pool is not None and self._pool.members:
+                    # pool tier: apply members' stream tails (the pool
+                    # reads its overflow flags on the spot, which is why
+                    # its dispatch lives at the sync boundary, not in
+                    # _dispatch). Inside the _unsettled branch on purpose:
+                    # the pool advances only when a flush is in flight, so
+                    # reads stay side-effect-free (no per-read dispatch +
+                    # compact) and tier-consistent — ingested-but-never-
+                    # applied ops stay invisible on BOTH tiers until the
+                    # next apply()
+                    for slot in self._pool.dispatch_pending(self._streams):
+                        self._evict(slot)  # beyond even pooled capacity
+                        # (_evict rebuilds the pool for the survivors)
 
     # ------------------------------------------------------------------
     # overflow recovery: grow ladder, then seq-sharded pool, then

@@ -19,6 +19,8 @@ from fluidframework_tpu.obs.profiler import (
     ContinuousProfiler,
     component_of,
     device_trace,
+    device_trace_enabled,
+    set_device_trace,
 )
 from fluidframework_tpu.obs.slo import (
     DEFAULT_FAST_WINDOW_S,
@@ -438,16 +440,28 @@ def test_profiler_validates_interval_and_dump_to_writes_stream():
     assert buf.getvalue().strip() == text.strip()
 
 
-def test_device_trace_is_a_noop_unless_enabled(monkeypatch):
+@pytest.mark.parametrize("switch", ["env", "flag"])
+def test_device_trace_is_a_noop_unless_enabled(monkeypatch, switch):
     monkeypatch.delenv("FFTPU_DEVICE_TRACE", raising=False)
+    set_device_trace(False)
+    assert not device_trace_enabled()
     with device_trace("round"):
         x = 1
     assert x == 1
-    # enabled: still must not raise (jax present in this env)
-    monkeypatch.setenv("FFTPU_DEVICE_TRACE", "1")
-    with device_trace("round"):
-        x = 2
-    assert x == 2
+    # enabled either way: still must not raise (jax present in this env)
+    if switch == "env":
+        monkeypatch.setenv("FFTPU_DEVICE_TRACE", "1")
+    else:
+        set_device_trace(True)
+    try:
+        assert device_trace_enabled()
+        with device_trace("round"):
+            x = 2
+        assert x == 2
+    finally:
+        set_device_trace(False)
+    monkeypatch.delenv("FFTPU_DEVICE_TRACE", raising=False)
+    assert not device_trace_enabled()
 
 
 # ======================================================================
