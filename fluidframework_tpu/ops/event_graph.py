@@ -51,11 +51,15 @@ This module is that idea translated to the batched SoA table world:
    one prefix-sum per macro-step, shared by every lane, where the
    chunked executor pays a per-lane [D, K, C] view stack. The
    restructure reuses the chunked macro-step's proven machinery (rank
-   replay from host ``pred``, boundary cuts, one stable multi-key
-   sort); the remove/annotate stamp replay also collapses — every op
-   sees every earlier in-span remove, so first-visible-remover-wins
-   degenerates to first-remover-wins (an exclusive cumulative-or over
-   lanes instead of a K-step replay loop).
+   replay from host ``pred``, boundary cuts, the stable multi-key row
+   order) but realises the order without a sort: the base rows are
+   already in key order, so the ≤ 3K new rows MERGE into place
+   (:func:`_merge_rows`, static lane shifts and selects — no
+   row-axis gather); the remove/annotate stamp replay also collapses
+   — every op sees every earlier in-span remove, so
+   first-visible-remover-wins degenerates to first-remover-wins (an
+   exclusive cumulative-or over lanes instead of a K-step replay
+   loop).
 
 Span breaks (``chunk_start``) happen only where host composition
 stops being exact: an anchor strictly inside another in-span op's
@@ -460,6 +464,74 @@ from .segment_table import (  # noqa: E402
 )
 
 
+# the per-row fields a walker step rewrites (the rest are per document)
+_ROW_FIELDS = ("length", "seq", "client", "removed_seq", "removers",
+               "op_id", "op_off", "is_marker") + tuple(
+                   f"prop{c}" for c in range(PROP_CHANNELS))
+
+
+def _shift_right(x, s: int):
+    """x[:, i - s] along the row axis, zero-filled: a static lane
+    shift, no gather."""
+    if s == 0:
+        return x
+    if s >= x.shape[1]:
+        return jnp.zeros_like(x)
+    return jnp.pad(x[:, :x.shape[1] - s], ((0, 0), (s, 0)))
+
+
+def _merge_rows(base: list, extra: list, slot, minor, K: int) -> list:
+    """The first C rows of the stable sort of ``base`` and ``extra``
+    rows by (slot, minor), with neither a sort nor a row-axis gather.
+
+    ``base`` holds [D, C] fields of rows already in key order (row j
+    has the key (j, K)); ``extra`` the same fields for N more rows,
+    [D, N], keyed by ``slot``/``minor`` [D, N]. An extra row's
+    destination counts the rows that sort before it ([D, N, N] and
+    [D, N] compares); base row j moves right by the number of extra
+    rows that sort before it, a(j) — monotone in j, so a log-depth
+    shifter moves all base rows by static lane shifts and selects, most
+    significant bit first (after each stage the rows' positions still
+    ascend, so no two rows ever meet); and each extra row lands through
+    a one-hot select over a lane-dense [D, N, C] mask. Rows that sort
+    past C (those of a document that overflows) are dropped, as the
+    walker drops them."""
+    D, C = base[0].shape
+    N = slot.shape[1]
+    pos = lax.broadcasted_iota(jnp.int32, (1, 1, C), 2)
+    # base rows before extra row t: every j < slot, and row `slot`
+    # itself when minor >= K (a tie would put base row `slot`, the lower
+    # index, first). Walker keys never tie a base row's (j, K): a valid
+    # tail's minor is (2 off + 1) K with off > 0, an event's 2 off K +
+    # rank with rank < K, and an invalid row sits at slot C + 1.
+    n_base = jnp.minimum(slot + (minor >= K).astype(jnp.int32), C)
+    s_a, s_b = slot[:, :, None], slot[:, None, :]
+    m_a, m_b = minor[:, :, None], minor[:, None, :]
+    t = jnp.arange(N, dtype=jnp.int32)
+    before = (s_a < s_b) | (s_a == s_b) & (
+        (m_a < m_b) | (m_a == m_b) & (t[:, None] < t[None, :]))
+    dest = n_base + jnp.sum(before, axis=1, dtype=jnp.int32)   # [D,N]
+    at = dest[:, :, None] == pos                               # [D,N,C]
+    hit = jnp.any(at, axis=1)                                  # [D,C]
+
+    # the shift of base row j: extra rows whose base-row count is <= j
+    amt = jnp.sum(n_base[:, :, None] <= pos, axis=1, dtype=jnp.int32)
+    cols = list(base)
+    for b in reversed(range(N.bit_length())):
+        step = 1 << b
+        go = (amt & step) != 0
+        arrive = _shift_right(go, step)
+        cols = [jnp.where(arrive, _shift_right(c, step), c) for c in cols]
+        # a row that left must not move again from the slot it vacated
+        amt = jnp.where(arrive, _shift_right(amt, step),
+                        jnp.where(go, 0, amt))
+    return [
+        jnp.where(hit, jnp.sum(jnp.where(at, x[:, :, None], 0), axis=1,
+                               dtype=x.dtype), c)
+        for c, x in zip(cols, extra)
+    ]
+
+
 def _walker_step(st: dict, ops: dict, K: int):
     """Apply one critical span of up to K ops per document. The
     structure mirrors ``merge_chunk._macro_step``; the differences ARE
@@ -636,8 +708,8 @@ def _walker_step(st: dict, ops: dict, K: int):
         # step recovers these with [D, 2K, C] masked reduces (a
         # Mosaic-safe idiom this XLA-only kernel does not need — it
         # already gathers for rank/win_val); invalid cuts read garbage
-        # from a clamped row, which is fine: their sort keys (slot C+1)
-        # park them past every live row.
+        # from a clamped row, which is fine: their keys (slot C+1) park
+        # them past every live row.
         cut_clamped = jnp.minimum(cut_slot, C - 1)
 
         def row_at(field):
@@ -654,64 +726,42 @@ def _walker_step(st: dict, ops: dict, K: int):
             mode="drop",
         )
 
-        # ---- row tables: C base + 2K tails + K events — verbatim --------
-        def rows(base, tail, event):
-            return jnp.concatenate([base, tail, event], axis=-1)
+        # ---- rows: C base rows + 2K tails + K events -------------------
+        # The base rows stay where they are: row j keeps slot j and the
+        # minor key K, so they are already in key order, and only the 3K
+        # tail and event rows ("extra" rows, [D, 3K]) carry keys that
+        # _merge_rows places them by. The minor key packs (off, is_base,
+        # rank) into one int32 — all three bounded (off < OPOFF_BOUND =
+        # 2^17, base 1 bit, rank < K) — lexicographically identical to
+        # the chunked step's three separate keys.
+        def extra(tail, event):
+            return jnp.concatenate([tail, event], axis=-1)     # [D,3K]
 
-        ev_row_valid = ev_valid
-        inval_t = jnp.where(cut_valid, cut_slot, C + 1)
-        inval_e = jnp.where(ev_row_valid, a_slot, C + 1)
+        x_slot = extra(jnp.where(cut_valid, cut_slot, C + 1),
+                       jnp.where(ev_valid, a_slot, C + 1))
+        x_minor = extra((cut_off * 2 + 1) * K,
+                        jnp.where(ev_valid, a_off, 0) * 2 * K + rank)
 
-        key_slot = rows(j, inval_t, inval_e)
-        key_off = rows(jnp.zeros((D, C), jnp.int32), cut_off,
-                       jnp.where(ev_row_valid, a_off, 0))
-        key_base = rows(jnp.ones((D, C), jnp.int32),
-                        jnp.ones((D, twoK), jnp.int32),
-                        jnp.zeros((D, K), jnp.int32))
-        key_rank = rows(jnp.zeros((D, C), jnp.int32),
-                        jnp.zeros((D, twoK), jnp.int32), rank)
-
-        r_length = rows(head_len, tail_len,
-                        jnp.where(ev_row_valid, ops["length"], 0))
-        r_seq = rows(st["seq"], row_at(st["seq"]), ops["seq"])
-        r_client = rows(st["client"], row_at(st["client"]),
-                        ops["client"])
-        r_removed = rows(
-            st["removed_seq"],
-            jnp.where(cut_valid, row_at(st["removed_seq"]),
-                      NOT_REMOVED),
-            jnp.full((D, K), NOT_REMOVED, jnp.int32),
-        )
-        r_removers = rows(
-            st["removers"].astype(jnp.int32),
-            row_at(st["removers"].astype(jnp.int32)),
-            jnp.zeros((D, K), jnp.int32),
-        )
-        r_op_id = rows(st["op_id"], row_at(st["op_id"]), ops["op_id"])
-        r_op_off = rows(st["op_off"],
-                        row_at(st["op_off"]) + cut_off,
-                        jnp.zeros((D, K), jnp.int32))
-        r_marker = rows(st["is_marker"], row_at(st["is_marker"]),
-                        ops["is_marker"])
-        r_props = [
-            rows(st[f"prop{c}"], row_at(st[f"prop{c}"]),
-                 jnp.zeros((D, K), jnp.int32))
-            for c in range(PROP_CHANNELS)
-        ]
-        r_frag_lo = rows(jnp.zeros((D, C), jnp.int32), cut_off,
-                         jnp.zeros((D, K), jnp.int32))
-        r_frag_hi = r_frag_lo + r_length
-        r_is_event = rows(jnp.zeros((D, C), jnp.int32),
-                          jnp.zeros((D, twoK), jnp.int32),
-                          ev_row_valid.astype(jnp.int32))
-        ev_bit = rows(jnp.zeros((D, C), jnp.int32),
-                      jnp.zeros((D, twoK), jnp.int32),
-                      kidx + jnp.zeros((D, K), jnp.int32))
-        r_live = rows(
-            alive.astype(jnp.int32),
-            cut_valid.astype(jnp.int32),
-            ev_row_valid.astype(jnp.int32),
-        )
+        zeros_k = jnp.zeros((D, K), jnp.int32)
+        t_removed = jnp.where(cut_valid, row_at(st["removed_seq"]),
+                              NOT_REMOVED)
+        b_rows = {f: st[f] for f in _ROW_FIELDS}
+        b_rows["length"] = head_len
+        x_rows = {
+            "length": extra(tail_len,
+                            jnp.where(ev_valid, ops["length"], 0)),
+            "seq": extra(row_at(st["seq"]), ops["seq"]),
+            "client": extra(row_at(st["client"]), ops["client"]),
+            "removed_seq": extra(t_removed,
+                                 jnp.full((D, K), NOT_REMOVED, jnp.int32)),
+            "removers": extra(row_at(st["removers"]),
+                              jnp.zeros((D, K), jnp.uint32)),
+            "op_id": extra(row_at(st["op_id"]), ops["op_id"]),
+            "op_off": extra(row_at(st["op_off"]) + cut_off, zeros_k),
+            "is_marker": extra(row_at(st["is_marker"]), ops["is_marker"]),
+        }
+        for c in range(PROP_CHANNELS):
+            x_rows[f"prop{c}"] = extra(row_at(st[f"prop{c}"]), zeros_k)
 
     with jax.named_scope("walker_stamps"):
         # ---- stamps -----------------------------------------------------
@@ -719,86 +769,75 @@ def _walker_step(st: dict, ops: dict, K: int):
         # lexicographic (slot, offset) interval test here; in a critical
         # span every op sees every S0 row, so (a) a base/tail row is
         # stampable iff it is live, not already removed in S0 (an
-        # always-visible removal), and non-empty — ONE [D, R] mask shared
-        # by every lane — and (b) the interval test collapses into the
-        # SHARED view's E-space: a stampable row's absolute extent is
-        # [E[slot]+frag_lo, E[slot]+frag_hi) and lane k stamps it iff that
-        # extent lies inside [pos1, pos2) (positions are span-base = this
-        # same E-space; visible extents partition [0, total], so the
-        # interval compare is exactly the chunked step's six-comparison
-        # lexicographic test at two comparisons). Event rows stamp only
-        # through the host cover bitmask, as in the chunked step.
-        row_E = jnp.take_along_axis(
-            E, jnp.minimum(key_slot, C - 1), axis=1)           # [D,R]
-        row_lo = (row_E + r_frag_lo)[:, :, None]               # [D,R,1]
-        row_hi = (row_E + r_frag_hi)[:, :, None]
-        in_interval = (row_lo >= p1[:, None, :, 0]) & \
-            (row_hi <= p2[:, None, :, 0])                      # [D,R,K]
+        # always-visible removal), and non-empty — ONE mask per row
+        # shared by every lane — and (b) the interval test collapses into
+        # the SHARED view's E-space: a stampable row's absolute extent is
+        # [E[slot]+frag_lo, E[slot]+frag_lo+length) and lane k stamps it
+        # iff that extent lies inside [pos1, pos2) (positions are
+        # span-base = this same E-space; visible extents partition
+        # [0, total], so the interval compare is exactly the chunked
+        # step's six-comparison lexicographic test at two comparisons).
+        # Event rows stamp only through the host cover bitmask, as in
+        # the chunked step. Masks are [D, K, rows], rows on the minor
+        # axis; a base row's E is E itself (its slot is j), a tail's is
+        # its parent row's.
+        def in_interval(row_lo, length, stampable):
+            lo = row_lo[:, None, :]                            # [D,1,n]
+            return stampable[:, None, :] & \
+                (lo >= ops["pos1"][:, :, None]) & \
+                (lo + length[:, None, :] <= ops["pos2"][:, :, None])
 
-        row_stampable = (
-            (r_live > 0) & (r_removed == NOT_REMOVED)
-            & (r_length > 0) & (r_is_event == 0)
-        )                                                      # [D,R]
-        base_stamp = in_interval & row_stampable[:, :, None] & \
-            (is_range & taken)[:, None, :]                     # [D,R,K]
-        cover = (
-            (ops["ev_cover"][:, None, :]
-             >> ev_bit[:, :, None].astype(jnp.uint32)) & 1
-        ) > 0
-        ev_stamp = cover & (r_is_event[:, :, None] > 0) & \
-            (is_range & taken)[:, None, :]
-        raw_stamp = base_stamp | ev_stamp
+        b_raw = in_interval(
+            E, head_len,
+            alive & (st["removed_seq"] == NOT_REMOVED) & (head_len > 0))
+        t_raw = in_interval(
+            row_at(E) + cut_off, tail_len,
+            cut_valid & (t_removed == NOT_REMOVED) & (tail_len > 0))
+        e_raw = (((ops["ev_cover"][:, :, None]
+                   >> kidx[:, None, :].astype(jnp.uint32)) & 1) > 0) & \
+            ev_valid[:, None, :]                               # [D,K,K]
 
-        # first-remover-wins: every op sees every earlier in-span remove,
-        # so the chunked step's K-iteration visibility replay collapses to
-        # "the first remove lane to stamp a row owns it; later range ops
-        # skip rows an earlier remove took" — one exclusive cumulative-or
-        # over the lane axis.
-        rm_lane = (is_rem & taken)[:, None, :]                 # [D,1,K]
-        rm_raw = raw_stamp & rm_lane                           # [D,R,K]
-        prior_rm = jnp.cumsum(
-            rm_raw.astype(jnp.int32), axis=-1
-        ) - rm_raw.astype(jnp.int32) > 0
-        eff = raw_stamp & ~prior_rm
-        rm_eff = eff & rm_lane
-        ann_eff = eff & (is_ann & taken)[:, None, :]
+        rm_lane = (is_rem & taken)[:, :, None]                 # [D,K,1]
+        ann_lane = (is_ann & taken)[:, :, None]
+        rm_bits = jnp.left_shift(jnp.uint32(1),
+                                 ops["client"].astype(jnp.uint32))
 
-        # at most ONE effective remove per row (first-wins) and lane
-        # order IS sequenced order within a span, so the stamping remove
-        # is simply the FIRST rm lane — one argmax + two [D, R] gathers
-        # replace the chunked step's masked [D, R, K] min/sum reduces
-        any_rm = jnp.any(rm_eff, axis=-1)                      # [D,R]
-        rm_k = jnp.argmax(rm_eff, axis=-1).astype(jnp.int32)
-        rm_k = jnp.minimum(rm_k, K - 1)
+        def lane_pick(mask, field, init):
+            """[D,K,n] mask, [D,K] field -> [D,n]: ``field`` at the
+            LAST True lane of each row, ``init`` where there is none —
+            K lane-dense selects in lane (= sequenced) order."""
+            out = init
+            for k in range(K):
+                out = jnp.where(mask[:, k], field[:, k, None], out)
+            return out
 
-        def lane_at(field, k):
-            return jnp.take_along_axis(field, k, axis=1)
+        def stamp(raw_stamp, rows):
+            """Apply the span's removes and annotates to ``rows``."""
+            raw_stamp = raw_stamp & (is_range & taken)[:, :, None]
+            # first-remover-wins: every op sees every earlier in-span
+            # remove, so the chunked step's K-iteration visibility replay
+            # collapses to "the first remove lane to stamp a row owns it;
+            # later range ops skip rows an earlier remove took" — one
+            # exclusive cumulative-or over the lane axis. So at most ONE
+            # lane of rm_eff is set per row, and only on a row not yet
+            # removed (a stampable row, or an event row).
+            rm_raw = (raw_stamp & rm_lane).astype(jnp.int32)
+            prior_rm = jnp.cumsum(rm_raw, axis=1) - rm_raw > 0
+            eff = raw_stamp & ~prior_rm
+            rm_eff = eff & rm_lane
+            ann_eff = eff & ann_lane
+            rows["removed_seq"] = lane_pick(rm_eff, ops["seq"],
+                                            rows["removed_seq"])
+            rows["removers"] = rows["removers"] | lane_pick(
+                rm_eff, rm_bits, jnp.uint32(0))
+            # LWW per channel: the LAST candidate lane wins
+            for c in range(PROP_CHANNELS):
+                rows[f"prop{c}"] = lane_pick(
+                    ann_eff & (ops["prop_key"][:, :, None] == c),
+                    ops["prop_val"], rows[f"prop{c}"])
 
-        new_removed = jnp.where(
-            (r_removed == NOT_REMOVED) & any_rm,
-            lane_at(ops["seq"], rm_k), r_removed,
-        )
-        rm_bit = jnp.left_shift(
-            jnp.uint32(1),
-            lane_at(ops["client"], rm_k).astype(jnp.uint32),
-        )
-        new_removers = r_removers.astype(jnp.uint32) | jnp.where(
-            any_rm, rm_bit, jnp.uint32(0)
-        )
-
-        new_props = []
-        for c in range(PROP_CHANNELS):
-            cand = ann_eff & (ops["prop_key"][:, None, :] == c)
-            # LWW winner = LAST candidate lane (lane order is sequenced
-            # order): argmax over the reversed lane axis
-            any_c = jnp.any(cand, axis=-1)                     # [D,R]
-            win_k = (K - 1) - jnp.argmax(
-                cand[..., ::-1], axis=-1
-            ).astype(jnp.int32)
-            win_val = lane_at(ops["prop_val"], jnp.minimum(win_k, K - 1))
-            new_props.append(
-                jnp.where(any_c, win_val, r_props[c])
-            )
+        stamp(b_raw, b_rows)
+        stamp(extra(t_raw, e_raw), x_rows)
 
     with jax.named_scope("walker_overflow"):
         # ---- overflow ----------------------------------------------------
@@ -812,64 +851,24 @@ def _walker_step(st: dict, ops: dict, K: int):
         overflow_now = new_count > C
         keep = ~overflow_now
 
-    with jax.named_scope("walker_sort"):
-        # ---- one stable multi-key sort ----------------------------------
-        # (off, is_base, rank) pack into ONE int32 minor key — all three
-        # are bounded (off < OPOFF_BOUND = 2^17, base 1 bit, rank < K), so
-        # the composite is lexicographically identical to the chunked
-        # step's three separate keys — and the sort carries only the keys
-        # plus an iota: the resulting PERMUTATION gathers the ten field
-        # arrays afterwards. XLA's stable sort moves every operand through
-        # every comparator, so a 12-operand sort (the chunked step's
-        # shape) costs ~4x this 3-operand one on CPU.
-        key_minor = (key_off * 2 + key_base) * K + key_rank
-        R = C + 3 * K
-        iota_r = jnp.broadcast_to(
-            jnp.arange(R, dtype=jnp.int32)[None], (D, R))
-        _, _, perm = jax.lax.sort(
-            [key_slot, key_minor, iota_r], dimension=-1, is_stable=True,
-            num_keys=2,
-        )
-
-    with jax.named_scope("walker_permute"):
-        def permute(arr):
-            return jnp.take_along_axis(arr, perm, axis=1)
-
-        s_len = permute(r_length)
-        s_seq = permute(r_seq)
-        s_cli = permute(r_client)
-        s_rem = permute(new_removed)
-        s_rrs = permute(new_removers.astype(jnp.int32))
-        s_oid = permute(r_op_id)
-        s_ooff = permute(r_op_off)
-        s_mark = permute(r_marker)
-        s_props = [permute(p) for p in new_props]
+    with jax.named_scope("walker_merge"):
+        merged = _merge_rows([b_rows[f] for f in _ROW_FIELDS],
+                             [x_rows[f] for f in _ROW_FIELDS],
+                             x_slot, x_minor, K)
 
     with jax.named_scope("walker_update"):
-        def upd(old, new):
-            return jnp.where(keep[:, None], new[:, :C], old)
-
         out = {
-            "length": upd(st["length"], s_len),
-            "seq": upd(st["seq"], s_seq),
-            "client": upd(st["client"], s_cli),
-            "removed_seq": upd(st["removed_seq"], s_rem),
-            "removers": jnp.where(
-                keep[:, None], s_rrs[:, :C].astype(jnp.uint32),
-                st["removers"],
-            ),
-            "op_id": upd(st["op_id"], s_oid),
-            "op_off": upd(st["op_off"], s_ooff),
-            "is_marker": upd(st["is_marker"], s_mark),
-            "count": jnp.where(keep, new_count, st["count"]),
-            "min_seq": jnp.maximum(
+            f: jnp.where(keep[:, None], new, st[f])
+            for f, new in zip(_ROW_FIELDS, merged)
+        }
+        out.update(
+            count=jnp.where(keep, new_count, st["count"]),
+            min_seq=jnp.maximum(
                 st["min_seq"],
                 jnp.max(jnp.where(taken, ops["min_seq"], 0), axis=-1),
             ),
-            "overflow": jnp.where(overflow_now, 1, st["overflow"]),
-        }
-        for c in range(PROP_CHANNELS):
-            out[f"prop{c}"] = upd(st[f"prop{c}"], s_props[c])
+            overflow=jnp.where(overflow_now, 1, st["overflow"]),
+        )
     return out, take_upto, overflow_now
 
 

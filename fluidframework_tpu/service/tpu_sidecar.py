@@ -299,8 +299,9 @@ class SeqShardedPool:
         self.mesh = mesh
         self.n_seq = n_seq
         self.capacity = per_doc_capacity
-        # the chunked/egwalker macro-steps' global multi-key sort does
-        # not decompose over a slot-sharded axis, so those routes
+        # the chunked macro-step's global multi-key sort and the
+        # egwalker step's global row merge do not decompose over a
+        # slot-sharded axis, so those routes
         # apply only on a degenerate (n_seq == 1) mesh; a real seq
         # mesh keeps the scan-collective executor (docs/PERF.md) and
         # SAYS SO once (counter + stderr, _warn_route_once) — the

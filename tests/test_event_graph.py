@@ -6,22 +6,32 @@ oracle and the C++ replayer) is the ground truth; the egwalker route —
 shared-chain critical-prefix composition + walker macro-steps + the
 scan suffix for genuinely concurrent tails — must reproduce its live
 rows bit-for-bit (garbage rows beyond ``count`` may differ: the
-permutation-gather restructure parks different garbage than the
-shift-based one). The three-route sweeps live in test_merge_chunk.py;
+walker's row merge parks different garbage than the scan's shift).
+The walker's merge itself is held to the stable sort it replaces, bit
+for bit over every row it keeps. The three-route sweeps live in
+test_merge_chunk.py;
 this suite owns the graph semantics (criticality, frontier, parents,
 prefix split), the span-compiler break conditions, and the route
 validation discipline.
 """
+import functools
+import re
+
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import lax
 
 from fluidframework_tpu.ops import build_batch, encode_stream, make_table
 from fluidframework_tpu.ops.event_graph import (
     EG_K,
     EXECUTOR_ROUTES,
+    _merge_rows,
     apply_batch_egwalker,
     apply_window_egwalker,
     build_event_graph,
+    compiled_window,
 )
 from fluidframework_tpu.ops.merge_kernel import apply_window_impl
 from fluidframework_tpu.ops.segment_table import (
@@ -460,6 +470,116 @@ def test_walker_prefix_applies_without_the_convenience_wrapper():
         padded[f][:, :W] = np.array(getattr(batch, f), np.int32)
     seq_tab = apply_window_impl(make_table(1, 256), OpBatch(**padded))
     assert_live_equal(seq_tab, eg_tab, "prefix-only")
+
+
+# ======================================================================
+# the walker's row merge: the stable sort it replaces, bit for bit
+
+
+@functools.lru_cache(maxsize=None)
+def _jit_merge(K: int):
+    return jax.jit(lambda b, x, s, m: _merge_rows(b, x, s, m, K))
+
+
+def _sorted_rows(base, extra, slot, minor, K):
+    """The order the walker used to build: a stable sort of base + extra
+    rows by (slot, minor), base row j keyed (j, K), each field permuted
+    by take_along_axis; the first C rows."""
+    D, C = base[0].shape
+    keys = (
+        jnp.concatenate([jnp.broadcast_to(jnp.arange(C, dtype=jnp.int32),
+                                          (D, C)), slot], axis=1),
+        jnp.concatenate([jnp.full((D, C), K, jnp.int32), minor], axis=1),
+        jnp.broadcast_to(jnp.arange(C + slot.shape[1], dtype=jnp.int32),
+                         (D, C + slot.shape[1])),
+    )
+    perm = lax.sort(keys, dimension=-1, is_stable=True, num_keys=2)[2]
+    return [jnp.take_along_axis(jnp.concatenate([b, x], axis=1), perm,
+                                axis=1)[:, :C]
+            for b, x in zip(base, extra)]
+
+
+def _walker_keys(rng, D, C, K):
+    """Keys of the 3K extra rows as the walker builds them: 2K tails
+    (valid: a live slot, offset > 0, minor (2 off + 1) K; invalid: slot
+    C + 1, offset 0) then K events (valid: a slot up to ``count``, the
+    event slot at ``count`` included, offset 0 or > 0, rank < K, minor
+    2 off K + rank; invalid: slot C + 1). Slots come from four per
+    document, so many rows share a slot, and some share a whole key."""
+    count = rng.integers(1, C + 1, D)
+    hot = rng.integers(0, count[:, None], (D, 4))
+    pick = np.take_along_axis(hot, rng.integers(0, 4, (D, 3 * K)), axis=1)
+    t_valid = rng.random((D, 2 * K)) < 0.6
+    t_off = np.where(t_valid, rng.integers(1, 4, (D, 2 * K)), 0)
+    t_slot = np.where(t_valid, pick[:, :2 * K], C + 1)
+    e_valid = rng.random((D, K)) < 0.7
+    e_slot = np.where(rng.random((D, K)) < 0.25, count[:, None],
+                      pick[:, 2 * K:])
+    e_off = np.where(rng.random((D, K)) < 0.5, 0,
+                     rng.integers(1, 4, (D, K)))
+    e_off = np.where(e_valid, e_off, 0)
+    rank = rng.integers(0, K, (D, K))
+    slot = np.concatenate([t_slot, np.where(e_valid, e_slot, C + 1)], 1)
+    minor = np.concatenate([(2 * t_off + 1) * K, 2 * e_off * K + rank], 1)
+    return slot.astype(np.int32), minor.astype(np.int32)
+
+
+@pytest.mark.parametrize("ties", [False, True])
+@pytest.mark.parametrize("seed", [0, 1])
+@pytest.mark.parametrize("K,C", [(EG_K, 40), (EG_K, 160), (3, 40)])
+def test_row_merge_equals_the_stable_sort(K, C, seed, ties):
+    """``_merge_rows`` against ``lax.sort(..., is_stable=True,
+    num_keys=2)`` + ``take_along_axis`` on random key tables with the
+    walker's structure, every field (int32 and the uint32 removers)
+    bit for bit. The walker's keys never tie a base row's (j, K) —
+    asserted on the generated tables; with ``ties`` some extra rows are
+    given a base row's key anyway, and the merge still keeps the stable
+    sort's order (the base row first)."""
+    rng = np.random.default_rng(1000 * K + 10 * C + seed)
+    D, N = 6, 3 * K
+    slot, minor = _walker_keys(rng, D, C, K)
+    assert not np.any((slot < C) & (minor == K))
+    if ties:
+        at = rng.random((D, N)) < 0.2
+        slot = np.where(at, rng.integers(0, C, (D, N)), slot)
+        minor = np.where(at, K, minor).astype(np.int32)
+    base = [jnp.asarray(rng.integers(-2**31, 2**31, (D, C)), jnp.int32),
+            jnp.asarray(rng.integers(0, 50, (D, C)), jnp.int32),
+            jnp.asarray(rng.integers(0, 2**32, (D, C)), jnp.uint32)]
+    extra = [jnp.asarray(rng.integers(-2**31, 2**31, (D, N)), jnp.int32),
+             jnp.asarray(rng.integers(50, 99, (D, N)), jnp.int32),
+             jnp.asarray(rng.integers(0, 2**32, (D, N)), jnp.uint32)]
+    slot, minor = jnp.asarray(slot), jnp.asarray(minor)
+    got = _jit_merge(K)(base, extra, slot, minor)
+    want = _sorted_rows(base, extra, slot, minor, K)
+    for f, (g, w) in enumerate(zip(got, want)):
+        assert g.dtype == w.dtype, f
+        assert np.array_equal(np.asarray(g), np.asarray(w)), (
+            f"field {f}\nmerge {np.asarray(g)}\nsort  {np.asarray(w)}")
+
+
+def test_walker_module_has_no_row_gather_and_no_sort():
+    """The per-element gathers stay gone from the walker: in its lowered
+    module (StableHLO, the same on every backend) no gather yields a row
+    axis, C or R = C + 3K wide, and nothing sorts. Only the small
+    [D, <= 3K] and [D] gathers remain."""
+    C = 64
+    R = C + 3 * EG_K
+    streams = [record_sequential_stream(seed=s, n_steps=30)[1]
+               for s in range(4)]
+    batch = build_batch([encode_stream(s) for s in streams])
+    program = build_event_graph(_arrays(batch))
+    fn, args = compiled_window(make_table(4, C), program["prefix"])
+    text = fn.lower(*args).as_text()
+    widths = [  # the minor dimension of each gather's result
+        int(m.group(1).rstrip("x").split("x")[-1])
+        for m in re.finditer(
+            r'"stablehlo\.gather".*-> tensor<((?:\d+x)+)[a-z]+\d*>', text)
+    ]
+    assert widths, "no gather found: the pattern no longer reads the text"
+    assert not [w for w in widths if w in (C, R)], widths
+    assert max(widths) <= 3 * EG_K
+    assert "stablehlo.sort" not in text
 
 
 # ======================================================================

@@ -357,8 +357,8 @@ def test_compiled_programs_carry_the_stage_scopes():
     fn, args = compiled_window(table, program["prefix"], K=EG_K)
     walker = fn.lower(*args).compile().as_text()
     for scope in ("gather_ops", "walker_take", "walker_view", "walker_rows",
-                  "walker_stamps", "walker_overflow", "walker_sort",
-                  "walker_permute", "walker_update"):
+                  "walker_stamps", "walker_overflow", "walker_merge",
+                  "walker_update"):
         assert f"/{scope}/" in walker, scope
     batch = OpBatch(**{f: jnp.asarray(arrays[f]) for f in OpBatch._fields})
     scan = scan_window().lower(table, batch).compile().as_text()
