@@ -43,6 +43,7 @@ import os
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from .merge_step import (
     OPOFF_BOUND,
@@ -51,7 +52,7 @@ from .merge_step import (
     state_to_table,
     table_to_state,
 )
-from .segment_table import NOT_REMOVED, OpBatch, SegmentTable
+from .segment_table import NOT_REMOVED, PROP_CHANNELS, OpBatch, SegmentTable
 
 
 def _env_unroll() -> int:
@@ -206,31 +207,98 @@ def pad_capacity(table: SegmentTable, new_capacity: int) -> SegmentTable:
     )
 
 
+def _shift_rows(x, s, right: bool):
+    """x[:, i - s] (``right``) or x[:, i + s] along the row axis,
+    zero-filled, for a traced shift 0 <= s <= C: a dynamic slice of the
+    zero-padded plane, no gather."""
+    C = x.shape[1]
+    if right:
+        return lax.dynamic_slice_in_dim(
+            jnp.pad(x, ((0, 0), (C, 0))), C - s, C, axis=1)
+    return lax.dynamic_slice_in_dim(
+        jnp.pad(x, ((0, 0), (0, C))), s, C, axis=1)
+
+
+def _partition_rows(planes: list, left_amt, right_amt):
+    """Two log shifters over the [D, C] ``planes``: one moves row j
+    left by ``left_amt[:, j]``, the other right by ``right_amt[:, j]``,
+    least significant bit first; a row with amount 0 stays put.
+
+    Stage b moves the rows whose amount has bit b set by 2^b, carrying
+    their amount along; the slot a row leaves keeps stale values with
+    amount 0, so it never moves again, and an arriving row overwrites
+    it. This needs compaction's amounts: of two moving rows j1 < j2,
+    the one shifted toward the other has the larger amount, by less
+    than j2 - j1 (left, z(j2) - z(j1) counts non-kept rows strictly
+    between them; right, r(j1) - r(j2) kept ones). After the stages
+    below b a row has moved by its amount mod 2^b, and for a >= a'
+    (a mod 2^b) - (a' mod 2^b) <= a - a' < j2 - j1: the gap between
+    the two rows never closes, so moving rows keep their order and no
+    arrival lands on a row that stays. Most significant bit first, as
+    ``event_graph._merge_rows`` runs, does not hold here: two rows can
+    cross.
+
+    The stages run as a loop with a traced shift, not unrolled: device
+    code is device memory for as long as the program is loaded, and on
+    a TPU v5e the unrolled stages compiled to 18 MB of it at 1,024 x
+    1,024, the loop to 8 MB."""
+
+    def stage(b, carry):
+        left, right, left_amt, right_amt = carry
+        step = jnp.left_shift(jnp.int32(1), b)
+        moved = []
+        for cols, amt, to_right in ((left, left_amt, False),
+                                    (right, right_amt, True)):
+            go = (amt & step) != 0
+            arrive = _shift_rows(go, step, to_right)
+            cols = [jnp.where(arrive, _shift_rows(c, step, to_right), c)
+                    for c in cols]
+            amt = jnp.where(arrive, _shift_rows(amt, step, to_right),
+                            jnp.where(go, 0, amt))
+            moved.append((cols, amt))
+        (left, left_amt), (right, right_amt) = moved
+        return left, right, left_amt, right_amt
+
+    stages = (planes[0].shape[1] - 1).bit_length()
+    left, right, _, _ = lax.fori_loop(
+        0, stages, stage, (planes, planes, left_amt, right_amt))
+    return left, right
+
+
 @jax.jit
 def compact(table: SegmentTable) -> SegmentTable:
     """Zamboni kernel (mergeTree.ts:800): drop tombstones at/below the
-    collab window, compacting live slots to the slab head."""
+    collab window, compacting live slots to the slab head.
 
-    def one(tab: SegmentTable) -> SegmentTable:
-        j = jnp.arange(tab.capacity, dtype=jnp.int32)
-        alive = j < tab.count
-        drop = alive & (tab.removed_seq != NOT_REMOVED) & (
-            tab.removed_seq <= tab.min_seq
+    The stable partition of rows into kept ones first and the rest
+    after, each group in its old order (what ``argsort(~keep,
+    stable=True)`` gives, the tail past ``count`` included), with
+    neither a sort nor a row-axis gather: a kept row moves left by the
+    non-kept rows before it, any other row right by the kept rows after
+    it, each group through a log shifter of lane shifts and
+    selects (``_partition_rows``), and the two results join at the
+    new count."""
+    with jax.named_scope("compact"):
+        j = jnp.arange(table.capacity, dtype=jnp.int32)[None, :]
+        alive = j < table.count[:, None]
+        drop = alive & (table.removed_seq != NOT_REMOVED) & (
+            table.removed_seq <= table.min_seq[:, None]
         )
         keep = alive & ~drop
-        src = jnp.argsort(~keep, stable=True)
-        return tab._replace(
-            length=tab.length[src],
-            seq=tab.seq[src],
-            client=tab.client[src],
-            removed_seq=tab.removed_seq[src],
-            removers=tab.removers[src],
-            op_id=tab.op_id[src],
-            op_off=tab.op_off[src],
-            is_marker=tab.is_marker[src],
-            prop=tab.prop[src],
-            count=keep.sum().astype(jnp.int32),
+        kept_through = jnp.cumsum(keep, axis=1, dtype=jnp.int32)
+        count = kept_through[:, -1]
+        names = ("length", "seq", "client", "removed_seq", "removers",
+                 "op_id", "op_off", "is_marker")
+        left, right = _partition_rows(
+            [getattr(table, f) for f in names] + [
+                table.prop[:, :, c] for c in range(PROP_CHANNELS)],
+            jnp.where(keep, j + 1 - kept_through, 0),
+            jnp.where(keep, 0, count[:, None] - kept_through))
+        head = j < count[:, None]
+        out = [jnp.where(head, a, b) for a, b in zip(left, right)]
+        n = len(names)
+        return table._replace(
+            **dict(zip(names, out[:n])),
+            prop=jnp.stack(out[n:], axis=-1),
+            count=count,
         )
-
-    with jax.named_scope("compact"):
-        return jax.vmap(one)(table)

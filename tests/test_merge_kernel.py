@@ -5,12 +5,16 @@ Every fuzz stream is applied both by a fresh oracle client (pure remote
 apply) and by the kernel; final text and per-position property
 signatures must match exactly.
 """
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from fluidframework_tpu.models.mergetree import MergeTreeClient
 from fluidframework_tpu.ops import (
     NOT_REMOVED,
+    PROP_CHANNELS,
+    SegmentTable,
     apply_window,
     build_batch,
     compact,
@@ -121,6 +125,116 @@ def test_kernel_compaction_preserves_content():
     removed = after["removed_seq"][0, :cnt]
     assert not ((removed != NOT_REMOVED)
                 & (removed <= int(after["min_seq"][0]))).any()
+
+
+@jax.jit
+def _compact_by_argsort(table):
+    """The argsort + gather formulation of ``compact``, kept as the
+    oracle its gather-free form must match bit for bit."""
+
+    def one(tab):
+        j = jnp.arange(tab.capacity, dtype=jnp.int32)
+        alive = j < tab.count
+        drop = alive & (tab.removed_seq != NOT_REMOVED) & (
+            tab.removed_seq <= tab.min_seq
+        )
+        keep = alive & ~drop
+        src = jnp.argsort(~keep, stable=True)
+        return tab._replace(
+            length=tab.length[src],
+            seq=tab.seq[src],
+            client=tab.client[src],
+            removed_seq=tab.removed_seq[src],
+            removers=tab.removers[src],
+            op_id=tab.op_id[src],
+            op_off=tab.op_off[src],
+            is_marker=tab.is_marker[src],
+            prop=tab.prop[src],
+            count=keep.sum().astype(jnp.int32),
+        )
+
+    return jax.vmap(one)(table)
+
+
+def _compaction_cases(C: int, seed: int) -> SegmentTable:
+    """One document per case: a random mix, count 0, count = C, no
+    drops, all dropped, drops at the head, at the tail and in runs,
+    and removals at min_seq (dropped) and min_seq + 1 (kept). Every
+    other field is random over its whole range, removers with bit 31
+    set, so a row that lands in the wrong slot shows."""
+    rng = np.random.default_rng(seed)
+    D = 9
+    i32 = np.iinfo(np.int32)
+
+    def rand(shape):
+        return rng.integers(i32.min, i32.max, shape, dtype=np.int64,
+                            endpoint=True).astype(np.int32)
+
+    min_seq = rng.integers(10, 1000, D).astype(np.int32)
+    ms = min_seq[:, None]
+    dropped = ms - rng.integers(0, 10, (D, C))   # <= min_seq
+    kept = ms + rng.integers(1, 10, (D, C))      # removed above it
+    live = np.full((D, C), NOT_REMOVED, np.int64)
+    removed = np.where(rng.random((D, C)) < 0.5, dropped,
+                       np.where(rng.random((D, C)) < 0.5, kept, live))
+    count = rng.integers(0, C + 1, D)
+    j = np.arange(C)
+    count[1] = 0
+    count[2] = C
+    removed[3] = np.where(removed[3] <= ms[3], kept[3], removed[3])
+    count[3] = C
+    removed[4] = dropped[4]
+    count[4] = C
+    k = rng.integers(1, C + 1)
+    removed[5] = np.where(j < k, dropped[5], live[5])
+    count[5] = C
+    count[6] = rng.integers(1, C + 1)
+    k = rng.integers(1, count[6] + 1)
+    removed[6] = np.where((j >= count[6] - k) & (j < count[6]),
+                          dropped[6], kept[6])
+    runs = np.cumsum(rng.random(C) < 0.2) % 2 == 1  # alternating runs
+    removed[7] = np.where(runs, dropped[7], live[7])
+    removed[8] = ms[8] + rng.integers(0, 2, C)
+    count[8] = rng.integers(C // 2, C + 1)
+    removers = rng.integers(0, 2**32, (D, C), dtype=np.uint64)
+    removers[:, ::3] |= 1 << 31
+    return SegmentTable(
+        length=jnp.asarray(rand((D, C))),
+        seq=jnp.asarray(rand((D, C))),
+        client=jnp.asarray(rand((D, C))),
+        removed_seq=jnp.asarray(removed.astype(np.int32)),
+        removers=jnp.asarray(removers.astype(np.uint32)),
+        op_id=jnp.asarray(rand((D, C))),
+        op_off=jnp.asarray(rand((D, C))),
+        is_marker=jnp.asarray(rand((D, C))),
+        prop=jnp.asarray(rand((D, C, PROP_CHANNELS))),
+        count=jnp.asarray(count.astype(np.int32)),
+        min_seq=jnp.asarray(min_seq),
+        overflow=jnp.asarray(rng.integers(0, 2, D).astype(np.int32)),
+    )
+
+
+@pytest.mark.parametrize("seed", range(3))
+@pytest.mark.parametrize("capacity", [16, 48, 64, 1024])
+def test_compact_matches_argsort_form(capacity, seed):
+    """The whole table, every field and every row past ``count`` too,
+    equals the argsort + gather formulation bit for bit."""
+    table = _compaction_cases(capacity, seed * 7919 + capacity)
+    got, want = fetch(compact(table)), fetch(_compact_by_argsort(table))
+    for f in SegmentTable._fields:
+        assert got[f].dtype == want[f].dtype, f
+        np.testing.assert_array_equal(got[f], want[f], err_msg=f)
+    # the cases reach what they are named for
+    assert list(want["count"][[1, 4]]) == [0, 0]
+    assert want["count"][3] == capacity
+
+
+def test_compact_lowers_without_sort_or_gather():
+    """Compaction moves rows by static lane shifts and selects: the
+    lowered program holds no sort and no gather."""
+    text = compact.lower(make_table(3, 48)).as_text()
+    assert "stablehlo.sort" not in text
+    assert "stablehlo.gather" not in text
 
 
 def test_kernel_overflow_flag():
