@@ -211,14 +211,13 @@ def native_texts(recorded: list) -> list:
 
 
 def phase_compile(sz: dict, meter: CompileMeter) -> dict:
-    """Ahead-of-time compiles of the three executor routes' window
+    """Ahead-of-time compiles of the two executor routes' window
     programs at the serving shape (docs x capacity, first window
     bucket): cold, then again after dropping JAX's in-memory caches so
     the persistent cache serves it."""
     import jax
 
-    from fluidframework_tpu.ops import make_table, merge_chunk
-    from fluidframework_tpu.ops import event_graph, merge_kernel
+    from fluidframework_tpu.ops import event_graph, make_table, merge_kernel
     from fluidframework_tpu.ops.host_bridge import pack_rows
     from fluidframework_tpu.ops.segment_table import KIND_NOOP, OpBatch
 
@@ -234,10 +233,6 @@ def phase_compile(sz: dict, meter: CompileMeter) -> dict:
         "egwalker": event_graph.compiled_window(
             table, event_graph.build_event_graph(
                 arrays, k_max=event_graph.EG_K)["prefix"]),
-        "chunked": merge_chunk.compiled_window(
-            table, merge_chunk.compile_chunks(
-                arrays, k_max=merge_chunk.CHUNK_K),
-            K=merge_chunk.CHUNK_K),
     }
     out = {"phase": "compile", "docs": sz["docs"],
            "capacity": sz["capacity"], "window": 16}

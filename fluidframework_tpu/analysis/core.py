@@ -25,7 +25,6 @@ DEFAULT_ROOTS = (
     "fluidframework_tpu",
     "tests",
     "examples",
-    "bench.py",
     "__graft_entry__.py",
 )
 
@@ -204,7 +203,7 @@ FAMILIES = ("layercheck", "jaxhazards", "lockcheck", "obscheck",
             "wirecheck", "failcheck")
 
 # rule id -> owning family: tooling that groups ONE combined run's
-# findings per family (bench's fluidlint_findings records) reads
+# findings per family (the gate's per-family counts) reads
 # this instead of re-running the analysis once per family. The gate
 # test pins it complete against FAMILIES.
 FAMILY_RULES = {

@@ -44,7 +44,7 @@ from .core import (
 # deliberate, justified exception or blessing — widen with review only)
 
 # Path components where the unladdered-jit-shape rule applies: the
-# serving kernel layer. tests/ and bench.py dispatch deliberately
+# serving kernel layer. tests/ dispatch deliberately
 # exact-fit shapes (fuzz sweeps, shape-cliff measurements) — that is
 # their job, and each runs a bounded number of shapes once; the storm
 # the rule exists to stop is an unladdered shape source on the SERVING
@@ -53,8 +53,7 @@ LADDER_SCOPE_COMPONENTS = ("ops", "parallel", "service", "tools")
 
 # Functions whose RESULTS carry ladder-governed shapes (relpath
 # suffix, qualname). _pack_rows buckets via BucketLadder internally;
-# compile_chunks/build_chunked are shape-preserving rewrites of packed
-# arrays; make_table's capacities come from the ladder's rungs at
+# make_table's capacities come from the ladder's rungs at
 # every serve-path call site (prewarm/regrow walk capacity_rungs) and
 # fresh tables are setup-time, not per-flush.
 LADDER_SOURCES = (
@@ -62,8 +61,6 @@ LADDER_SOURCES = (
     # the sidecar re-exports it as _pack_rows (both names resolve)
     ("ops/host_bridge.py", "pack_rows"),
     ("service/tpu_sidecar.py", "_pack_rows"),
-    ("ops/merge_chunk.py", "compile_chunks"),
-    ("ops/merge_chunk.py", "build_chunked"),
     # the event-graph compiler re-buckets its prefix/suffix windows
     # through the BucketLadder internally (same contract as pack_rows)
     ("ops/event_graph.py", "build_event_graph"),
@@ -84,28 +81,12 @@ LADDER_SOURCES = (
 # or shape argument display) -> justification. Keys mirror finding
 # keys so an entry here is exactly one suppressed finding.
 LADDERED_CALLS: dict[tuple[str, str, str], str] = {
-    # K is the chunked factory's cache key — the static
+    # K is the egwalker factory's cache key — the static
     # program-selection knob, not a per-dispatch shape. These sites
-    # pass the module constant CHUNK_K: exactly one program per
-    # route, and prewarm dispatches through the same K, so the one
-    # compile is paid before serving. A DATA-DEPENDENT K elsewhere
-    # still gets flagged (one XLA program per distinct value).
-    ("tpu_sidecar.py", "SeqShardedPool._apply",
-     "apply_window_chunked[K]"):
-        "K=CHUNK_K module constant; pool prewarm walks it",
-    ("tpu_sidecar.py", "TpuMergeSidecar._apply_program",
-     "apply_window_chunked[K]"):
-        "K=CHUNK_K module constant; prewarm walks the chunked route",
-    ("tpu_sidecar.py", "TpuMergeSidecar._apply_program",
-     "apply_window_chunked_pingpong[K]"):
-        "K=CHUNK_K module constant; prewarm walks the ping-pong jits",
-    ("mesh_pool.py", "MeshShardedPool._apply",
-     "apply_window_chunked[K]"):
-        "K=CHUNK_K module constant (single-shard chunked fast path); "
-        "MeshShardedPool.prewarm walks it",
-    # EG_K is the egwalker factory's static program-selection
-    # constant, exactly like CHUNK_K for the chunked route: one
-    # program per route, prewarm dispatches through the same K.
+    # pass the module constant EG_K: exactly one program per route,
+    # and prewarm dispatches through the same K, so the one compile
+    # is paid before serving. A DATA-DEPENDENT K elsewhere still gets
+    # flagged (one XLA program per distinct value).
     ("tpu_sidecar.py", "TpuMergeSidecar._apply_program",
      "apply_window_egwalker[K]"):
         "K=EG_K module constant; prewarm walks the egwalker route",
@@ -869,7 +850,7 @@ class _LadderAnalysis:
                 elif isinstance(node.func, ast.Attribute):
                     # a method call's result derives from its
                     # receiver: ``state.items()`` is as laddered as
-                    # ``state`` (the pallas padding false positive).
+                    # ``state``.
                     # NOT so for module-attr calls (``jnp.asarray``):
                     # the base is an import alias, not a value
                     root = node.func.value
@@ -1119,7 +1100,7 @@ def _check_unladdered(idx: _Index, jits_by_file: dict) -> list[Finding]:
                             "BucketLadder (or a static_argnums "
                             "slot): every distinct shape here is a "
                             "20-40s XLA compile mid-serve — pack "
-                            "through _pack_rows/compile_chunks or a "
+                            "through _pack_rows or a "
                             "BucketLadder bucket, or register a "
                             "reviewed exception in "
                             "shapecheck.LADDERED_CALLS"
@@ -1543,7 +1524,6 @@ def ladder_bounds(window_floor: int, max_bucket: int,
                   capacity: int, max_capacity: int,
                   executor: str = "scan",
                   donate: bool = False,
-                  pallas: bool = False,
                   pool_capacity: Optional[int] = None,
                   pool_rows: int = 1) -> dict[str, int]:
     """Static per-root compile-count bounds for a sidecar configured
@@ -1560,34 +1540,22 @@ def ladder_bounds(window_floor: int, max_bucket: int,
         # one program per (window bucket x capacity rung)
         "apply_window": shapes,
         "apply_window_pingpong": shapes if donate else 0,
-        "chunked": shapes,
-        "chunked_pingpong": shapes if donate else 0,
         "egwalker": shapes,
         "egwalker_pingpong": shapes if donate else 0,
         # one per capacity rung
         "compact": n_rungs,
         # one per rung TRANSITION
         "pad_capacity": max(n_rungs - 1, 0),
-        "pallas": shapes if pallas else 0,
     }
     if executor == "scan":
-        bounds["chunked"] = 0
-        bounds["chunked_pingpong"] = 0
         bounds["egwalker"] = 0
         bounds["egwalker_pingpong"] = 0
-    elif executor == "egwalker":
+    else:
         # the walker covers critical prefixes; concurrent SUFFIXES
         # dispatch the PLAIN scan jit per rung x bucket (never the
         # ping-pong form — the suffix input is the walker stage's
         # live output), and prewarm walks both programs
-        bounds["chunked"] = 0
-        bounds["chunked_pingpong"] = 0
         bounds["apply_window_pingpong"] = 0
-    else:
-        bounds["apply_window"] = 0
-        bounds["apply_window_pingpong"] = 0
-        bounds["egwalker"] = 0
-        bounds["egwalker_pingpong"] = 0
     if pool_capacity is not None:
         # MeshShardedPool jit roots (per-shard ladder x sharding
         # signatures): ``pool_rows`` is the largest per-shard row
@@ -1605,19 +1573,12 @@ def ladder_bounds(window_floor: int, max_bucket: int,
         if not (window_floor <= chunk <= max_bucket):
             n_windows += 1
         bounds["mesh_pool"] = rb * n_windows * 2
-        if executor in ("chunked", "egwalker"):
-            # BOTH pool tiers route these executors through the
-            # CHUNKED kernel on a degenerate mesh (the seq pool's
-            # n_seq==1 fast path, the mesh pool's single-shard fast
-            # path; an egwalker pool deliberately routes chunked —
-            # pool dispatches are full-history replays): those
-            # programs ride the shared merge_chunk jit cache at the
-            # pool's own (row bucket x window/replay-chunk x sharding
-            # signature) shapes, ON TOP of whatever the primary route
-            # compiles there — without this allowance a correctly
-            # laddered pooled egwalker sidecar would read as a
-            # recompile storm (bounds['chunked'] == 0)
-            bounds["chunked"] += rb * n_windows * 2
+        # on a one-device mesh BOTH pool tiers apply the plain scan
+        # jit, whatever the sidecar's route: those programs ride the
+        # shared apply_window cache at the pool's own (row bucket x
+        # window/replay-chunk x sharding signature) shapes, ON TOP of
+        # whatever the primary route compiles there
+        bounds["apply_window"] += rb * n_windows * 2
         # one gather program per pool table shape (x2 sharding sigs).
         # The migration handoff ALWAYS donates on backends that
         # support it (shard_moves.migrate_rows routes on the backend,
@@ -1647,9 +1608,8 @@ def infer_kernel_output(root: str, spec: dict,
     silently stops preserving a shape or widens a dtype fails there
     BY NAME."""
     identity_roots = {
-        "apply_window", "apply_window_pingpong", "chunked",
-        "chunked_pingpong", "egwalker", "egwalker_pingpong",
-        "compact", "seq_shard", "pallas",
+        "apply_window", "apply_window_pingpong", "egwalker",
+        "egwalker_pingpong", "compact", "seq_shard",
     }
     if root in identity_roots:
         return {f: (tuple(shape), dtype)

@@ -140,7 +140,7 @@ _replay_error: Optional[str] = None
 
 def load_merge_replay() -> Optional[ctypes.CDLL]:
     """Build + load the C++ scalar merge replayer (the compiled
-    baseline for bench.py); None when the toolchain is unavailable."""
+    baseline); None when the toolchain is unavailable."""
     global _replay_lib, _replay_error
     with _lock:
         if _replay_lib is not None:

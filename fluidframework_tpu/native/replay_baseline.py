@@ -1,9 +1,8 @@
 """Python face of the C++ scalar merge replayer (merge_replay.cpp).
 
-Used by bench.py as the compiled-language baseline (the stand-in for
-the reference's Node.js merge-tree — no Node runtime exists in this
-image) and by tests as a third differential implementation next to the
-Python oracle and the batched kernel.
+A compiled-language baseline (the stand-in for the reference's
+Node.js merge-tree) and, in tests, a third differential implementation
+next to the Python oracle and the batched kernel.
 """
 from __future__ import annotations
 

@@ -4,8 +4,7 @@ label sets, Prometheus-style text exposition plus a JSON snapshot.
 One process-wide :data:`REGISTRY` replaces the ad-hoc private
 counters the service modules used to keep: ingress, sequencer, the
 TPU sidecar, the seq-sharded pool, the broker and moira all register
-families here, ``bench.py`` snapshots the registry into every stage
-record, the ingress serves it over the ``metrics`` frame, and
+families here, the ingress serves it over the ``metrics`` frame, and
 ``python -m fluidframework_tpu.service --dump-metrics`` is the
 /metrics-equivalent CLI.
 

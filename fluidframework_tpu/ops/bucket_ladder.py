@@ -1,14 +1,14 @@
 """The (docs, window, capacity) shape ladder — ONE definition.
 
-``apply_window`` / ``apply_window_chunked`` compile per input shape
+``apply_window`` and the egwalker walker compile per input shape
 (20-40s each on the real chip), so every dispatch pads its window to a
 rung of this ladder and every capacity grow doubles along it. The
 ladder used to live implicitly in three places (``_pack_rows``'s
 bucket loop, ``prewarm``'s nested loops, the regrow doubling) — any
 drift between them meant a mid-serve XLA compile that ``prewarm``
 never saw. This module is the single source the sidecar's pack path,
-``prewarm``, and the bench stages all share: if ``prewarm`` walked it,
-steady-state serving cannot hit an uncompiled shape.
+``build_event_graph`` and ``prewarm`` all share: if ``prewarm`` walked
+it, steady-state serving cannot hit an uncompiled shape.
 """
 from __future__ import annotations
 

@@ -42,17 +42,17 @@ This module is that idea translated to the batched SoA table world:
 
 3. WALKER KERNEL (:func:`apply_window_egwalker`, device half): the
    critical prefix is composed on the host by ONE SHARED span chain
-   (``merge_chunk._Chain``, but cross-client — every op in a critical
-   span sees every earlier one, so the exact own-chain composition
-   generalizes to the whole span with no cross-client chunk breaks)
-   and applied in macro-steps of up to ``EG_K`` ops. Because every op
-   in the span is critical, its view of the span-base state S0 is THE
-   SAME full-visibility view (``alive & ~removed``): one view pass +
-   one prefix-sum per macro-step, shared by every lane, where the
-   chunked executor pays a per-lane [D, K, C] view stack. The
-   restructure reuses the chunked macro-step's proven machinery (rank
-   replay from host ``pred``, boundary cuts, the stable multi-key row
-   order) but realises the order without a sort: the base rows are
+   (:class:`_Chain`, cross-client — every op in a critical span sees
+   every earlier one, so an exact own-chain composition generalizes
+   to the whole span with no cross-client breaks) and applied in
+   macro-steps of up to ``EG_K`` ops. Because every op in the span is
+   critical, its view of the span-base state S0 is THE SAME
+   full-visibility view (``alive & ~removed``): one view pass + one
+   prefix-sum per macro-step, shared by every lane, where a
+   non-critical op would need a per-lane [D, K, C] view stack. The
+   restructure replays ranks from host ``pred``, cuts boundary
+   segments and orders rows by a stable multi-key order, but
+   realises the order without a sort: the base rows are
    already in key order, so the ≤ 3K new rows MERGE into place
    (:func:`_merge_rows`, static lane shifts and selects — no
    row-axis gather); the remove/annotate stamp replay also collapses
@@ -64,8 +64,8 @@ This module is that idea translated to the batched SoA table world:
 Span breaks (``chunk_start``) happen only where host composition
 stops being exact: an anchor strictly inside another in-span op's
 text, the ``EG_K`` lane cap, or the narrow aging-collision residue
-below. The chunk compiler's min_seq-aging breaks are SPLIT instead of
-broken (Eg-walker's internal-run splitting, arXiv 2409.14252): an
+below. Breaks on min_seq aging are SPLIT instead of
+taken (Eg-walker's internal-run splitting, arXiv 2409.14252): an
 open-span tombstone that ages out of the stop set is split out of the
 anchor walk by the chain itself (``_Chain._locate`` with the
 exclusive ``ms`` watermark), and a committed tombstone crossing
@@ -74,16 +74,16 @@ min_seq mid-span is resolved exactly by the device's per-lane
 exact anchor coordinate across the aging boundary does the span still
 break (same-anchor rank groups would split). Absorbed breaks are
 counted per row in ``program["span_splits"]``. Cross-client
-visibility — the chunk compiler's main break — never breaks a
+visibility never breaks a
 critical span: that is where the throughput comes from.
 
 Semantics contract: bit-identical live slot state to the sequential
-executor (tests/test_event_graph.py + the three-route sweeps in
-tests/test_merge_chunk.py pin it differentially), with the chunked
-executor's overflow semantics: a document whose span restructure
-would exceed capacity is flagged and PARKED at its pre-span state
-(the sidecar's snapshot re-apply recovery absorbs the difference,
-exactly as for the chunked route).
+executor (tests/test_event_graph.py and the fuzz sweeps in
+tests/test_walker_differential.py pin it differentially), except
+after an overflow: a document whose span restructure would exceed
+capacity is flagged and PARKED at its pre-span state, where the scan
+keeps applying (the sidecar's snapshot re-apply recovery absorbs the
+difference).
 """
 from __future__ import annotations
 
@@ -100,20 +100,20 @@ from .segment_table import (
     KIND_REMOVE,
     OpBatch,
 )
-from .merge_chunk import (
-    CHUNK_FIELDS,
-    _Chain,
-)
 
-# The three sidecar executor routes — ONE registry (service and both
-# pool tiers validate against it; docs/PERF.md "Executor routes").
-EXECUTOR_ROUTES = ("scan", "chunked", "egwalker")
+# extra per-op int32 arrays the span compiler emits alongside OpBatch
+CHUNK_FIELDS = ("chunk_start", "pred", "ev_cover")
+
+# The sidecar's executor routes — ONE registry (the sidecar validates
+# against it; docs/PERF.md "Executor routes"). The backend picks one
+# (tpu_sidecar.default_executor); string pools always run the scan.
+EXECUTOR_ROUTES = ("scan", "egwalker")
 
 # Walker macro-step lane count. Must be <= 31 (the ev_cover bitmask is
-# int32, like the chunk compiler's k_max); 16 doubles the chunked
-# route's per-step amortization while keeping the [D, C+3K, K] stamp
-# pass bounded. A static program-selection constant, not a per-
-# dispatch shape (the LADDERED_CALLS discipline: prewarm walks it).
+# int32); 16 amortizes a step over many ops while keeping the
+# [D, C+3K, K] stamp pass bounded. A static program-selection
+# constant, not a per-dispatch shape (the LADDERED_CALLS discipline:
+# prewarm walks it).
 EG_K = 16
 
 
@@ -185,19 +185,160 @@ def _graph_arrays(kind, seq, refseq, client, base_head):
     return parent_seq, parent_own, frontier_other, critical
 
 
+class _Seg:
+    """One segment of a client's own-view composition. ``base_len`` is
+    the span's width in the client's FROZEN BASE VIEW (what the device
+    resolves against — own-removed base text keeps counting there
+    until the chunk materializes); ``view_len`` is its width in the
+    client's CURRENT own view; ``ev_k`` >= 0 marks own in-chunk insert
+    text (zero base width); ``rm_seq`` records the sequence number of
+    the in-chunk remove that zeroed this segment's view (None = never
+    removed) — the event-splitting walkers use it to age tombstone
+    segments out of the anchor walk (``_locate`` with ``ms``)."""
+
+    __slots__ = ("base_len", "view_len", "ev_k", "rm_seq")
+
+    def __init__(self, base_len, view_len, ev_k=-1):
+        self.base_len = base_len
+        self.view_len = view_len
+        self.ev_k = ev_k
+        self.rm_seq = None
+
+
+class _Chain:
+    """A client's own-op composition within the open chunk."""
+
+    def __init__(self, refseq: int):
+        self.refseq = refseq
+        self.segs: list[_Seg] = []  # implicit infinite base tail after
+
+    def _locate(self, pos: int, ms=None):
+        """Own-view pos -> (seg index, offset, base coord). The walk
+        stops at the FIRST zero-view segment once the position is
+        consumed (a sequenced insert tie-breaks BEFORE zero-width
+        slots at its point — breakTie, seq > slot seq always on the
+        sequenced path) — UNLESS ``ms`` is given and the segment is an
+        in-chunk tombstone whose remove has aged at/below it
+        (``rm_seq <= ms``): an aged tombstone leaves the stop set
+        (fused_step's ``below`` mask), so the walk passes THROUGH it —
+        this is the event split that lets the egwalker span survive
+        min_seq aging. Index len(segs) = the infinite base tail."""
+        base = 0
+        rem = pos
+        for i, s in enumerate(self.segs):
+            if rem < s.view_len or (rem == 0 and s.view_len == 0):
+                if ms is not None and s.view_len == 0 \
+                        and s.rm_seq is not None and s.rm_seq <= ms:
+                    base += s.base_len
+                    continue
+                return i, rem, base + (rem if s.ev_k < 0 else 0)
+            rem -= s.view_len
+            base += s.base_len
+        return len(self.segs), rem, base + rem
+
+    def map_insert(self, pos: int, length: int, k: int, ms=None):
+        """Place own insert at own-view ``pos``. Returns
+        (base_coord, pred, ok); ok False => the anchor falls strictly
+        inside own event text (chunk must break). ``ms`` (the
+        EXCLUSIVE min_seq watermark for this op — before its own
+        min_seq applies, matching the device's ``ms_pre`` cummax)
+        ages in-chunk tombstone segments out of the anchor walk."""
+        i, off, base = self._locate(pos, ms)
+        if off > 0:
+            if i < len(self.segs):
+                seg = self.segs[i]
+                if seg.ev_k >= 0:
+                    return 0, -1, False
+                tail = _Seg(seg.base_len - off, seg.view_len - off)
+                seg.base_len = off
+                seg.view_len = off
+                self.segs.insert(i + 1, tail)
+            else:
+                self.segs.append(_Seg(off, off))
+            i += 1
+        # pred: nearest preceding own event within the zero-base run
+        # just before the insertion point (the walk lands right after
+        # the own text it consumed)
+        pred = -1
+        q = i - 1
+        while q >= 0 and self.segs[q].base_len == 0:
+            if self.segs[q].ev_k >= 0:
+                pred = self.segs[q].ev_k
+                break
+            q -= 1
+        self.segs.insert(i, _Seg(0, length, ev_k=k))
+        return base, pred, True
+
+    def map_range(self, p1: int, p2: int):
+        """Map own-view range [p1, p2) to base coords + fully-covered
+        own events. Returns (b1, b2, cover_mask, ok)."""
+        i1, o1, b1 = self._locate(p1)
+        i2, o2, b2 = self._locate(p2)
+        for idx, off in ((i1, o1), (i2, o2)):
+            if idx < len(self.segs) and off > 0 \
+                    and self.segs[idx].ev_k >= 0:
+                return 0, 0, 0, False
+        cover = 0
+        i, off, _ = self._locate(p1)
+        rem = p2 - p1
+        while rem > 0 and i < len(self.segs):
+            s = self.segs[i]
+            avail = s.view_len - off
+            if avail > 0:
+                take = min(avail, rem)
+                if s.ev_k >= 0 and off == 0 and take == s.view_len:
+                    cover |= 1 << s.ev_k
+                rem -= take
+            off = 0
+            i += 1
+        return b1, b2, cover, True
+
+    def apply_remove(self, p1: int, p2: int, seq=None) -> None:
+        """Materialize own remove in the own view (base widths stay —
+        the device counts the text until the chunk materializes).
+        ``seq`` stamps the zeroed segments' ``rm_seq`` so a later
+        ``_locate(..., ms)`` can age them out of the anchor walk."""
+        for p in (p2, p1):  # split p2 first so indices stay valid
+            i, off, _ = self._locate(p)
+            if off > 0 and i < len(self.segs):
+                seg = self.segs[i]
+                assert seg.ev_k < 0, "event split rejected earlier"
+                tail = _Seg(seg.base_len - off, seg.view_len - off)
+                seg.base_len = off
+                seg.view_len = off
+                self.segs.insert(i + 1, tail)
+            elif off > 0:
+                self.segs.append(_Seg(off, off))
+        i, off, _ = self._locate(p1)
+        rem = p2 - p1
+        while rem > 0 and i < len(self.segs):
+            s = self.segs[i]
+            if s.view_len:
+                take = min(s.view_len - off, rem)
+                if off == 0:
+                    rem -= s.view_len if s.view_len <= rem else rem
+                    s.view_len = max(0, s.view_len - take)
+                    if s.view_len == 0:
+                        s.rm_seq = seq
+                else:  # pragma: no cover - boundaries were split
+                    rem -= take
+            off = 0
+            i += 1
+
+
 def _compile_span_row(out, chunk_start, pred, ev_cover, span_splits,
                       d: int, k_max: int) -> None:
     """Compose one document's critical prefix into spans with ONE
-    shared chain (the chunk compiler's per-client chain machinery,
-    applied span-wide: every op is critical, so every earlier in-span
+    shared chain (:class:`_Chain`'s own-op composition, applied
+    span-wide: every op is critical, so every earlier in-span
     op is visible to it and the composition is exact cross-client).
     Rewrites positions into span-base coordinates in place and emits
     chunk_start/pred/ev_cover.
 
     EVENT SPLITTING (the Eg-walker internal-run split, arXiv
     2409.14252 §"splitting items", translated to the span chain):
-    where the chunk compiler breaks on min_seq aging, this compiler
-    SPLITS THE EVENT and keeps composing —
+    where a per-client composition would break on min_seq aging, this
+    compiler SPLITS THE EVENT and keeps composing —
 
     - an OPEN-SPAN remove aging into ``below``: the aged tombstone
       segment is split out of the anchor walk by ``_Chain._locate``'s
@@ -213,9 +354,8 @@ def _compile_span_row(out, chunk_start, pred, ev_cover, span_splits,
       live row) and the device cannot replay their relative order, so
       the span breaks (the narrow residue of the seed-90007 class).
 
-    Every absorbed would-be break counts into ``span_splits[d]`` —
-    the config14 ``span_splits_per_doc`` evidence that the launches
-    saved are real. Breaks that remain: the ``k_max`` lane cap, an
+    Every absorbed would-be break counts into ``span_splits[d]`` (each
+    one a walker step saved). Breaks that remain: the ``k_max`` lane cap, an
     anchor strictly inside another in-span op's text, and the
     same-coordinate aging collision above."""
     kind = out["kind"]
@@ -283,10 +423,11 @@ def _compile_span_row(out, chunk_start, pred, ev_cover, span_splits,
             fresh(w)
         else:
             # count the span breaks event-splitting absorbed (each
-            # would have been a fresh() under the chunk compiler's
-            # aging conditions): an open-span tombstone aging out of
-            # the anchor walk, or a committed tombstone crossing
-            # min_seq before an insert without a coordinate collision
+            # would have been a fresh() under a plain per-client
+            # composition's aging conditions): an open-span tombstone
+            # aging out of the anchor walk, or a committed tombstone
+            # crossing min_seq before an insert without a coordinate
+            # collision
             if rm_open and rm_open[0] <= ms_k:
                 span_splits[d] += 1
                 while rm_open and rm_open[0] <= ms_k:
@@ -393,8 +534,7 @@ def build_event_graph(arrays: dict, base_head=None, k_max: int = EG_K,
     ladder = BucketLadder(window_floor=window_floor)
 
     # per-row count of would-be span breaks event-splitting absorbed
-    # (feeds egwalker_span_splits_total and config14's
-    # span_splits_per_doc — the launches-saved evidence)
+    # (feeds egwalker_span_splits_total)
     span_splits = np.zeros(D, np.int32)
     program: dict = {"egwalker": True, "k": k_max, "graph": graph,
                      "prefix": None, "suffix": None,
@@ -450,18 +590,48 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 from jax import lax  # noqa: E402
 
-from .merge_chunk import (  # noqa: E402
-    BIG,
-    _chunk_state,
-    _chunk_unstate,
-    _gather_ops,
-)
 from .merge_kernel import apply_window  # noqa: E402
+from .merge_step import state_to_table, table_to_state  # noqa: E402
 from .segment_table import (  # noqa: E402
     NOT_REMOVED,
     PROP_CHANNELS,
     SegmentTable,
 )
+
+BIG = jnp.int32(2**30)
+
+
+def _gather_ops(ops_w: dict, cursor: jnp.ndarray, K: int) -> dict:
+    """Slice the next K ops per doc from [D, W] arrays. Beyond-window
+    lanes read as NOOP chunk starts (they stop the take)."""
+    W = ops_w["kind"].shape[1]
+    with jax.named_scope("gather_ops"):
+        idx = cursor[:, None] + jnp.arange(K, dtype=jnp.int32)[None]
+        cidx = jnp.minimum(idx, W - 1)
+        out = {
+            f: jnp.take_along_axis(a, cidx, axis=1)
+            for f, a in ops_w.items()
+        }
+        off_end = idx >= W
+        out["kind"] = jnp.where(off_end, KIND_NOOP, out["kind"])
+        out["chunk_start"] = jnp.where(
+            off_end, 1, out["chunk_start"]
+        )
+    return out
+
+
+def _chunk_state(table: SegmentTable) -> dict:
+    st = table_to_state(table)
+    # doc-scalar fields flat [D] in this executor
+    for f in ("count", "min_seq", "overflow"):
+        st[f] = st[f][..., 0]
+    return st
+
+
+def _chunk_unstate(st: dict) -> SegmentTable:
+    for f in ("count", "min_seq", "overflow"):
+        st[f] = st[f][..., None]
+    return state_to_table(st, SegmentTable)
 
 
 # the per-row fields a walker step rewrites (the rest are per document)
@@ -533,9 +703,9 @@ def _merge_rows(base: list, extra: list, slot, minor, K: int) -> list:
 
 
 def _walker_step(st: dict, ops: dict, K: int):
-    """Apply one critical span of up to K ops per document. The
-    structure mirrors ``merge_chunk._macro_step``; the differences ARE
-    the fast path — annotated inline. Returns (state', take, over)."""
+    """Apply one critical span of up to K ops per document; what the
+    criticality of every op saves is annotated inline. Returns
+    (state', take, over)."""
     D, C = st["length"].shape
     kidx = jnp.arange(K, dtype=jnp.int32)[None]            # [1,K]
 
@@ -557,11 +727,11 @@ def _walker_step(st: dict, ops: dict, K: int):
         # Every op in a critical span has seen every seq in S0, so its
         # view is the full-visibility view: all inserts visible, all
         # removals visible => vis = alive & ~removed, identical across
-        # lanes. One [D, C] pass + one cumsum replaces the chunked
-        # executor's per-lane [D, K, C] view stack. `stop` (insert
+        # lanes. One [D, C] pass + one cumsum replaces a per-lane
+        # [D, K, C] view stack. `stop` (insert
         # tie-break eligibility) is the ONLY lane-dependent mask: a lane's
         # `below` watermark is the exclusive running max of earlier taken
-        # lanes' min_seq (the chunked step's ms_pre cummax — the
+        # lanes' min_seq (the ms_pre cummax — the
         # sequential executor applies an op's min_seq AFTER its view
         # pass), so a committed tombstone aging MID-SPAN resolves exactly
         # instead of forcing a span break (the event-splitting win). The
@@ -591,12 +761,11 @@ def _walker_step(st: dict, ops: dict, K: int):
 
         # ---- batched resolve of all K lanes against the shared view -----
         # All searches run on BOOLEAN [D, K, C] masks reduced by argmax
-        # (first-True index — exactly the chunked step's masked min-index,
+        # (first-True index — exactly a masked min-index,
         # since XLA argmax breaks ties toward the lowest index) and the
         # values at the found index come back through [D, K] gathers on
-        # the shared [D, C] prefix sums. The chunked step materializes
-        # int32 [D, K, C] `where` operands for every one of these; here
-        # the wide intermediates stay 1-byte bools.
+        # the shared [D, C] prefix sums; the wide intermediates stay 1-byte
+        # bools, never int32 [D, K, C] `where` operands.
         E3 = E[:, None, :]                                     # [D,1,C]
         incl3 = incl[:, None, :]
         p1 = ops["pos1"][..., None]                            # [D,K,1]
@@ -638,9 +807,8 @@ def _walker_step(st: dict, ops: dict, K: int):
 
     with jax.named_scope("walker_rows"):
         # ---- event ranks: replay the walk's insertion order -------------
-        # (verbatim from the chunked macro-step: pred comes from the
-        # shared span chain instead of per-client chains, so same-anchor
-        # ordering composes across clients)
+        # (pred comes from the shared span chain, so same-anchor ordering
+        # composes across clients)
         ev_valid = valid_ins & taken
         rank = jnp.zeros((D, K), jnp.int32)
         pred = ops["pred"]
@@ -704,10 +872,9 @@ def _walker_step(st: dict, ops: dict, K: int):
             ),
             axis=-1,
         )                                                      # [D,2K]
-        # parent-row fields for tails: a plain batched gather. The chunked
-        # step recovers these with [D, 2K, C] masked reduces (a
-        # Mosaic-safe idiom this XLA-only kernel does not need — it
-        # already gathers for rank/win_val); invalid cuts read garbage
+        # parent-row fields for tails: a plain batched gather (not
+        # [D, 2K, C] masked reduces — this XLA-only kernel already
+        # gathers for rank/win_val); invalid cuts read garbage
         # from a clamped row, which is fine: their keys (slot C+1) park
         # them past every live row.
         cut_clamped = jnp.minimum(cut_slot, C - 1)
@@ -733,7 +900,7 @@ def _walker_step(st: dict, ops: dict, K: int):
         # _merge_rows places them by. The minor key packs (off, is_base,
         # rank) into one int32 — all three bounded (off < OPOFF_BOUND =
         # 2^17, base 1 bit, rank < K) — lexicographically identical to
-        # the chunked step's three separate keys.
+        # three separate keys.
         def extra(tail, event):
             return jnp.concatenate([tail, event], axis=-1)     # [D,3K]
 
@@ -765,7 +932,7 @@ def _walker_step(st: dict, ops: dict, K: int):
 
     with jax.named_scope("walker_stamps"):
         # ---- stamps -----------------------------------------------------
-        # The chunked executor computes per-(row, op) visibility and a
+        # A non-critical op would need per-(row, op) visibility and a
         # lexicographic (slot, offset) interval test here; in a critical
         # span every op sees every S0 row, so (a) a base/tail row is
         # stampable iff it is live, not already removed in S0 (an
@@ -775,12 +942,11 @@ def _walker_step(st: dict, ops: dict, K: int):
         # [E[slot]+frag_lo, E[slot]+frag_lo+length) and lane k stamps it
         # iff that extent lies inside [pos1, pos2) (positions are
         # span-base = this same E-space; visible extents partition
-        # [0, total], so the interval compare is exactly the chunked
-        # step's six-comparison lexicographic test at two comparisons).
-        # Event rows stamp only through the host cover bitmask, as in
-        # the chunked step. Masks are [D, K, rows], rows on the minor
-        # axis; a base row's E is E itself (its slot is j), a tail's is
-        # its parent row's.
+        # [0, total], so the interval compare is exactly the
+        # six-comparison lexicographic test at two comparisons).
+        # Event rows stamp only through the host cover bitmask. Masks
+        # are [D, K, rows], rows on the minor axis; a base row's E is E
+        # itself (its slot is j), a tail's is its parent row's.
         def in_interval(row_lo, length, stampable):
             lo = row_lo[:, None, :]                            # [D,1,n]
             return stampable[:, None, :] & \
@@ -815,7 +981,7 @@ def _walker_step(st: dict, ops: dict, K: int):
             """Apply the span's removes and annotates to ``rows``."""
             raw_stamp = raw_stamp & (is_range & taken)[:, :, None]
             # first-remover-wins: every op sees every earlier in-span
-            # remove, so the chunked step's K-iteration visibility replay
+            # remove, so a K-iteration visibility replay
             # collapses to "the first remove lane to stamp a row owns it;
             # later range ops skip rows an earlier remove took" — one
             # exclusive cumulative-or over the lane axis. So at most ONE
@@ -874,8 +1040,7 @@ def _walker_step(st: dict, ops: dict, K: int):
 
 def _walker_loop(st: dict, ops_w: dict, K: int) -> dict:
     """while_loop over span macro-steps until every doc's cursor
-    passes its window (overflowed docs park immediately — the chunked
-    executor's parking contract)."""
+    passes its window (overflowed docs park immediately)."""
     D = st["length"].shape[0]
     W = ops_w["kind"].shape[1]
     cursor0 = jnp.zeros((D,), jnp.int32)
@@ -899,8 +1064,8 @@ _jit_cache: dict = {}
 
 
 def _get_jit(K: int):
-    """One cache-fill site per K (the merge_chunk discipline: jitsan
-    reads this cache for compile counting)."""
+    """One cache-fill site per K (jitsan reads this cache for
+    compile counting)."""
     if K not in _jit_cache:
         _jit_cache[K] = jax.jit(
             lambda st, ops: _walker_loop(st, ops, K)
@@ -965,7 +1130,7 @@ def apply_window_egwalker_pingpong(dead: SegmentTable | None,
 def apply_batch_egwalker(table: SegmentTable, batch: OpBatch,
                          k_max: int = EG_K, base_head=None,
                          window_floor: int = 16) -> SegmentTable:
-    """Kernel-level convenience (tests, bench): build the event graph
+    """Kernel-level convenience (tests): build the event graph
     for one OpBatch and run the full route — walker over the critical
     prefix, scan over the concurrent suffix."""
     arrays = {f: np.array(getattr(batch, f), np.int32)
@@ -987,7 +1152,7 @@ def apply_batch_egwalker(table: SegmentTable, batch: OpBatch,
 def compiled_window(table: SegmentTable, prefix: dict, K: int = EG_K):
     """PUBLIC handle for AOT cost analysis of the walker: the SAME jit
     object ``apply_window_egwalker`` dispatches at this K, with the
-    traced argument structure (the merge_chunk convention)."""
+    traced argument structure."""
     args = (
         _chunk_state(table),
         {f: jnp.asarray(prefix[f])

@@ -16,30 +16,15 @@ positions created by the previous one), so the op window is a sequential
 loop; parallelism is across documents (the reference's Kafka-partition
 axis, SURVEY §2.9 axis 1).
 
-Two executors share the identical step function:
-
-- XLA (`apply_window_impl`): ``lax.scan`` over the window. Runs on any
-  backend, shards over a doc-axis mesh, and is the reference for the
-  Pallas path. HBM-bound: every scan step streams the whole table.
-- Pallas TPU (`pallas_merge.apply_window_pallas`): one kernel per doc
-  block with the segment table VMEM-RESIDENT across the entire window —
-  HBM traffic drops from O(window × table) to O(table + ops).
-
-``apply_window`` runs the XLA scan by default everywhere; the Pallas
-kernel is OPT-IN via FFTPU_PALLAS=1 on a TPU backend (correct and
-bit-identical, but Mosaic's current lane-reduce codegen loses to the
-pipelined scan on throughput — see _use_pallas).
-
-STATUS of the Pallas route (round 4): the claim that it is "the route
-to the HBM-optimal single-launch kernel" is RETIRED. The chunked
-executor (ops/merge_chunk.py) now provides launch and HBM
-amortization over K ops per step through plain XLA, without
-depending on Mosaic codegen maturing; the Pallas kernel remains as a
-correctness-proven alternative backend for the single-op step only.
+``apply_window`` runs the XLA executor (``apply_window_impl``): a
+``lax.scan`` of the fused step over the window. It runs on any
+backend and shards over a doc-axis mesh; every scan step streams the
+whole table through HBM. It is the sidecar's whole route off a TPU,
+and on a TPU it applies the concurrent suffix of every egwalker
+window (ops/event_graph.py) and every window the pools dispatch on a
+one-device mesh.
 """
 from __future__ import annotations
-
-import os
 
 import jax
 import jax.numpy as jnp
@@ -55,18 +40,10 @@ from .merge_step import (
 from .segment_table import NOT_REMOVED, PROP_CHANNELS, OpBatch, SegmentTable
 
 
-def _env_unroll() -> int:
-    """TPU scan unroll, read ONCE at import (jit caches per shape, so
-    later env changes would be silently ignored anyway). 4 was chosen
-    on an earlier shared-chip setup at window ~70 and has not been
-    re-measured on the v5e chip."""
-    try:
-        return max(1, int(os.environ.get("FFTPU_UNROLL", "4")))
-    except ValueError:
-        return 4
-
-
-_TPU_UNROLL = _env_unroll()
+# TPU scan unroll: fewer, larger scan steps amortize per-step launch
+# overhead. 4 was chosen on an earlier shared-chip setup at window ~70
+# and has not been re-measured on the v5e.
+_TPU_UNROLL = 4
 
 
 def apply_window_impl(table: SegmentTable, batch: OpBatch) -> SegmentTable:
@@ -138,41 +115,14 @@ def apply_window_pingpong(dead: SegmentTable, table: SegmentTable,
 
 def compiled_window():
     """PUBLIC handle to the exact jit object ``apply_window``
-    dispatches (for AOT cost analysis / instrumentation — bench's
-    HBM accounting); keeps callers off the private alias."""
+    dispatches (for AOT compiles and cost analysis, as chip_smoke.py's
+    compile phase does); keeps callers off the private alias."""
     return _apply_window_xla
 
 
-def _use_pallas(table: SegmentTable) -> bool:
-    """Opt-in (FFTPU_PALLAS=1). The Mosaic kernel lost to the XLA scan
-    on throughput on an earlier chip setup (Mosaic's lane-reduce
-    codegen makes the VMEM-resident body VPU-bound); it has not been
-    timed on the v5e. An opt-in it cannot honour raises: serving the
-    scan in its place would hide which kernel ran."""
-    if os.environ.get("FFTPU_PALLAS") != "1":
-        return False
-    if table.capacity % 128 != 0:
-        raise ValueError(
-            f"FFTPU_PALLAS=1: capacity {table.capacity} is not a "
-            "multiple of 128, which the Pallas kernel needs"
-        )
-    backend = jax.default_backend()
-    if backend != "tpu":
-        raise ValueError(
-            f"FFTPU_PALLAS=1: the Pallas kernel needs a TPU backend, "
-            f"not {backend!r}"
-        )
-    return True
-
-
 def apply_window(table: SegmentTable, batch: OpBatch) -> SegmentTable:
-    """Apply a [docs, window] op batch. XLA scan by default; the
-    VMEM-resident Pallas kernel when FFTPU_PALLAS=1 on TPU. Both run
-    the same fused step and agree bit-for-bit."""
-    if _use_pallas(table):
-        from .pallas_merge import apply_window_pallas
-
-        return apply_window_pallas(table, batch)
+    """Apply a [docs, window] op batch: the XLA scan, on every
+    backend."""
     return _apply_window_xla(table, batch)
 
 

@@ -77,7 +77,7 @@ TREE_EXECUTOR_ROUTES = ("atom", "macro")
 
 # Trunk-rebase ring depth: how many already-rebased trunk commits each
 # doc keeps on device for concurrency-window rebasing. A static
-# program-selection constant (the CHUNK_K discipline: one program per
+# program-selection constant (the EG_K discipline: one program per
 # shape, prewarm walks it). A commit whose ref predates the ring's
 # oldest entry is host-path (the sidecar evicts — ring_safe()).
 TRUNK_RING = 16

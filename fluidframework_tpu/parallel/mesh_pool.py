@@ -48,7 +48,6 @@ read through ``row_of``, and every rebuild replaces the table.
 """
 from __future__ import annotations
 
-import sys
 from typing import Optional
 
 import jax
@@ -61,14 +60,8 @@ from ..obs import metrics as obs_metrics
 from ..obs.heat import HeatLedger
 from ..obs.trace import stamp as _trace_stamp
 from ..ops.bucket_ladder import BucketLadder
-from ..ops.event_graph import validate_executor
 from ..ops.host_bridge import coalesce_noops, pack_rows, replay_chunked
-from ..ops.merge_chunk import (
-    CHUNK_K,
-    apply_window_chunked,
-    compile_chunks,
-)
-from ..ops.merge_kernel import compact
+from ..ops.merge_kernel import apply_window, compact
 from ..ops.merge_step import (
     batch_to_window,
     fused_step,
@@ -120,10 +113,6 @@ _M_POOL_FAULTS = obs_metrics.REGISTRY.counter(
     "pool operations deferred or retried under a transient fault "
     "(shared by NAME across the seq and mesh tiers, like the "
     "sidecar.pool_* chaos sites)", labelnames=("tier", "op"))
-_M_ROUTE_FALLBACK = obs_metrics.REGISTRY.counter(
-    "mesh_pool_route_fallback_total",
-    "chunked-route requests served by the scan window body on a "
-    "multi-shard mesh")
 
 
 # ---------------------------------------------------------------------------
@@ -203,10 +192,12 @@ class MeshShardedPool:
     remove / rebuild / dispatch_pending / prewarm / overflowed_slots /
     fetch, plus ``row_of``/``applied_upto``/``members``), so
     ``TpuMergeSidecar`` route-selects between the two tiers without
-    caring which one it holds (``select_pool``)."""
+    caring which one it holds (``select_pool``). Like that pool it
+    runs the per-op scan whatever the sidecar's route: the shard_map
+    window body on a multi-shard mesh, the plain ``apply_window`` on
+    a one-shard mesh."""
 
     def __init__(self, mesh: Mesh, per_doc_capacity: int,
-                 executor: Optional[str] = None,
                  doc_axis: str = DOC_AXIS,
                  heat_decay: float = 0.5,
                  timeline=None,
@@ -233,20 +224,6 @@ class MeshShardedPool:
         self.doc_axis = doc_axis
         self.n_shards = mesh.shape[doc_axis]
         self.capacity = per_doc_capacity
-        # the chunked/egwalker macro-steps do not ride the doc-sharded
-        # shard_map dispatch (yet); a single-shard mesh follows the
-        # executor route exactly like the degenerate seq pool (an
-        # egwalker pool routes CHUNKED there: pool dispatches are
-        # full-history replays, where the critical-prefix fast path
-        # buys nothing by construction), a multi-shard mesh uses the
-        # scan window body and says so LOUDLY once (counter + stderr,
-        # _warn_route_once). The backend-default route lives in
-        # service (default_executor); select_pool resolves it before
-        # constructing this pool — None here (direct construction)
-        # just means scan
-        validate_executor(executor, "executor")
-        self.executor = executor or "scan"
-        self._route_warned = False
         # per-shard ownership: shard_members[s][r] = sidecar slot at
         # local row r of shard s; global row = s * rows_per_shard + r
         self.shard_members: list[list[int]] = [
@@ -317,30 +294,13 @@ class MeshShardedPool:
 
     # -- dispatch ------------------------------------------------------
 
-    def _warn_route_once(self) -> None:
-        if self._route_warned:
-            return
-        self._route_warned = True
-        _M_ROUTE_FALLBACK.inc()
-        print(
-            f"fftpu: MeshShardedPool: the {self.executor} macro-step "
-            "does not ride the doc-sharded shard_map dispatch; using "
-            f"the scan window body on this {self.n_shards}-shard mesh",
-            file=sys.stderr, flush=True,
-        )
-
     def _apply(self, table, arrays):
-        if self.executor in ("chunked", "egwalker") and \
-                self.n_shards == 1:
-            out = apply_window_chunked(
-                table, compile_chunks(arrays, k_max=CHUNK_K), K=CHUNK_K
-            )
+        batch = OpBatch(**arrays)
+        if self.n_shards == 1:
+            out = apply_window(table, batch)
         else:
-            if self.executor in ("chunked", "egwalker"):
-                self._warn_route_once()
             out = apply_window_mesh_sharded(
-                table, OpBatch(**arrays), self.mesh, self.doc_axis
-            )
+                table, batch, self.mesh, self.doc_axis)
         # compact after every pool dispatch: remove-heavy histories
         # otherwise accumulate dead segments until they overflow a
         # pool that could easily hold the live text
@@ -348,7 +308,7 @@ class MeshShardedPool:
 
     def _replay_all(self, streams) -> None:
         """Rebuild the pool table and re-replay every member's
-        canonical stream in chunked sharded dispatches (the same
+        canonical stream in chunk-sized sharded dispatches (the same
         recipe — and the same replay helper — as the seq pool)."""
         self._reindex()
         if not self.row_of:

@@ -255,9 +255,8 @@ def wire_schema_fields(frame_type: str):
 
 def wire_schema_hash() -> str:
     """Content hash of the registry (canonical JSON, sha256/16) —
-    stamped into bench stage records next to fluidlint_findings and
     pinned by the protocol/WIRE_SCHEMA.json golden test, so a wire
-    change surfaces both as a bench delta and a reviewed diff."""
+    change surfaces as a reviewed diff."""
     import hashlib
     import json
 

@@ -6,20 +6,22 @@ batched into padded tensors and merge resolution runs on-device across
 thousands of documents per dispatch, while the per-client host path
 stays untouched. The sidecar subscribes to sequenced channel streams
 (deli out-topic / broadcaster fan-out), accumulates per-document
-windows, applies them with the chunked executor, and serves
+windows, applies them with the backend's executor route, and serves
 text/summary state — powering service-side summarization, replay
-validation, and the batched benchmarks.
+validation, and the benchmark.
 
 DISPATCH PIPELINE (docs/PERF.md): the apply loop is a two-stage
 pipeline. The host half (noop coalescing, vectorized ``_pack_rows``,
-the chunk compile) runs for round N+1 while the device still computes
-round N; the only host<->device sync is ``_settle`` — the designated
-boundary where round N's overflow flag is read and recovery runs.
-Dispatches ride the chunked executor (``ops/merge_chunk.py``,
-launch/HBM-amortized, bit-identical to the scan for live state) by
-default on launch-taxed backends (TPU); see ``default_executor`` for
-the backend policy and ``FFTPU_SIDECAR_EXECUTOR`` / ``executor=`` for
-the escape hatch either way. Donation is re-enabled through double
+the event-graph build) runs for round N+1 while the device still
+computes round N; the only host<->device sync is ``_settle`` — the
+designated boundary where round N's overflow flag is read and recovery
+runs. Two executor routes exist (``EXECUTOR_ROUTES``): on a TPU the
+egwalker route (``ops/event_graph.py``: the walker over each
+document's critical prefix, the scan over its concurrent suffix),
+elsewhere the per-op scan (``ops/merge_kernel.py``); both are
+bit-identical for live state. ``default_executor`` derives the route
+from the backend; ``executor=`` names one explicitly (tests run the
+chip's route on the CPU that way). Donation is re-enabled through double
 buffering: round N+1 donates the round N-1 table (provably idle —
 round N's input depended on it), never the live input, so the
 pre-dispatch snapshot regrow needs stays alive.
@@ -32,13 +34,13 @@ O(window), not O(history); JAX tables are immutable so the snapshot
 is a free handle — or, past ``max_capacity``, admits the document to
 the sequence-sharded pool / EVICTS it to a host-side scalar oracle
 replica (the retained per-document encoded stream is the durable
-source for those paths). The chunked executor PARKS an overflowed
-document at its pre-chunk state instead of applying past the flag;
-that difference is absorbed here at the policy layer — recovery
-re-applies the whole failed window from the snapshot (or replays the
-canonical stream), so both executors converge to the same served
-state. ``prewarm`` walks the shared ``BucketLadder`` so neither
-bucket jumps nor regrows ever hit an XLA compile mid-serve.
+source for those paths). The walker PARKS an overflowed document at
+its pre-span state instead of applying past the flag; that difference
+is absorbed here at the policy layer — recovery re-applies the whole
+failed window from the snapshot (or replays the canonical stream), so
+both routes converge to the same served state. ``prewarm`` walks the
+shared ``BucketLadder`` so neither bucket jumps nor regrows ever hit
+an XLA compile mid-serve.
 """
 from __future__ import annotations
 
@@ -74,7 +76,6 @@ from ..ops import (
 from ..ops.bucket_ladder import BucketLadder
 from ..ops.event_graph import (
     EG_K,
-    EXECUTOR_ROUTES,
     apply_window_egwalker,
     apply_window_egwalker_pingpong,
     build_event_graph,
@@ -84,12 +85,6 @@ from ..ops.host_bridge import (
     OP_FIELDS,
     pack_rows as _pack_rows,
     replay_chunked as _replay_chunked,
-)
-from ..ops.merge_chunk import (
-    CHUNK_K,
-    apply_window_chunked,
-    apply_window_chunked_pingpong,
-    compile_chunks,
 )
 from ..ops.merge_kernel import apply_window_pingpong
 from ..ops.segment_table import KIND_NOOP
@@ -101,11 +96,9 @@ from ..qos.faults import (
     PLANE as _CHAOS,
 )
 
-# CHUNK_K, _pack_rows and _replay_chunked live in ops/ since the
-# mesh-pool PR (merge_chunk.CHUNK_K, host_bridge.pack_rows /
-# replay_chunked — both pool tiers share them with this module); the
-# old names are re-exported above because they are part of this
-# module's de-facto surface (tests, bench's legacy-pack monkeypatch).
+# _pack_rows and _replay_chunked are host_bridge.pack_rows /
+# replay_chunked (both pool tiers share them with this module);
+# tests import _pack_rows from here.
 
 # Registry families (process aggregates across every sidecar/pool
 # instance; exact per-instance counts stay on the owning object —
@@ -146,10 +139,6 @@ _M_POOL_WATERMARK = obs_metrics.REGISTRY.gauge(
     "pool_watermark_ops", "sum of member stream watermarks")
 _M_POOL_MEMBERS = obs_metrics.REGISTRY.gauge(
     "pool_members", "documents admitted to the pool")
-_M_POOL_ROUTE_FALLBACK = obs_metrics.REGISTRY.counter(
-    "pool_route_fallback_total",
-    "SeqShardedPool chunked-route requests served by the "
-    "scan-collective executor on a real seq mesh")
 _M_DUP_DROPS = obs_metrics.REGISTRY.counter(
     "sidecar_duplicate_drops_total",
     "already-ingested sequenced messages dropped by the per-document "
@@ -183,79 +172,16 @@ _SITE_POOL_ADMIT = _CHAOS.site("sidecar.pool_admit", (KIND_ERROR,))
 _SITE_POOL_MIGRATE = _CHAOS.site("sidecar.pool_migrate", (KIND_DEFER,))
 
 
-# --- the TPU default's launch arithmetic (reviewed, not hard-coded) --
-#
-# The serving cost model is launches/window x cost/launch: with a
-# fixed per-launch cost, the route with the fewest launches per
-# dispatch window wins regardless of per-step FLOPs. LAUNCH_COST_MS is
-# a MODELED per-launch cost, not a chip measurement; chip_smoke.py
-# reports the route this picks so a chip number can replace it. The
-# launches/window column is RECORDED evidence —
-# bench config14's sequential-heavy corpus at cpu scale (BENCH PR15:
-# walker spans 14.5 vs chunked chunks 53.4 per doc window; the scan
-# route pays one fused step per op = the padded 64-op window at that
-# scale). Launch COUNTS are backend-portable (they are compiled-
-# program dispatch counts, not timings), which is what lets CPU
-# evidence drive the TPU default before real-chip numbers land.
-# ``default_executor`` derives the TPU route from this table and
-# bench config14 stamps the table + decision into its record, so a
-# re-measure that changes the winner changes the default in review.
-LAUNCH_COST_MS = 0.3
-LAUNCHES_PER_WINDOW = {
-    "scan": 64.0,       # one fused step per op in the padded window
-    "chunked": 53.4,    # chunked_chunks_per_doc, config14 sequential
-    "egwalker": 14.5,   # walker_spans_per_doc, config14 sequential
-                        # (pre-event-splitting; splitting only shrinks
-                        # it, so the flip is conservative)
-}
-
-
-def executor_flip() -> dict:
-    """The launch-arithmetic decision behind the TPU default, with
-    its inputs: per-route modeled launch cost per dispatch window and
-    the winning route. Stamped into bench config14's record so the
-    flip is reviewable data, not a constant."""
-    cost = {
-        route: round(n * LAUNCH_COST_MS, 2)
-        for route, n in LAUNCHES_PER_WINDOW.items()
-    }
-    return {
-        "launch_cost_ms": LAUNCH_COST_MS,
-        "launches_per_window": dict(LAUNCHES_PER_WINDOW),
-        "launch_ms_per_window": cost,
-        "winner": min(cost, key=cost.get),
-        "evidence": "config14 sequential-heavy graph stats (cpu "
-                    "scale); ~0.3ms/launch modeled, not measured",
-    }
-
-
 def default_executor() -> str:
-    """Service-side executor route. On a TPU backend the default is
-    DERIVED from the launch-arithmetic table above (currently the
-    egwalker route: 14.5 modeled launches/window vs chunked's 53.4 —
-    the critical-version fast path composes whole spans per launch,
-    and event splitting keeps spans open across min_seq-aging
-    boundaries). On backends without a launch tax (CPU) the
-    one-op-per-step scan stays the default — the macro-step routes'
-    [D, ..., K] resolve + sort costs several x a fused scan step
-    there and launches are ~free, so routing by the table would be a
-    measured serving REGRESSION (bench config7/config14 record
-    per-route numbers per backend).
-    ``FFTPU_SIDECAR_EXECUTOR=scan|chunked|egwalker`` overrides either
-    way (the operational escape hatch)."""
-    env = os.environ.get("FFTPU_SIDECAR_EXECUTOR")
-    if env:
-        # the escape hatch must fail LOUDLY on a typo: silently
-        # falling back to the backend default would mean an
-        # emergency route change that never happened
-        validate_executor(env, "FFTPU_SIDECAR_EXECUTOR")
-        return env
+    """The sidecar's executor route, derived from the backend: the
+    egwalker route on a TPU, the per-op scan elsewhere (the
+    macro-step's resolve and row merge cost several times a fused
+    scan step on the CPU, where launches are nearly free)."""
     import jax
 
     # a backend that fails to initialise raises here: routing it as
     # the CPU would serve a TPU deployment from the wrong route
-    backend = jax.default_backend()
-    return executor_flip()["winner"] if backend == "tpu" else "scan"
+    return "egwalker" if jax.default_backend() == "tpu" else "scan"
 
 
 class SeqShardedPool:
@@ -270,12 +196,17 @@ class SeqShardedPool:
     Admissions are rare (a document must exhaust the primary ladder),
     so the pool keeps its machinery simple and correct: admitting
     rebuilds the pool table at the next power-of-two row count and
-    re-replays every member's canonical encoded stream in chunked
+    re-replays every member's canonical encoded stream in chunk-sized
     sequence-sharded dispatches (same recipe as the primary ladder's
-    regrow)."""
+    regrow).
 
-    def __init__(self, mesh, per_doc_capacity: int,
-                 executor: Optional[str] = None):
+    The pool runs the per-op scan whatever the sidecar's route: the
+    walker's global row merge does not decompose over a slot-sharded
+    axis, so a real seq mesh applies the scan collective
+    (``parallel/seq_shard.py``), and a one-device mesh applies the
+    plain ``apply_window``."""
+
+    def __init__(self, mesh, per_doc_capacity: int):
         from ..parallel.seq_shard import SEQ_AXIS
 
         if SEQ_AXIS not in mesh.axis_names:
@@ -299,21 +230,6 @@ class SeqShardedPool:
         self.mesh = mesh
         self.n_seq = n_seq
         self.capacity = per_doc_capacity
-        # the chunked macro-step's global multi-key sort and the
-        # egwalker step's global row merge do not decompose over a
-        # slot-sharded axis, so those routes
-        # apply only on a degenerate (n_seq == 1) mesh; a real seq
-        # mesh keeps the scan-collective executor (docs/PERF.md) and
-        # SAYS SO once (counter + stderr, _warn_route_once) — the
-        # silent off-route fallback used to be invisible. On the
-        # degenerate mesh an egwalker pool routes CHUNKED: the pool
-        # only ever replays full histories (admission/rebuild), where
-        # the critical-prefix fast path buys nothing by construction
-        # (replay chunks carry arbitrary concurrency) and chunked owns
-        # the launch-amortized replay recipe.
-        validate_executor(executor, "executor")
-        self.executor = executor or default_executor()
-        self._route_warned = False
         self.members: list[int] = []      # sidecar slot per pool row
         self.row_of: dict[int, int] = {}  # sidecar slot -> row
         # per-member STREAM WATERMARK: how many of the slot's canonical
@@ -337,38 +253,14 @@ class SeqShardedPool:
             b *= 2
         return b
 
-    def _warn_route_once(self) -> None:
-        if self._route_warned:
-            return
-        self._route_warned = True
-        _M_POOL_ROUTE_FALLBACK.inc()
-        import sys
-
-        print(
-            f"fftpu: SeqShardedPool: the {self.executor} macro-step "
-            "does not decompose over a slot-sharded axis; using the "
-            f"scan-collective route on this {self.n_seq}-way seq mesh "
-            "(a docs-sharded MeshShardedPool follows the executor "
-            "route — see select_pool)",
-            file=sys.stderr, flush=True,
-        )
-
     def _apply(self, table, arrays):
         from ..parallel import apply_window_seq_sharded
 
-        if self.executor in ("chunked", "egwalker") and self.n_seq == 1:
-            # egwalker routes chunked here on purpose: pool dispatches
-            # are full-history replays, chunked's home turf (see the
-            # executor-route comment in __init__)
-            out = apply_window_chunked(
-                table, compile_chunks(arrays, k_max=CHUNK_K), K=CHUNK_K
-            )
+        batch = OpBatch(**arrays)
+        if self.n_seq == 1:
+            out = apply_window(table, batch)
         else:
-            if self.executor in ("chunked", "egwalker"):
-                self._warn_route_once()
-            out = apply_window_seq_sharded(
-                table, OpBatch(**arrays), self.mesh
-            )
+            out = apply_window_seq_sharded(table, batch, self.mesh)
         # compact after every pool dispatch: remove-heavy histories
         # otherwise accumulate dead segments until they overflow a
         # pool that could easily hold the live text (the primary
@@ -377,7 +269,7 @@ class SeqShardedPool:
 
     def _replay_all(self, streams) -> None:
         """Rebuild the pool table and re-replay every member's stream
-        (chunked sequence-sharded dispatches)."""
+        (chunk-sized pool dispatches)."""
         if not self.members:
             self._table = None
             return
@@ -519,7 +411,8 @@ def select_pool(mesh, per_doc_capacity: Optional[int] = None,
     (``TreeSeqPool``): the tree kernels' per-changeset sorts do not
     decompose over a slot-sharded axis, so that pool's capacity
     unlock is a larger chip-local slab and the merge-plane
-    ``route`` knob does not apply.
+    ``route`` knob does not apply. ``executor`` is the tree pool's
+    route; string pools run the scan and take none.
 
     - a mesh with a real ``seq`` axis (size > 1) -> ``SeqShardedPool``
       (one long document's SLOT axis split across devices);
@@ -528,7 +421,7 @@ def select_pool(mesh, per_doc_capacity: Optional[int] = None,
     - a single-shard mesh -> whichever tier matches its axis names
       (a degenerate ``seq`` mesh keeps the existing SeqShardedPool
       path; a ``docs`` mesh gets a 1-shard MeshShardedPool — both
-      follow the executor route there).
+      apply the plain ``apply_window`` there).
 
     ``route='seq'|'mesh'`` (constructor arg) or
     ``FFTPU_SIDECAR_POOL=seq|mesh`` (env, arg wins) overrides; an
@@ -554,8 +447,11 @@ def select_pool(mesh, per_doc_capacity: Optional[int] = None,
             else min(max_capacity * 4, 16384),
             executor=executor,
         )
+    if executor is not None:
+        raise ValueError(
+            f"executor={executor!r}: string pools run the scan and "
+            "take no route")
     source = "pool_route"
-    validate_executor(executor, "executor")
     if route is None:
         route = os.environ.get("FFTPU_SIDECAR_POOL") or None
         source = "FFTPU_SIDECAR_POOL"
@@ -588,17 +484,10 @@ def select_pool(mesh, per_doc_capacity: Optional[int] = None,
             # merge step's op_off composite needs
             # capacity * OPOFF_BOUND < 2^31 (segment_table.py)
             per_doc_capacity = min(max_capacity * 4, 8192)
-        # resolve the backend-default route HERE (the mesh pool lives
-        # below service and cannot read it itself): a single-shard
-        # docs mesh must follow the chunked fast path on TPU exactly
-        # like the degenerate seq pool does
-        return MeshShardedPool(
-            mesh, per_doc_capacity,
-            executor=executor or default_executor(),
-        )
+        return MeshShardedPool(mesh, per_doc_capacity)
     if per_doc_capacity is None:
         per_doc_capacity = max_capacity * seq_n
-    return SeqShardedPool(mesh, per_doc_capacity, executor=executor)
+    return SeqShardedPool(mesh, per_doc_capacity)
 
 
 class TpuMergeSidecar:
@@ -712,8 +601,8 @@ class TpuMergeSidecar:
         self._pool = None
         if seq_mesh is not None:
             self._pool = select_pool(
-                seq_mesh, pool_capacity, executor=self.executor,
-                route=pool_route, max_capacity=max_capacity,
+                seq_mesh, pool_capacity, route=pool_route,
+                max_capacity=max_capacity,
             )
         self.pool_admit_count = 0
         self._table = make_table(max_docs, capacity)
@@ -1035,11 +924,8 @@ class TpuMergeSidecar:
 
     def _compile_program(self, arrays: dict, base_head=None) -> dict:
         """Host half of one dispatch: raw packed arrays for the scan
-        route, the compiled chunk program for the chunked route, the
-        event-graph program (critical prefix + concurrent suffix) for
-        the egwalker route."""
-        if self.executor == "chunked":
-            return compile_chunks(arrays, k_max=CHUNK_K)
+        route, the event-graph program (critical prefix + concurrent
+        suffix) for the egwalker route."""
         if self.executor == "egwalker":
             return build_event_graph(
                 arrays, base_head=base_head, k_max=EG_K,
@@ -1055,12 +941,6 @@ class TpuMergeSidecar:
             dead.capacity != table.capacity or dead.docs != table.docs
         ):
             dead = None  # shape changed (regrow): fodder is useless
-        if "chunk_start" in program:
-            if dead is not None:
-                return apply_window_chunked_pingpong(
-                    dead, table, program, K=CHUNK_K
-                )
-            return apply_window_chunked(table, program, K=CHUNK_K)
         if not program.get("egwalker"):
             batch = OpBatch(**{f: program[f] for f in OpBatch._fields})
             if dead is not None:
@@ -1173,14 +1053,11 @@ class TpuMergeSidecar:
                 queue.clear()
         pack_s = time.perf_counter() - t0
         # lanes the round dispatches: the walker's K lanes per row per
-        # step, the scan's one per row per window slot (the chunked
-        # route's loop is not counted)
+        # step, the scan's one per row per window slot
         if program.get("egwalker"):
             walker = program["walker_steps"]
             suffix = program["suffix"]
             scan = 0 if suffix is None else suffix["kind"].shape[1]
-        elif "chunk_start" in program:
-            walker = scan = 0
         else:
             walker, scan = 0, arrays["kind"].shape[1]
         stats = self.stats
@@ -1346,9 +1223,9 @@ class TpuMergeSidecar:
         pre-dispatch snapshot (content-preserving, one kernel) and
         re-apply the SAME window at the new capacity. O(window) rather
         than the old full-history re-replay — the failed dispatch
-        never mutated the snapshot (the chunked executor additionally
-        PARKS overflowed docs pre-chunk, which this re-apply
-        supersedes), so this is exact; with ``prewarm`` the
+        never mutated the snapshot (the walker additionally PARKS
+        overflowed docs pre-span, which this re-apply supersedes), so
+        this is exact; with ``prewarm`` the
         new-capacity shapes are already compiled and a warm regrow
         costs about one steady apply."""
         from ..ops.merge_kernel import pad_capacity

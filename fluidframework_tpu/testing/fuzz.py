@@ -215,7 +215,7 @@ def record_corpus_stream(index: int, seed: int, n_clients: int,
                          n_steps: int):
     """One stream of the bench corpus: every fourth stream (index % 4
     == 3) is the webflow editor mix, the rest the generic fuzz mix.
-    ``bench.py`` and ``chip_smoke.py`` both draw from here. Returns
+    ``chip_smoke.py`` draws from here. Returns
     (converged_text, stream)."""
     if index % 4 == 3:
         return record_flow_stream(seed=seed + index, n_clients=n_clients,

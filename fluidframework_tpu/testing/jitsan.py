@@ -9,8 +9,8 @@ tests pin them to each other (tests/test_jitsan.py) so an
 abstract-interpreter gap fails BY NAME instead of rotting silently:
 
 - **compile counts**: every jit root in ``ops/merge_kernel.py``,
-  ``ops/merge_chunk.py``, ``ops/pallas_merge.py`` and
-  ``parallel/seq_shard.py`` caches one executable per input
+  ``ops/event_graph.py``, ``ops/shard_moves.py``, ``ops/tree_apply.py``
+  and ``parallel/`` caches one executable per input
   signature; jitsan reads those caches (``_cache_size()`` — the
   number of distinct signatures XLA actually compiled) per ROOT.
   Differential (a): observed counts must stay <= the per-root bounds
@@ -19,7 +19,7 @@ abstract-interpreter gap fails BY NAME instead of rotting silently:
   does not contain (the recompile storm ``unladdered-jit-shape``
   exists to stop).
 - **donation traps**: the ping-pong dispatch wrappers
-  (``apply_window_pingpong`` / ``apply_window_chunked_pingpong``)
+  (``apply_window_pingpong`` / ``apply_window_egwalker_pingpong``)
   consume their ``dead`` argument — reading it afterwards is the
   ``donated-buffer-reuse`` invariant. On TPU, XLA enforces this by
   reusing the buffers (garbage reads, silently). On CPU, donation is
@@ -37,10 +37,8 @@ conftest guard that installs fluidsan) or per-test via
 
 The ``jax_compiles_total{root}`` registry counter is fed from here in
 BOTH modes: installed, every ``publish_compiles()`` call advances it
-from the live cache watermarks; uninstalled, the same call is the
-cheap cache-size probe bench embeds in stage records (next to
-``fluidlint_findings``) so a recompile regression shows up in
-BENCH_* deltas, not just in the gate.
+from the live cache watermarks; uninstalled, the same call is a cheap
+cache-size probe any harness can take.
 """
 from __future__ import annotations
 
@@ -78,8 +76,6 @@ _JIT_ATTRS = {
         "fluidframework_tpu.ops.merge_kernel", "pad_capacity"),
     "compact": (
         "fluidframework_tpu.ops.merge_kernel", "compact"),
-    "pallas": (
-        "fluidframework_tpu.ops.pallas_merge", "_call"),
     # the mesh pool's migration gather (ops/shard_moves.py): plain
     # form (CPU / prewarm) and the donating handoff form (TPU)
     "mesh_move": (
@@ -93,10 +89,6 @@ _JIT_ATTRS = {
 
 # factory caches of jit objects (dict -> jit): root -> (module, attr)
 _JIT_CACHES = {
-    "chunked": (
-        "fluidframework_tpu.ops.merge_chunk", "_jit_cache"),
-    "chunked_pingpong": (
-        "fluidframework_tpu.ops.merge_chunk", "_jit_pingpong_cache"),
     "egwalker": (
         "fluidframework_tpu.ops.event_graph", "_jit_cache"),
     "egwalker_pingpong": (
@@ -118,8 +110,6 @@ ROOTS = tuple(sorted((*_JIT_ATTRS, *_JIT_CACHES)))
 _DONATING_WRAPPERS = (
     ("fluidframework_tpu.ops.merge_kernel",
      "apply_window_pingpong", "apply_window_pingpong"),
-    ("fluidframework_tpu.ops.merge_chunk",
-     "apply_window_chunked_pingpong", "chunked_pingpong"),
     ("fluidframework_tpu.ops.event_graph",
      "apply_window_egwalker_pingpong", "egwalker_pingpong"),
     # the migration handoff consumes its SOURCE table (position 0) —
@@ -221,7 +211,7 @@ def publish_compiles() -> dict[str, int]:
     cache sizes (monotone per-root watermarks, so repeated calls
     never double-count) and return the sizes. This is the ONE feed
     for both modes: jitsan-active sessions call it after driving
-    traffic, bench calls it per stage record as the cheap probe."""
+    traffic, a harness calls it as the cheap probe."""
     sizes = probe_cache_sizes()
     with _LOCK:
         published = _STATE.published

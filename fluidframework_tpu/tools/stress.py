@@ -14,8 +14,7 @@ writer / slow-reader / summary traffic through the REAL ingress
 dispatch path — driven directly and under a MANUAL clock, so the
 whole overload scenario is deterministic (no sockets, no event loop,
 no timing races) — and reports goodput, shed counts per class, peak
-outbound depth and the registry delta. bench.py config8 sweeps it
-over offered-load multiples with the throttler on vs off.
+outbound depth and the registry delta.
 """
 from __future__ import annotations
 

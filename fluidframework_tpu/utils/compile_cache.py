@@ -3,7 +3,7 @@
 The cache path is part of every entry's key, so it must not move
 between runs: ``JAX_COMPILATION_CACHE_DIR`` when the environment sets
 it, otherwise the fixed in-checkout ``<repo>/.jax_cache`` (gitignored).
-``bench.py`` and ``chip_smoke.py`` call :func:`enable_compile_cache`;
+``chip_smoke.py`` calls :func:`enable_compile_cache`;
 no other code sets a cache path.
 """
 from __future__ import annotations

@@ -8,8 +8,8 @@ scan suffix for genuinely concurrent tails — must reproduce its live
 rows bit-for-bit (garbage rows beyond ``count`` may differ: the
 walker's row merge parks different garbage than the scan's shift).
 The walker's merge itself is held to the stable sort it replaces, bit
-for bit over every row it keeps. The three-route sweeps live in
-test_merge_chunk.py;
+for bit over every row it keeps. The largest fuzz sweeps live in
+test_walker_differential.py;
 this suite owns the graph semantics (criticality, frontier, parents,
 prefix split), the span-compiler break conditions, and the route
 validation discipline.
@@ -184,18 +184,18 @@ def test_concurrent_stream_routes_to_the_suffix():
 
 
 # ======================================================================
-# span composition: cross-client chains that the chunk compiler breaks
+# span composition: cross-client chains a per-client composition breaks
 
 
 def test_cross_client_visible_dependency_shares_a_span():
-    """The chunk compiler's main break — a cross-client VISIBLE
+    """A per-client composition's main break — a cross-client VISIBLE
     dependency — never breaks a critical span: that is where the
     egwalker throughput comes from."""
     rows = [
         dict(kind=KIND_INSERT, pos1=0, seq=1, refseq=0, client=0,
              op_id=0, length=4),
         # client 1 SAW client 0's insert and types right after it —
-        # the chunk compiler breaks here (cross-client visible
+        # a per-client chain breaks here (cross-client visible
         # ins/rm); the shared critical chain composes it exactly
         dict(kind=KIND_INSERT, pos1=4, seq=2, refseq=1, client=1,
              op_id=1, length=2),
@@ -380,7 +380,7 @@ def test_noops_advance_min_seq_through_spans():
 
 
 def test_overflow_flags_match_and_doc_parks():
-    """Walker overflow semantics = chunked's: flag + park; the
+    """Walker overflow semantics: flag + park; the
     sidecar's snapshot re-apply recovery absorbs the difference."""
     rows = [
         dict(kind=KIND_INSERT, pos1=0, seq=i + 1, refseq=i, client=0,
@@ -586,14 +586,17 @@ def test_walker_module_has_no_row_gather_and_no_sort():
 # route validation (the select_pool loud-on-typo discipline)
 
 
-def test_executor_env_typo_is_loud(monkeypatch):
+@pytest.mark.parametrize("backend,route", [
+    ("tpu", "egwalker"), ("cpu", "scan"), ("gpu", "scan")])
+def test_default_route_by_backend(monkeypatch, backend, route):
+    """The route is the backend's rule alone: egwalker on a TPU, the
+    scan everywhere else — no table, no environment knob."""
     from fluidframework_tpu.service.tpu_sidecar import default_executor
 
-    monkeypatch.setenv("FFTPU_SIDECAR_EXECUTOR", "egwalkr")
-    with pytest.raises(ValueError, match="FFTPU_SIDECAR_EXECUTOR"):
-        default_executor()
-    monkeypatch.setenv("FFTPU_SIDECAR_EXECUTOR", "egwalker")
-    assert default_executor() == "egwalker"
+    monkeypatch.setenv("FFTPU_SIDECAR_EXECUTOR", "scan")  # no knob
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert default_executor() == route
+    assert route in EXECUTOR_ROUTES
 
 
 def test_executor_constructor_typo_is_loud():
@@ -610,35 +613,32 @@ def test_executor_constructor_typo_is_loud():
     from fluidframework_tpu.parallel import make_seq_mesh
 
     mesh = make_seq_mesh(jax.devices()[:1])
-    with pytest.raises(ValueError, match="executor='chunkedd'"):
-        select_pool(mesh, 64, executor="chunkedd")
+    with pytest.raises(ValueError, match="executor='chunked'"):
+        select_pool(mesh, 64, executor="chunked")
 
 
-def test_mesh_pool_constructor_executor_typo_is_loud():
-    import jax
-
-    from fluidframework_tpu.parallel.mesh import make_mesh
-    from fluidframework_tpu.parallel.mesh_pool import MeshShardedPool
-
-    mesh = make_mesh(jax.devices()[:1])
-    with pytest.raises(ValueError, match="executor='scann'"):
-        MeshShardedPool(mesh, 64, executor="scann")
-
-
-def test_egwalker_pool_routes_chunked_on_degenerate_seq_mesh():
+def test_egwalker_pool_routes_scan_on_degenerate_seq_mesh(monkeypatch):
     """The pool tier replays full histories where the critical-prefix
-    fast path buys nothing: an egwalker pool on a single-shard seq
-    mesh takes the chunked replay path (and warns on a real one, like
-    chunked itself — pinned in test_mesh_pool for that case)."""
-    import jax
-
+    fast path buys nothing: the pool under an egwalker sidecar on a
+    single-shard seq mesh runs the plain scan and takes no route."""
+    from fluidframework_tpu.ops import merge_kernel
     from fluidframework_tpu.parallel import make_seq_mesh
-    from fluidframework_tpu.service.tpu_sidecar import SeqShardedPool
+    from fluidframework_tpu.service import TpuMergeSidecar, tpu_sidecar
 
-    pool = SeqShardedPool(make_seq_mesh(jax.devices()[:1]), 64,
-                          executor="egwalker")
-    assert pool.executor == "egwalker"
-    pool.prewarm()  # drives _apply through the chunked replay path
+    scanned = []
+
+    def spy(table, batch):
+        scanned.append(table.capacity)
+        return merge_kernel.apply_window(table, batch)
+
+    monkeypatch.setattr(tpu_sidecar, "apply_window", spy)
+    sc = TpuMergeSidecar(max_docs=2, capacity=16, max_capacity=16,
+                         executor="egwalker", pool_capacity=64,
+                         seq_mesh=make_seq_mesh(jax.devices()[:1]))
+    assert sc.executor == "egwalker"
+    assert not hasattr(sc._pool, "executor")
+    sc._pool.prewarm()  # drives _apply through the plain scan
+    assert scanned and set(scanned) == {64}
 
 
 def test_eg_k_stays_within_the_cover_bitmask():

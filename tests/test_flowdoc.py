@@ -167,7 +167,7 @@ def test_flow_workload_fuzz_converges(seed):
 
 
 def test_recorded_flow_stream_is_kernel_exact():
-    """The webflow-mix recorded stream (bench corpus member) is
+    """The webflow-mix recorded stream (a corpus member) is
     kernel-encodable within the 4 device property channels and BOTH
     executors reproduce the scalar oracle on it."""
     import jax
@@ -184,10 +184,7 @@ def test_recorded_flow_stream_is_kernel_exact():
         fetch,
         interned_signature,
     )
-    from fluidframework_tpu.ops.merge_chunk import (
-        apply_window_chunked,
-        build_chunked,
-    )
+    from fluidframework_tpu.ops.event_graph import apply_batch_egwalker
     from fluidframework_tpu.ops.merge_kernel import apply_window_impl
     from fluidframework_tpu.protocol.messages import MessageType
     from fluidframework_tpu.testing import record_flow_stream
@@ -197,8 +194,7 @@ def test_recorded_flow_stream_is_kernel_exact():
     assert len(enc.prop_keys) <= 4
     batch = build_batch([enc])
     seq = fetch(apply_window_impl(make_table(1, 1024), batch))
-    chk = fetch(apply_window_chunked(
-        make_table(1, 1024), build_chunked(batch, K=8), K=8))
+    eg = fetch(apply_batch_egwalker(make_table(1, 1024), batch))
     obs = MergeTreeClient("o")
     obs.start_collaboration("o")
     for m in stream:
@@ -206,4 +202,4 @@ def test_recorded_flow_stream_is_kernel_exact():
             obs.apply_msg(m)
     want = interned_signature(obs, enc)
     assert extract_signature(seq, enc, 0) == want
-    assert extract_signature(chk, enc, 0) == want
+    assert extract_signature(eg, enc, 0) == want

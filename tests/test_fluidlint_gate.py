@@ -1095,28 +1095,6 @@ def test_cli_sarif_mode_emits_valid_report(tmp_path, monkeypatch):
     assert report["runs"][0]["invocations"][0]["executionSuccessful"]
 
 
-def test_bench_records_carry_fluidlint_counts(monkeypatch):
-    """Stage records embed the per-family finding trajectory next to
-    metrics_registry (machine-readable debt curve across rounds).
-    FAMILIES is narrowed to the cheap non-interprocedural pair here —
-    the full-tree cleanliness of every family is the gate test's
-    job, this one pins the record SHAPE and memoization."""
-    import bench
-
-    monkeypatch.setattr(bench, "_FLUIDLINT_CACHE", None)
-    monkeypatch.setattr(bench, "_FLUIDLINT_RAN", False)
-    monkeypatch.setattr(core, "FAMILIES", ("layercheck", "qoscheck"))
-    counts = bench._fluidlint_counts()
-    assert counts is not None
-    assert set(counts) == {"layercheck", "qoscheck"}
-    for fam, c in counts.items():
-        assert set(c) == {"findings", "allowlisted"}, fam
-        # the gate keeps the live tree clean
-        assert c["findings"] == 0, (fam, c)
-    # memoized: the second call must not re-run the analyzer
-    assert bench._fluidlint_counts() is counts
-
-
 def test_cli_json_mode_exits_zero_on_clean_tree():
     """The `--json` surface BENCH/ADVICE tooling consumes: exit 0 and
     a well-formed empty report on a clean tree."""

@@ -132,30 +132,12 @@ def test_compile_counts_within_ladder_bounds_scan_route(sanitizer):
     assert counts["apply_window"] > 0  # the bound check is not vacuous
 
 
-def test_compile_counts_within_ladder_bounds_chunked_route(sanitizer):
-    ladder = BucketLadder(window_floor=16, max_bucket=32)
-    sidecar = TpuMergeSidecar(
-        max_docs=2, capacity=16, max_capacity=64, executor="chunked",
-        donate=False, ladder=ladder,
-    )
-    sidecar.prewarm()
-    server = LocalServer()
-    _drive(server, sidecar, "doc")
-    counts = sanitizer.compile_counts()
-    bounds = ladder_bounds(16, 32, 16, 64, executor="chunked",
-                           donate=False)
-    for root, bound in bounds.items():
-        assert counts[root] <= bound, (root, counts[root], bound)
-    assert counts["chunked"] > 0
-    assert counts["apply_window"] == 0  # the scan jit stayed cold
-
-
 def test_compile_counts_within_ladder_bounds_egwalker_route(sanitizer):
-    """The third executor route: a prewarmed egwalker sidecar driven
+    """The TPU's executor route: a prewarmed egwalker sidecar driven
     through real traffic — including an overflow regrow — compiles at
     most the shapes shapecheck derives per root. The walker jits AND
     the plain scan jit (the concurrent-suffix program) both stay
-    within bounds; the chunked roots stay cold."""
+    within bounds."""
     ladder = BucketLadder(window_floor=16, max_bucket=32)
     sidecar = TpuMergeSidecar(
         max_docs=2, capacity=16, max_capacity=64, executor="egwalker",
@@ -174,7 +156,6 @@ def test_compile_counts_within_ladder_bounds_egwalker_route(sanitizer):
             f"{bound} — an unladdered shape reached the kernel"
         )
     assert counts["egwalker"] > 0  # the bound check is not vacuous
-    assert counts["chunked"] == 0  # the chunked jits stayed cold
 
 
 def test_prewarm_covers_egwalker_serving_compiles(sanitizer):
@@ -223,49 +204,48 @@ def test_prewarm_covers_egwalker_serving_compiles(sanitizer):
 def test_egwalker_bounds_arithmetic():
     """The egwalker route's bound shape: walker roots get the full
     (window bucket x capacity rung) ladder, the suffix rides the
-    PLAIN scan root (never its ping-pong form), chunked roots are
-    zero — and the other routes pin the egwalker roots to zero."""
+    PLAIN scan root (never its ping-pong form) — and the scan route
+    pins the egwalker roots to zero."""
     b = ladder_bounds(16, 32, 16, 64, executor="egwalker")
     shapes = b["egwalker"]
     assert shapes > 0
     assert b["apply_window"] == shapes
     assert b["apply_window_pingpong"] == 0
     assert b["egwalker_pingpong"] == 0  # donate off
-    assert b["chunked"] == b["chunked_pingpong"] == 0
     donating = ladder_bounds(16, 32, 16, 64, executor="egwalker",
                              donate=True)
     assert donating["egwalker_pingpong"] == shapes
     assert donating["apply_window_pingpong"] == 0  # suffix stays plain
-    for other in ("scan", "chunked"):
-        cold = ladder_bounds(16, 32, 16, 64, executor=other)
-        assert cold["egwalker"] == cold["egwalker_pingpong"] == 0
-    # a POOLED egwalker (or chunked) sidecar routes pool dispatches
-    # through the chunked kernel on a degenerate mesh — the bound
-    # must grant the pool's chunked programs instead of reading a
-    # correctly laddered sidecar as a recompile storm
-    pooled = ladder_bounds(16, 32, 16, 64, executor="egwalker",
-                           pool_capacity=64, pool_rows=1)
-    assert pooled["chunked"] > 0
-    assert pooled["chunked"] == ladder_bounds(
-        16, 32, 16, 64, executor="chunked",
-        pool_capacity=64, pool_rows=1,
-    )["chunked"] - ladder_bounds(16, 32, 16, 64,
-                                 executor="chunked")["chunked"]
-    scan_pooled = ladder_bounds(16, 32, 16, 64, executor="scan",
-                                pool_capacity=64, pool_rows=1)
-    assert scan_pooled["chunked"] == 0  # scan pools ride seq_shard
+    cold = ladder_bounds(16, 32, 16, 64, executor="scan")
+    assert cold["egwalker"] == cold["egwalker_pingpong"] == 0
+    # a POOLED sidecar's pool applies the plain scan on a degenerate
+    # mesh whatever the route — the bound must grant the pool's scan
+    # programs instead of reading a correctly laddered sidecar as a
+    # recompile storm; the walker roots get nothing from the pool
+    for route in ("scan", "egwalker"):
+        alone = ladder_bounds(16, 32, 16, 64, executor=route)
+        pooled = ladder_bounds(16, 32, 16, 64, executor=route,
+                               pool_capacity=64, pool_rows=1)
+        assert pooled["apply_window"] - alone["apply_window"] == \
+            pooled["mesh_pool"] > 0
+        assert pooled["egwalker"] == alone["egwalker"]
 
 
 @pytest.fixture
 def cold_route_caches(monkeypatch):
-    """Fresh chunked/egwalker factory caches: both fill with FRESH
-    lambdas on miss, so an emptied dict yields genuinely cold
-    compiles — suite-order warm caches otherwise make cache-delta
-    non-vacuity asserts flaky (the cold_mesh_caches precedent)."""
-    from fluidframework_tpu.ops import event_graph, merge_chunk
+    """Fresh egwalker factory caches and a fresh scan jit: the
+    factories fill with FRESH lambdas on miss, so an emptied dict
+    yields genuinely cold compiles, and the scan jit wraps a fresh
+    function object — suite-order warm caches otherwise make
+    cache-delta non-vacuity asserts flaky (the cold_mesh_caches
+    precedent)."""
+    from fluidframework_tpu.ops import event_graph, merge_kernel
 
-    monkeypatch.setattr(merge_chunk, "_jit_cache", {})
-    monkeypatch.setattr(merge_chunk, "_jit_pingpong_cache", {})
+    def _fresh_scan(table, batch):
+        return merge_kernel.apply_window_impl(table, batch)
+
+    monkeypatch.setattr(
+        merge_kernel, "_apply_window_xla", jax.jit(_fresh_scan))
     monkeypatch.setattr(event_graph, "_jit_cache", {})
     monkeypatch.setattr(event_graph, "_jit_pingpong_cache", {})
     jitsan.reset()  # baseline the fresh (empty) caches
@@ -275,8 +255,9 @@ def test_pooled_egwalker_compile_counts_within_ladder_bounds(
         sanitizer, cold_route_caches):
     """The runtime half of the pooled-route bound: an egwalker
     sidecar whose documents overflow into a degenerate seq pool
-    compiles chunked POOL programs (the deliberate egwalker->chunked
-    pool routing) and still stays within ladder_bounds per root."""
+    compiles the pool's plain scan programs (pools run the scan
+    whatever the route) and still stays within ladder_bounds per
+    root."""
     from fluidframework_tpu.parallel.seq_shard import make_seq_mesh
 
     mesh = make_seq_mesh(jax.devices()[:1], doc_shards=1)
@@ -296,9 +277,11 @@ def test_pooled_egwalker_compile_counts_within_ladder_bounds(
                            pool_rows=1)
     for root, bound in bounds.items():
         assert counts[root] <= bound, (root, counts[root], bound)
-    # non-vacuity (cold caches): the pool's chunked programs AND the
-    # primary window's walker programs both actually compiled
-    assert counts["chunked"] > 0
+    # non-vacuity (cold caches): the pool's scan programs (more than
+    # the primary route alone may compile) AND the primary window's
+    # walker programs both actually compiled
+    assert counts["apply_window"] > ladder_bounds(
+        16, 16, 16, 16, executor="egwalker")["apply_window"]
     assert counts["egwalker"] > 0
 
 
@@ -477,53 +460,29 @@ def test_static_signatures_match_eval_shape_pad_capacity(rung):
 
 @pytest.mark.parametrize("rung", RUNGS)
 @pytest.mark.parametrize("bucket", BUCKETS)
-def test_static_signatures_match_eval_shape_chunked(rung, bucket):
-    from fluidframework_tpu.ops.merge_chunk import (
-        CHUNK_FIELDS,
-        _chunk_state,
-        _window_loop,
-        build_chunked,
-    )
-    import jax.numpy as jnp
-
-    st = _chunk_state(make_table(4, rung))
-    spec = {f: (tuple(a.shape), str(a.dtype)) for f, a in st.items()}
-    chunked = build_chunked(_batch(4, bucket), K=8)
-    ops_w = {f: jnp.asarray(chunked[f])
-             for f in OpBatch._fields + CHUNK_FIELDS}
-    out = jax.eval_shape(lambda s, o: _window_loop(s, o, 8), st, ops_w)
-    assert infer_kernel_output("chunked", spec) == _sig_of(out)
-
-
-def test_static_signatures_match_eval_shape_egwalker():
+def test_static_signatures_match_eval_shape_egwalker(rung, bucket):
     """Differential (b) for the walker root: shapecheck's abstract
     (shape, dtype) signature == jax.eval_shape for the egwalker
-    macro-step loop across a rung x bucket sample."""
+    macro-step loop across every rung x bucket."""
     import jax.numpy as jnp
 
     from fluidframework_tpu.ops.event_graph import (
+        CHUNK_FIELDS,
         EG_K,
+        _chunk_state,
         _walker_loop,
         build_event_graph,
     )
-    from fluidframework_tpu.ops.merge_chunk import (
-        CHUNK_FIELDS,
-        _chunk_state,
-    )
-    import numpy as np
 
-    for rung, bucket in ((16, 16), (64, 32)):
-        st = _chunk_state(make_table(4, rung))
-        spec = {f: (tuple(a.shape), str(a.dtype))
-                for f, a in st.items()}
-        arrays = {f: np.array(getattr(_batch(4, bucket), f), np.int32)
-                  for f in OpBatch._fields}
-        prefix = build_event_graph(arrays)["prefix"]
-        ops_w = {f: jnp.asarray(prefix[f])
-                 for f in OpBatch._fields + CHUNK_FIELDS}
-        out = jax.eval_shape(
-            lambda s, o: _walker_loop(s, o, EG_K), st, ops_w)
-        assert infer_kernel_output("egwalker", spec) == _sig_of(out)
+    st = _chunk_state(make_table(4, rung))
+    spec = {f: (tuple(a.shape), str(a.dtype)) for f, a in st.items()}
+    arrays = {f: np.array(getattr(_batch(4, bucket), f), np.int32)
+              for f in OpBatch._fields}
+    prefix = build_event_graph(arrays)["prefix"]
+    ops_w = {f: jnp.asarray(prefix[f])
+             for f in OpBatch._fields + CHUNK_FIELDS}
+    out = jax.eval_shape(lambda s, o: _walker_loop(s, o, EG_K), st, ops_w)
+    assert infer_kernel_output("egwalker", spec) == _sig_of(out)
 
 
 @pytest.mark.parametrize("rung", RUNGS)
@@ -541,25 +500,6 @@ def test_static_signatures_match_eval_shape_seq_shard(rung):
         table, _batch(4, 16),
     )
     assert infer_kernel_output("seq_shard", spec) == _sig_of(out)
-
-
-@pytest.mark.parametrize("rung", (128, 256))
-def test_static_signatures_match_eval_shape_pallas(rung):
-    from fluidframework_tpu.ops import pallas_merge
-    from fluidframework_tpu.ops.merge_step import (
-        OP_COLS,
-        table_to_state,
-    )
-    import jax.numpy as jnp
-
-    state = table_to_state(make_table(8, rung))
-    spec = {f: (tuple(a.shape), str(a.dtype))
-            for f, a in state.items()}
-    arrays = _pack_rows(8, {0: [NOOP]}, bucket_floor=16)
-    ops = {f: jnp.asarray(arrays[f]).astype(jnp.int32)
-           for f in OP_COLS}
-    out = jax.eval_shape(pallas_merge._pallas_call, state, ops)
-    assert infer_kernel_output("pallas", spec) == _sig_of(out)
 
 
 def test_infer_kernel_output_rejects_unknown_root():
@@ -593,29 +533,6 @@ def test_donated_table_reads_trap_on_any_backend(sanitizer):
     np.asarray(table.length)
     np.asarray(out.length)
     assert sanitizer.trips() == []
-
-
-def test_donated_chunked_state_reads_trap(sanitizer):
-    from fluidframework_tpu.ops.merge_chunk import (
-        apply_window_chunked_pingpong,
-        build_chunked,
-    )
-
-    table = make_table(2, 32)
-    dead = make_table(2, 32)
-    out = apply_window_chunked_pingpong(
-        dead, table, build_chunked(_batch(2, 16), K=8), K=8)
-    assert [e.root for e in sanitizer.donation_events()] == [
-        "chunked_pingpong"]
-    with pytest.raises(RuntimeError, match="deleted"):
-        # the deliberate post-donation read the trap exists to catch
-        np.asarray(dead.seq)  # fluidlint: disable=donated-buffer-reuse
-    np.asarray(out.length)
-    # dead=None is the explicit plain-dispatch opt-out: no trap
-    jitsan.reset()
-    apply_window_chunked_pingpong(
-        None, table, build_chunked(_batch(2, 16), K=8), K=8)
-    assert sanitizer.donation_events() == []
 
 
 def test_donated_egwalker_fodder_reads_trap(sanitizer):

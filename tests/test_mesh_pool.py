@@ -101,19 +101,56 @@ def test_select_pool_env_and_arg_override(monkeypatch):
         select_pool(seq1, 128, route="msh")
 
 
-def test_select_pool_resolves_backend_default_executor(monkeypatch):
-    """A single-shard docs mesh follows the executor route like the
-    degenerate seq pool: select_pool resolves default_executor() (the
-    mesh pool lives below service and cannot read it itself), so a
-    chunked-default backend gets the chunked fast path without the
-    caller passing executor."""
-    monkeypatch.setenv("FFTPU_SIDECAR_EXECUTOR", "chunked")
-    pool = select_pool(make_mesh(jax.devices()[:1]), 128)
-    assert isinstance(pool, MeshShardedPool)
-    assert pool.executor == "chunked"
-    monkeypatch.setenv("FFTPU_SIDECAR_EXECUTOR", "scan")
-    assert select_pool(
-        make_mesh(jax.devices()[:1]), 128).executor == "scan"
+def test_select_pool_resolves_backend_default_executor():
+    """String pools resolve no executor route at all: select_pool
+    builds them without one on any mesh, and a route passed for the
+    merge plane fails loudly instead of being ignored."""
+    for mesh in (make_mesh(jax.devices()[:1]),
+                 make_seq_mesh(jax.devices()[:1])):
+        pool = select_pool(mesh, 128)
+        assert not hasattr(pool, "executor")
+        with pytest.raises(ValueError, match="take no route"):
+            select_pool(mesh, 128, executor="egwalker")
+
+
+@pytest.mark.parametrize("route", ["scan", "egwalker"])
+@pytest.mark.parametrize("tier", ["seq", "mesh"])
+def test_pools_run_the_scan(monkeypatch, tier, route):
+    """A document that outgrows the ladder top is admitted to the
+    pool and served by the pool's scan — the plain ``apply_window``
+    on a one-device mesh, whatever the sidecar's route — equal to
+    the client's text."""
+    from fluidframework_tpu.ops import merge_kernel
+    from fluidframework_tpu.parallel import mesh_pool
+    from fluidframework_tpu.service import tpu_sidecar
+
+    module = tpu_sidecar if tier == "seq" else mesh_pool
+    scanned = []
+
+    def spy(table, batch):
+        scanned.append(table.capacity)
+        return merge_kernel.apply_window(table, batch)
+
+    monkeypatch.setattr(module, "apply_window", spy)
+    mesh = (make_seq_mesh(jax.devices()[:1]) if tier == "seq"
+            else make_mesh(jax.devices()[:1]))
+    server = LocalServer()
+    sc = TpuMergeSidecar(max_docs=2, capacity=16, max_capacity=16,
+                         executor=route, seq_mesh=mesh,
+                         pool_capacity=256)
+    c, s = _open_doc(server, [sc], "doc")
+    _grow_into_pool(c, s)
+    sc.apply()
+    sc.sync()
+    assert sc.pooled_docs() == 1
+    assert isinstance(sc._pool, SeqShardedPool if tier == "seq"
+                      else MeshShardedPool)
+    assert 256 in scanned, "the pool never ran the scan"
+    s.insert_text(0, "tail")
+    c.flush()
+    sc.apply()
+    sc.sync()
+    assert sc.text("doc", "d", "s") == s.get_text()
 
 
 def test_mesh_pool_rejects_bad_meshes():
@@ -342,61 +379,6 @@ def test_migration_racing_overflow_recovery_rebuild():
         sc.sync()
     assert mesh_sc.pooled_docs() == 5
     _assert_parity([mesh_sc, seq_sc], docs, strings)
-
-
-# ======================================================================
-# loud route fallback (the silent-fallback bugfix)
-
-
-def test_seq_pool_off_route_fallback_is_loud(capsys):
-    from fluidframework_tpu.obs import metrics as obs_metrics
-
-    pool = SeqShardedPool(make_seq_mesh(jax.devices()[:4]), 256,
-                          executor="chunked")
-    before = obs_metrics.REGISTRY.flat().get(
-        "pool_route_fallback_total", 0.0)
-    from fluidframework_tpu.ops import DocStream
-
-    streams = [DocStream()]
-    streams[0].add_noop(0)
-    pool.admit([0], streams)
-    err = capsys.readouterr().err
-    assert "scan-collective route" in err
-    assert obs_metrics.REGISTRY.flat()[
-        "pool_route_fallback_total"] == before + 1
-    # once per instance, not per dispatch
-    streams[0].add_noop(1)
-    pool.dispatch_pending(streams)
-    assert "scan-collective" not in capsys.readouterr().err
-
-
-def test_mesh_pool_chunked_request_is_loud_on_multishard(capsys):
-    from fluidframework_tpu.obs import metrics as obs_metrics
-    from fluidframework_tpu.ops import DocStream
-
-    pool = MeshShardedPool(make_mesh(jax.devices()[:2]), 128,
-                           executor="chunked")
-    before = obs_metrics.REGISTRY.flat().get(
-        "mesh_pool_route_fallback_total", 0.0)
-    streams = [DocStream()]
-    streams[0].add_noop(0)
-    pool.admit([0], streams)
-    assert "scan window body" in capsys.readouterr().err
-    assert obs_metrics.REGISTRY.flat()[
-        "mesh_pool_route_fallback_total"] == before + 1
-
-
-def test_mesh_pool_single_shard_follows_chunked_route():
-    """A 1-shard mesh pool follows the executor route exactly like
-    the degenerate seq pool — no fallback, no warning."""
-    from fluidframework_tpu.ops import DocStream
-
-    pool = MeshShardedPool(make_mesh(jax.devices()[:1]), 128,
-                           executor="chunked")
-    streams = [DocStream()]
-    streams[0].add_noop(0)
-    assert pool.admit([0], streams) == []
-    assert pool._route_warned is False
 
 
 # ======================================================================

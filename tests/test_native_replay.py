@@ -1,7 +1,7 @@
 """C++ scalar merge replayer vs batched kernel vs Python oracle.
 
-The replayer (native/merge_replay.cpp) is bench.py's compiled baseline;
-its semantics must match the kernel bit-for-bit on the sequenced path.
+The replayer (native/merge_replay.cpp) is a compiled baseline; its
+semantics must match the kernel bit-for-bit on the sequenced path.
 """
 import pytest
 

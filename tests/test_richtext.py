@@ -190,9 +190,7 @@ def test_workload_feeds_merge_kernel():
     from fluidframework_tpu.ops import (
         build_batch, encode_stream, extract_text, fetch, make_table,
     )
-    from fluidframework_tpu.ops.merge_chunk import (
-        apply_window_chunked, build_chunked,
-    )
+    from fluidframework_tpu.ops.event_graph import apply_batch_egwalker
     from fluidframework_tpu.ops.merge_kernel import apply_window_impl
     from fluidframework_tpu.protocol.messages import MessageType
 
@@ -227,14 +225,13 @@ def test_workload_feeds_merge_kernel():
     enc = encode_stream(msgs)
     batch = build_batch([enc])
     seq_tab = fetch(apply_window_impl(make_table(1, 1024), batch))
-    chunk_tab = fetch(apply_window_chunked(
-        make_table(1, 1024), build_chunked(batch, K=8), K=8))
+    eg_tab = fetch(apply_batch_egwalker(make_table(1, 1024), batch))
     want = ea.plain_text()
     assert extract_text(seq_tab, enc, 0) == want
-    assert extract_text(chunk_tab, enc, 0) == want
+    assert extract_text(eg_tab, enc, 0) == want
     n = int(seq_tab["count"][0])
     for f in ("length", "seq", "client", "removed_seq"):
-        assert np.array_equal(seq_tab[f][0, :n], chunk_tab[f][0, :n])
+        assert np.array_equal(seq_tab[f][0, :n], eg_tab[f][0, :n])
 
 
 def test_toggle_mark_across_paragraph_boundary_clears():

@@ -24,18 +24,16 @@ from fluidframework_tpu.obs.profiler import SELF_TIME_EVERY
 from fluidframework_tpu.ops import OpBatch, make_table
 from fluidframework_tpu.ops.bucket_ladder import BucketLadder
 from fluidframework_tpu.ops.event_graph import (
+    CHUNK_FIELDS,
     EG_K,
+    _chunk_state,
+    _gather_ops,
     _walker_step,
     build_event_graph,
     compiled_window,
     walker_steps,
 )
 from fluidframework_tpu.ops.host_bridge import pack_rows
-from fluidframework_tpu.ops.merge_chunk import (
-    CHUNK_FIELDS,
-    _chunk_state,
-    _gather_ops,
-)
 from fluidframework_tpu.ops.merge_kernel import compact
 from fluidframework_tpu.ops.merge_kernel import (
     compiled_window as scan_window,

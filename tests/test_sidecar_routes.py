@@ -1,19 +1,17 @@
-"""Differential sidecar-level parity across ALL THREE executor routes.
+"""Differential sidecar-level parity across the two executor routes.
 
-The chunked and egwalker executors' bit-identical contracts are pinned
-at the kernel level (tests/test_merge_chunk.py, tests/
-test_event_graph.py); this suite pins them at the SERVICE level —
-three sidecars on the same sequenced stream, one per route (scan /
-chunked / egwalker), must serve identical ``text()`` and
-``signature()`` through every policy transition: steady windows, the
-2x regrow ladder, host eviction at the ladder top, the seq-sharded
-pool, and the one semantic divergence the macro-step executors have —
-post-overflow PARKING (chunked and egwalker stop applying a doc's
-window at the failing chunk/span while the scan keeps going; the
-sidecar's recovery re-applies the whole window from the pre-dispatch
-snapshot, which must erase the difference; the egwalker route
-additionally scans its concurrent SUFFIX onto a parked prefix, which
-the same recovery absorbs).
+The egwalker executor's bit-identical contract is pinned at the kernel
+level (tests/test_walker_differential.py, tests/test_event_graph.py);
+this suite pins it at the SERVICE level — two sidecars on the same
+sequenced stream, one per route (scan / egwalker), must serve
+identical ``text()`` and ``signature()`` through every policy
+transition: steady windows, the 2x regrow ladder, host eviction at the
+ladder top, the seq-sharded pool, and the one semantic divergence the
+walker has — post-overflow PARKING (the walker stops applying a doc's
+window at the failing span while the scan keeps going, and scans its
+concurrent SUFFIX onto the parked prefix; the sidecar's recovery
+re-applies the whole window from the pre-dispatch snapshot, which must
+erase the difference).
 """
 import random
 
@@ -24,7 +22,7 @@ from fluidframework_tpu.loader import Container
 from fluidframework_tpu.service import LocalServer, TpuMergeSidecar
 
 
-ROUTES = ("scan", "chunked", "egwalker")
+ROUTES = ("scan", "egwalker")
 
 
 def _pair(**kw):
@@ -98,7 +96,7 @@ def test_routes_agree_on_steady_multidoc_traffic():
 @pytest.mark.slow
 def test_routes_agree_through_grow_ladder():
     """Windows big enough to overflow a 16-slot slab force the regrow
-    path — where the chunked route's overflow PARKING differs from the
+    path — where the walker's overflow PARKING differs from the
     scan mid-window, and recovery must reconverge them."""
     server = LocalServer()
     sidecars = _pair(max_docs=2, capacity=16, max_capacity=512)
@@ -122,7 +120,7 @@ def test_routes_agree_on_overflow_parking_within_one_window():
     """The overflow-parking case proper: ONE window whose ops keep
     coming after the capacity overflow point. The scan executor keeps
     applying post-overflow ops (garbage-tolerant: the doc is flagged),
-    the chunked executor parks the doc at its pre-chunk state — the
+    the walker parks the doc at its pre-span state — the
     sidecar policy layer re-applies the window from the snapshot at
     the doubled capacity, so the served state must be identical."""
     server = LocalServer()
@@ -171,8 +169,8 @@ def test_routes_agree_through_eviction_and_recovery():
 
 def test_routes_agree_with_pool_tier():
     """Grow ladder -> seq-sharded pool admission -> continued pooled
-    collaboration, on both routes (single-shard mesh: the chunked
-    route applies to the pool table directly there)."""
+    collaboration, on both routes (single-shard mesh: the pool
+    applies the plain scan to its table there, whatever the route)."""
     import jax
 
     from fluidframework_tpu.parallel import make_seq_mesh

@@ -87,33 +87,17 @@ def test_scan_window_compiles_at_serving_size(one_chip):
     assert mem.argument_size_in_bytes > 1024 * 1024 * 4 * 12
 
 
-def test_pallas_window_compiles_to_a_mosaic_kernel(one_chip):
-    from fluidframework_tpu.ops.pallas_merge import apply_window_pallas
-
-    table, arrays = _window(1024, 1024, 16)
-    batch = OpBatch(**{f: arrays[f] for f in OpBatch._fields})
-    compiled = jax.jit(apply_window_pallas).lower(
-        _shapes(table, one_chip), _shapes(batch, one_chip)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-
-
-@pytest.mark.parametrize("route", ["chunked", "egwalker"])
+@pytest.mark.parametrize("route", ["egwalker"])
 def test_macro_step_routes_compile(one_chip, route):
-    """The smallest size that shows the macro-step lowering; at serving
-    size chunked takes over a minute (chip_smoke.py reports it)."""
-    from fluidframework_tpu.ops import event_graph, merge_chunk
+    """The smallest size that shows the walker's macro-step lowering
+    (chip_smoke.py compiles it at serving size)."""
+    from fluidframework_tpu.ops import event_graph
 
     table = make_table(16, 256)
     arrays = pack_rows(16, {0: [NOOP]}, bucket_floor=16)
-    if route == "chunked":
-        jitted, args = merge_chunk.compiled_window(
-            table, merge_chunk.compile_chunks(
-                arrays, k_max=merge_chunk.CHUNK_K),
-            K=merge_chunk.CHUNK_K)
-    else:
-        jitted, args = event_graph.compiled_window(
-            table, event_graph.build_event_graph(
-                arrays, k_max=event_graph.EG_K)["prefix"])
+    jitted, args = event_graph.compiled_window(
+        table, event_graph.build_event_graph(
+            arrays, k_max=event_graph.EG_K)["prefix"])
     jitted.lower(*_shapes(args, one_chip)).compile()
 
 

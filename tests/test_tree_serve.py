@@ -37,7 +37,7 @@ from fluidframework_tpu.service import (
 )
 from fluidframework_tpu.service.tree_sidecar import ChannelKindRouter
 from fluidframework_tpu.testing.tree_fuzz import random_change_with_moves
-from test_merge_chunk import smoke_seeds
+from test_walker_differential import smoke_seeds
 
 ROUTES = ("atom", "macro")
 

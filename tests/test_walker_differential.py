@@ -1,31 +1,27 @@
-"""Chunked executor vs sequential executor: differential bit-equality
-of the live slot state on identical fuzzed op windows.
+"""Egwalker route vs sequential executor: differential bit-equality of
+the live slot state on identical fuzzed op windows.
 
-This is the semantics gate for ops/merge_chunk.py — the sequential
-scan (itself differential-fuzzed against the scalar oracle and the C++
-replayer) is the ground truth; the chunked path must reproduce its
-live rows bit-for-bit (garbage rows beyond `count` may differ: the
-sort-based restructure parks different garbage than the shift-based
-one). The fuzz sweeps drive the THIRD executor too: every
-``run_both`` window also runs the egwalker route
-(ops/event_graph.py), so all three executors are pinned bit-identical
-on the same streams."""
+The sequential scan (itself differential-fuzzed against the scalar
+oracle and the C++ replayer) is the ground truth; the egwalker route
+(ops/event_graph.py: the walker over each document's critical prefix,
+the scan over its concurrent suffix) must reproduce its live rows
+bit-for-bit (garbage rows beyond `count` may differ: the walker's row
+merge parks different garbage than the scan's shift). These are the
+route's largest sweeps; tests/test_event_graph.py owns the graph
+semantics and the span compiler's break conditions."""
 import numpy as np
 import pytest
 
 from fluidframework_tpu.ops import build_batch, encode_stream, make_table
-from fluidframework_tpu.ops.event_graph import apply_batch_egwalker
-from fluidframework_tpu.ops.merge_chunk import (
-    apply_window_chunked,
-    build_chunked,
-    compile_chunks,
+from fluidframework_tpu.ops.event_graph import (
+    apply_batch_egwalker,
+    build_event_graph,
 )
 from fluidframework_tpu.ops.merge_kernel import apply_window_impl
 from fluidframework_tpu.ops.segment_table import (
     KIND_INSERT,
     KIND_NOOP,
     KIND_REMOVE,
-    NOT_REMOVED,
     OpBatch,
 )
 from fluidframework_tpu.testing import FuzzConfig, record_op_stream
@@ -46,47 +42,39 @@ def smoke_seeds(n, keep):
     ]
 
 
-def assert_live_equal(seq_tab, chunk_tab, ctx=""):
-    ns, nc = {}, {}
+def assert_live_equal(seq_tab, eg_tab, ctx=""):
+    ns, ne = {}, {}
     for f in seq_tab._fields:
         ns[f] = np.asarray(getattr(seq_tab, f))
-        nc[f] = np.asarray(getattr(chunk_tab, f))
-    assert np.array_equal(ns["count"], nc["count"]), (
-        f"{ctx}: count {ns['count']} vs {nc['count']}"
+        ne[f] = np.asarray(getattr(eg_tab, f))
+    assert np.array_equal(ns["count"], ne["count"]), (
+        f"{ctx}: count {ns['count']} vs {ne['count']}"
     )
-    assert np.array_equal(ns["min_seq"], nc["min_seq"]), ctx
-    assert np.array_equal(ns["overflow"], nc["overflow"]), ctx
+    assert np.array_equal(ns["min_seq"], ne["min_seq"]), ctx
+    assert np.array_equal(ns["overflow"], ne["overflow"]), ctx
     D = ns["count"].shape[0]
     for d in range(D):
         if ns["overflow"][d]:
             continue  # post-overflow application intentionally differs
         n = int(ns["count"][d])
         for f in LIVE_FIELDS:
-            assert np.array_equal(ns[f][d, :n], nc[f][d, :n]), (
+            assert np.array_equal(ns[f][d, :n], ne[f][d, :n]), (
                 f"{ctx}: doc {d} field {f}\n"
-                f"seq:   {ns[f][d, :n]}\n"
-                f"chunk: {nc[f][d, :n]}"
+                f"seq:      {ns[f][d, :n]}\n"
+                f"egwalker: {ne[f][d, :n]}"
             )
         assert np.array_equal(
-            ns["prop"][d, :n], nc["prop"][d, :n]
+            ns["prop"][d, :n], ne["prop"][d, :n]
         ), f"{ctx}: doc {d} props"
 
 
-def run_both(streams, capacity=256, K=8):
-    """Three routes, one window: returns (scan, chunked) for the
-    call-site asserts and pins the EGWALKER route against the scan
-    inline — every fuzz sweep in this file drives all three executors
-    to bit-identical live state."""
+def run_both(streams, capacity=256):
+    """Both routes on one window: returns (scan, egwalker)."""
     batch = build_batch([encode_stream(s) for s in streams])
     D = len(streams)
     seq_tab = apply_window_impl(make_table(D, capacity), batch)
-    chunked = build_chunked(batch, K=K)
-    chunk_tab = apply_window_chunked(
-        make_table(D, capacity), chunked, K=K
-    )
     eg_tab = apply_batch_egwalker(make_table(D, capacity), batch)
-    assert_live_equal(seq_tab, eg_tab, "egwalker route")
-    return seq_tab, chunk_tab
+    return seq_tab, eg_tab
 
 
 @pytest.mark.parametrize("seed", smoke_seeds(30, {1, 2, 3}))
@@ -97,34 +85,36 @@ def test_differential_fuzz(seed):
         insert_weight=0.55, remove_weight=0.25,
         annotate_weight=0.05, process_weight=0.15,
     ))
-    seq_tab, chunk_tab = run_both([stream])
-    assert_live_equal(seq_tab, chunk_tab, f"seed {seed}")
+    seq_tab, eg_tab = run_both([stream])
+    assert_live_equal(seq_tab, eg_tab, f"seed {seed}")
 
 
 @pytest.mark.parametrize("seed", smoke_seeds(10, {2, 8}))
 def test_differential_fuzz_heavy_process(seed):
     """High process weight => refseq advances often => many visible
-    cross-client pairs => chunk breaks; exactness must survive."""
+    cross-client pairs, short critical runs and long concurrent
+    suffixes; exactness must survive."""
     _, stream = record_op_stream(FuzzConfig(
         n_clients=3, n_steps=80, seed=1000 + seed,
         insert_weight=0.45, remove_weight=0.3,
         annotate_weight=0.1, process_weight=0.3,
     ))
-    seq_tab, chunk_tab = run_both([stream], K=4)
-    assert_live_equal(seq_tab, chunk_tab, f"hp seed {seed}")
+    seq_tab, eg_tab = run_both([stream])
+    assert_live_equal(seq_tab, eg_tab, f"hp seed {seed}")
 
 
 @pytest.mark.parametrize("seed", smoke_seeds(10, {7, 8}))
 def test_differential_fuzz_single_client_chain(seed):
-    """One client typing+backspacing: the pure own-chain composition
-    path (host compiler does all the position arithmetic)."""
+    """One client typing+backspacing: every op is critical, so the
+    whole window is walker spans (the host chain does all the
+    position arithmetic)."""
     _, stream = record_op_stream(FuzzConfig(
         n_clients=1, n_steps=70, seed=2000 + seed,
         insert_weight=0.55, remove_weight=0.3,
         annotate_weight=0.1, process_weight=0.05,
     ))
-    seq_tab, chunk_tab = run_both([stream])
-    assert_live_equal(seq_tab, chunk_tab, f"chain seed {seed}")
+    seq_tab, eg_tab = run_both([stream])
+    assert_live_equal(seq_tab, eg_tab, f"chain seed {seed}")
 
 
 @pytest.mark.parametrize("seed", smoke_seeds(8, {3, 5}))
@@ -140,54 +130,54 @@ def test_differential_fuzz_multidoc(seed):
             annotate_weight=0.1, process_weight=0.15,
         ))
         streams.append(s)
-    seq_tab, chunk_tab = run_both(streams, K=8)
-    assert_live_equal(seq_tab, chunk_tab, f"multidoc seed {seed}")
+    seq_tab, eg_tab = run_both(streams)
+    assert_live_equal(seq_tab, eg_tab, f"multidoc seed {seed}")
 
 
-def _raw(ops_rows, window=None):
-    """Build an OpBatch for one doc from raw op dicts."""
+def _raw(ops_rows):
+    """[1, W] OpBatch field arrays for one doc from raw op dicts."""
     base = dict(kind=KIND_NOOP, pos1=0, pos2=0, seq=0, refseq=0,
                 client=0, op_id=0, length=0, is_marker=0,
                 prop_key=0, prop_val=0, min_seq=0)
     rows = [dict(base, **r) for r in ops_rows]
-    W = window or len(rows)
     arrs = {
-        f: np.zeros((1, W), np.int32) for f in OpBatch._fields
+        f: np.zeros((1, len(rows)), np.int32) for f in OpBatch._fields
     }
-    arrs["kind"][:] = KIND_NOOP
     for w, r in enumerate(rows):
         for f in OpBatch._fields:
             arrs[f][0, w] = r[f]
-    return OpBatch(**{f: arrs[f] for f in OpBatch._fields})
+    return arrs
 
 
-def _run_raw(rows, capacity=64, K=8):
-    batch = _raw(rows)
+def _spans(rows):
+    """The walker's span starts over the window's critical prefix."""
+    program = build_event_graph(_raw(rows))
+    return program["prefix"]["chunk_start"][0].tolist()[:len(rows)]
+
+
+def _run_raw(rows, capacity=64):
+    batch = OpBatch(**_raw(rows))
     seq_tab = apply_window_impl(make_table(1, capacity), batch)
-    chunk_tab = apply_window_chunked(
-        make_table(1, capacity), build_chunked(batch, K=K), K=K
-    )
-    return seq_tab, chunk_tab
+    eg_tab = apply_batch_egwalker(make_table(1, capacity), batch)
+    return seq_tab, eg_tab
 
 
 @pytest.mark.slow
-def test_same_client_typing_burst_coalesces_into_one_chunk():
-    """abcdef typed one char at a time: one chunk, one macro-step."""
+def test_same_client_typing_burst_is_one_span():
+    """abcdef typed one char at a time: one span, one walker step."""
     rows = [
         dict(kind=KIND_INSERT, pos1=i, seq=i + 1, refseq=0,
              client=0, op_id=i, length=1)
         for i in range(6)
     ]
-    batch = _raw(rows)
-    chunked = build_chunked(batch, K=8)
-    assert chunked["chunk_start"][0].tolist() == [1, 0, 0, 0, 0, 0]
-    seq_tab, chunk_tab = _run_raw(rows)
-    assert_live_equal(seq_tab, chunk_tab, "typing burst")
+    assert _spans(rows) == [1, 0, 0, 0, 0, 0]
+    seq_tab, eg_tab = _run_raw(rows)
+    assert_live_equal(seq_tab, eg_tab, "typing burst")
     # the whole burst resolves to six slots in order
-    assert int(np.asarray(chunk_tab.count)[0]) == 6
+    assert int(np.asarray(eg_tab.count)[0]) == 6
 
 
-def test_backspace_run_stays_one_chunk():
+def test_backspace_run_stays_one_span():
     """Type 4 chars then backspace 2: own-chain removes compose."""
     rows = [
         dict(kind=KIND_INSERT, pos1=i, seq=i + 1, refseq=0,
@@ -199,11 +189,9 @@ def test_backspace_run_stays_one_chunk():
         dict(kind=KIND_REMOVE, pos1=2, pos2=3, seq=6, refseq=0,
              client=0),
     ]
-    batch = _raw(rows)
-    chunked = build_chunked(batch, K=8)
-    assert chunked["chunk_start"][0].tolist() == [1, 0, 0, 0, 0, 0]
-    seq_tab, chunk_tab = _run_raw(rows)
-    assert_live_equal(seq_tab, chunk_tab, "backspace run")
+    assert _spans(rows) == [1, 0, 0, 0, 0, 0]
+    seq_tab, eg_tab = _run_raw(rows)
+    assert_live_equal(seq_tab, eg_tab, "backspace run")
 
 
 @pytest.mark.slow
@@ -218,13 +206,15 @@ def test_concurrent_same_position_inserts_order():
         dict(kind=KIND_INSERT, pos1=0, seq=3, refseq=0, client=2,
              op_id=2, length=1),
     ]
-    seq_tab, chunk_tab = _run_raw(rows)
-    assert_live_equal(seq_tab, chunk_tab, "same-pos storm")
+    seq_tab, eg_tab = _run_raw(rows)
+    assert_live_equal(seq_tab, eg_tab, "same-pos storm")
 
 
-def test_cross_client_visible_dependency_breaks_chunk():
-    """Client 1 saw client 0's insert (refseq >= its seq): the chunk
-    must break, then still converge bit-identically."""
+def test_cross_client_visible_dependency_breaks_span():
+    """Client 1 saw client 0's insert (refseq >= its seq) and inserts
+    strictly inside its text: every op is critical, but an anchor
+    inside another in-span op's text breaks the span. It must still
+    converge bit-identically."""
     rows = [
         dict(kind=KIND_INSERT, pos1=0, seq=1, refseq=0, client=0,
              op_id=0, length=4),
@@ -233,32 +223,31 @@ def test_cross_client_visible_dependency_breaks_chunk():
         dict(kind=KIND_REMOVE, pos1=1, pos2=3, seq=3, refseq=2,
              client=0),
     ]
-    batch = _raw(rows)
-    chunked = build_chunked(batch, K=8)
-    assert chunked["chunk_start"][0].tolist()[:2] == [1, 1]
-    seq_tab, chunk_tab = _run_raw(rows)
-    assert_live_equal(seq_tab, chunk_tab, "cross visible")
+    assert _spans(rows)[:2] == [1, 1]
+    seq_tab, eg_tab = _run_raw(rows)
+    assert_live_equal(seq_tab, eg_tab, "cross visible")
 
 
 def test_remove_then_insert_at_tombstone_boundary():
     """Insert lands exactly at an own fresh tombstone: breakTie puts
-    it BEFORE the removed text."""
+    it BEFORE the removed text. No span assertion: where the walker
+    breaks here is the span compiler's business
+    (tests/test_event_graph.py), the placement is this test's."""
     rows = [
         dict(kind=KIND_INSERT, pos1=0, seq=1, refseq=0, client=0,
              op_id=0, length=6),
-    ]
-    # sequence the big insert first (separate chunk via refseq seen)
-    rows += [
         dict(kind=KIND_REMOVE, pos1=2, pos2=4, seq=2, refseq=1,
              client=0),
         dict(kind=KIND_INSERT, pos1=2, seq=3, refseq=1, client=0,
              op_id=1, length=1),
     ]
-    seq_tab, chunk_tab = _run_raw(rows)
-    assert_live_equal(seq_tab, chunk_tab, "tombstone boundary")
+    seq_tab, eg_tab = _run_raw(rows)
+    assert_live_equal(seq_tab, eg_tab, "tombstone boundary")
 
 
-def test_annotate_lww_within_chunk():
+def test_annotate_lww_within_span():
+    # no span assertion: last-writer-wins is a property of the stamps,
+    # whichever spans the annotates fall into
     rows = [
         dict(kind=KIND_INSERT, pos1=0, seq=1, refseq=0, client=0,
              op_id=0, length=5),
@@ -267,8 +256,8 @@ def test_annotate_lww_within_chunk():
         dict(kind=2, pos1=1, pos2=3, seq=3, refseq=1, client=0,
              prop_key=1, prop_val=9),
     ]
-    seq_tab, chunk_tab = _run_raw(rows)
-    assert_live_equal(seq_tab, chunk_tab, "annotate lww")
+    seq_tab, eg_tab = _run_raw(rows)
+    assert_live_equal(seq_tab, eg_tab, "annotate lww")
 
 
 @pytest.mark.slow
@@ -278,16 +267,14 @@ def test_overflow_flags_match_and_doc_parks():
              client=0, op_id=i, length=1)
         for i in range(10)
     ]
-    batch = _raw(rows)
-    seq_tab = apply_window_impl(make_table(1, 4), batch)
-    chunk_tab = apply_window_chunked(
-        make_table(1, 4), build_chunked(batch, K=8), K=8
-    )
+    seq_tab, eg_tab = _run_raw(rows, capacity=4)
     assert int(np.asarray(seq_tab.overflow)[0]) == 1
-    assert int(np.asarray(chunk_tab.overflow)[0]) == 1
+    assert int(np.asarray(eg_tab.overflow)[0]) == 1
 
 
 def test_min_seq_advance_rides_noops():
+    # no span assertion: the noop's min_seq is applied in order
+    # whichever span it lands in; the differential is the property
     rows = [
         dict(kind=KIND_INSERT, pos1=0, seq=1, refseq=0, client=0,
              op_id=0, length=3),
@@ -295,17 +282,18 @@ def test_min_seq_advance_rides_noops():
         dict(kind=KIND_REMOVE, pos1=0, pos2=1, seq=2, refseq=1,
              client=0, min_seq=1),
     ]
-    seq_tab, chunk_tab = _run_raw(rows)
-    assert_live_equal(seq_tab, chunk_tab, "noop min_seq")
+    seq_tab, eg_tab = _run_raw(rows)
+    assert_live_equal(seq_tab, eg_tab, "noop min_seq")
 
 
 @pytest.mark.slow
-def test_mid_chunk_tombstone_aging_breaks_chunk():
+def test_mid_span_tombstone_aging_splits_ops():
     """A committed tombstone ages (min_seq crosses its removed seq)
-    between two same-position in-chunk inserts: without a chunk break
-    the two events anchor at different slots and the breakTie rank
-    group splits across the tombstone (seed-90007 divergence class).
-    The compiler must close the chunk at the second insert."""
+    between two same-position inserts: in one span the two events
+    would anchor at different slots and the breakTie rank group would
+    split across the tombstone (seed-90007 divergence class). The
+    second insert never saw the first, so it is not critical: it goes
+    to the concurrent suffix, never into the first insert's span."""
     rows = [
         dict(kind=KIND_INSERT, pos1=0, seq=1, refseq=0, client=0,
              op_id=0, length=2),                       # "ab"
@@ -316,39 +304,36 @@ def test_mid_chunk_tombstone_aging_breaks_chunk():
         dict(kind=KIND_INSERT, pos1=1, seq=4, refseq=2, client=3,
              op_id=2, length=1),                       # tomb now below
     ]
-    batch = _raw(rows)
-    chunked = build_chunked(batch, K=8)
-    # ops 2 and 3 must NOT share a chunk (aging crossed seq 2)
-    assert chunked["chunk_start"][0].tolist()[3] == 1
-    seq_tab, chunk_tab = _run_raw(rows)
-    assert_live_equal(seq_tab, chunk_tab, "mid-chunk aging")
+    program = build_event_graph(_raw(rows))
+    assert program["graph"].critical[0].tolist()[:4] == [1, 1, 1, 0]
+    seq_tab, eg_tab = _run_raw(rows)
+    assert_live_equal(seq_tab, eg_tab, "mid-span aging")
     seqs = np.asarray(seq_tab.seq)[0, :4].tolist()
     assert seqs == [1, 4, 3, 1], seqs  # a | op3 | op2 | tomb-b
 
 
 @pytest.mark.slow
 def test_regression_seed_90007():
-    """Driver-caught r4 divergence: 120-step stream whose min_seq
-    advance mid-chunk aged a committed tombstone between two
-    same-position inserts (found by the bench's fuzz stage)."""
+    """A 120-step stream whose min_seq advance mid-window aged a
+    committed tombstone between two same-position inserts."""
     _, stream = record_op_stream(FuzzConfig(
         n_clients=4, n_steps=120, seed=90007,
         insert_weight=0.5, remove_weight=0.3,
         annotate_weight=0.1, process_weight=0.1,
     ))
-    seq_tab, chunk_tab = run_both([stream], capacity=1024, K=8)
-    assert_live_equal(seq_tab, chunk_tab, "seed 90007")
+    seq_tab, eg_tab = run_both([stream], capacity=1024)
+    assert_live_equal(seq_tab, eg_tab, "seed 90007")
 
 
-@pytest.mark.parametrize("steps,K,seed0", [
-    pytest.param(120, 8, 90000, marks=pytest.mark.slow),
-    pytest.param(160, 16, 90020, marks=pytest.mark.slow),
-    (200, 4, 90040),
+@pytest.mark.parametrize("steps,seed0", [
+    pytest.param(120, 90000, marks=pytest.mark.slow),
+    pytest.param(160, 90020, marks=pytest.mark.slow),
+    (200, 90040),
 ])
-def test_differential_fuzz_deep(steps, K, seed0):
-    """Bench-mix deep sweep, doc-batched (12 seeds per call) so the
-    suite stays bounded on 1 CPU; the long-stream regime is where the
-    r4 divergence lived (in-repo cap was 90 steps — too shallow)."""
+def test_differential_fuzz_deep(steps, seed0):
+    """Deep sweep, doc-batched (12 seeds per call) so the suite stays
+    bounded on 1 CPU; the long-stream regime is where the seed-90007
+    divergence lived (90-step streams were too shallow)."""
     streams = []
     for seed in range(seed0, seed0 + 12):
         _, s = record_op_stream(FuzzConfig(
@@ -357,5 +342,5 @@ def test_differential_fuzz_deep(steps, K, seed0):
             annotate_weight=0.1, process_weight=0.1,
         ))
         streams.append(s)
-    seq_tab, chunk_tab = run_both(streams, capacity=2048, K=K)
-    assert_live_equal(seq_tab, chunk_tab, f"deep {steps}/{K}/{seed0}")
+    seq_tab, eg_tab = run_both(streams, capacity=2048)
+    assert_live_equal(seq_tab, eg_tab, f"deep {steps}/{seed0}")
