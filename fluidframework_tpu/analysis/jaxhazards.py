@@ -84,13 +84,14 @@ DISPATCH_LOOPS = {
     ),
     # The egwalker route's dispatch path (ops/event_graph.py): the
     # host graph/span compiler runs in the pipeline's pack stage and
-    # the walker dispatch wrappers run in its device stage — a
+    # the walker and suffix dispatch wrappers run in its device stage — a
     # device->host read in either re-serializes the pipeline exactly
     # like one in the sidecar module itself (the sidecar's
     # _compile_program/_apply_program call straight into these).
     "ops/event_graph.py": (
         ("build_event_graph", "apply_window_egwalker",
-         "apply_window_egwalker_pingpong", "apply_batch_egwalker"),
+         "apply_window_egwalker_pingpong", "apply_suffix",
+         "apply_batch_egwalker"),
         (),
     ),
     # The obs instrumentation the dispatch loop calls into (flight-

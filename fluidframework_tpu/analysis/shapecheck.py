@@ -1542,6 +1542,7 @@ def ladder_bounds(window_floor: int, max_bucket: int,
         "apply_window_pingpong": shapes if donate else 0,
         "egwalker": shapes,
         "egwalker_pingpong": shapes if donate else 0,
+        "egwalker_suffix": shapes,
         # one per capacity rung
         "compact": n_rungs,
         # one per rung TRANSITION
@@ -1550,11 +1551,14 @@ def ladder_bounds(window_floor: int, max_bucket: int,
     if executor == "scan":
         bounds["egwalker"] = 0
         bounds["egwalker_pingpong"] = 0
+        bounds["egwalker_suffix"] = 0
     else:
         # the walker covers critical prefixes; concurrent SUFFIXES
-        # dispatch the PLAIN scan jit per rung x bucket (never the
-        # ping-pong form — the suffix input is the walker stage's
-        # live output), and prewarm walks both programs
+        # dispatch the route's own plain scan jit per rung x bucket
+        # (never a donating form — the suffix input is the walker
+        # stage's live output), and prewarm walks both programs; the
+        # primary window never reaches apply_window
+        bounds["apply_window"] = 0
         bounds["apply_window_pingpong"] = 0
     if pool_capacity is not None:
         # MeshShardedPool jit roots (per-shard ladder x sharding
@@ -1609,7 +1613,7 @@ def infer_kernel_output(root: str, spec: dict,
     BY NAME."""
     identity_roots = {
         "apply_window", "apply_window_pingpong", "egwalker",
-        "egwalker_pingpong", "compact", "seq_shard",
+        "egwalker_pingpong", "egwalker_suffix", "compact", "seq_shard",
     }
     if root in identity_roots:
         return {f: (tuple(shape), dtype)

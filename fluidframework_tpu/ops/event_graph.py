@@ -33,12 +33,12 @@ This module is that idea translated to the batched SoA table world:
    splits at its FIRST non-critical op. The critical prefix takes the
    walker fast path below; the suffix (from the first genuinely
    concurrent op on) is applied by the per-op scan executor
-   (``merge_kernel.apply_window``), whose masked visibility pass at
-   each op's ``(refseq, client)`` IS the batched-table analogue of
-   Eg-walker's retreat/advance: it re-prepares the op's view of the
-   state instead of assuming the current one. Sequential docs pay no
-   transform at all; concurrent docs pay it only from the point
-   concurrency actually starts.
+   (``merge_kernel.apply_window_impl`` under :func:`apply_suffix`),
+   whose masked visibility pass at each op's ``(refseq, client)`` IS
+   the batched-table analogue of Eg-walker's retreat/advance: it
+   re-prepares the op's view of the state instead of assuming the
+   current one. Sequential docs pay no transform at all; concurrent
+   docs pay it only from the point concurrency actually starts.
 
 3. WALKER KERNEL (:func:`apply_window_egwalker`, device half): the
    critical prefix is composed on the host by ONE SHARED span chain
@@ -103,6 +103,12 @@ from .segment_table import (
 
 # extra per-op int32 arrays the span compiler emits alongside OpBatch
 CHUNK_FIELDS = ("chunk_start", "pred", "ev_cover")
+
+# the rows of the packed prefix: build_event_graph emits the critical
+# prefix as ONE [len(PREFIX_FIELDS), D, P] int32 array, these fields
+# in this order, so a round sends it in one host->device copy and the
+# walker program splits it by static index
+PREFIX_FIELDS = OpBatch._fields + CHUNK_FIELDS
 
 # The sidecar's executor routes — ONE registry (the sidecar validates
 # against it; docs/PERF.md "Executor routes"). The backend picks one
@@ -503,11 +509,13 @@ def build_event_graph(arrays: dict, base_head=None, k_max: int = EG_K,
     Returns ``{"egwalker": True, "k": k_max, "prefix": ..., "suffix":
     ..., "graph": EventGraph, "span_splits": ..., "walker_steps":
     ...}``: ``prefix`` holds every document's
-    critical prefix (positions rewritten to span-base coordinates +
+    critical prefix as one packed [len(PREFIX_FIELDS), D, P] int32
+    array (positions rewritten to span-base coordinates +
     CHUNK_FIELDS, window pow2-bucketed through the BucketLadder so
     compile counts stay laddered), ``suffix`` the raw remainder from
-    each document's first non-critical op on (left-aligned, bucketed;
-    None when every op is critical — the common case). ``base_head``
+    each document's first non-critical op on, packed the same way as
+    [len(OpBatch._fields), D, S] (left-aligned, bucketed; None when
+    every op is critical — the common case). ``base_head``
     [D] is the max sequence number already applied per row (0 /
     omitted = a fresh table); it only gates the criticality of ops
     whose refseq predates the window, conservatively. ``walker_steps``
@@ -542,43 +550,41 @@ def build_event_graph(arrays: dict, base_head=None, k_max: int = EG_K,
     max_p = int(prefix_len.max()) if D else 0
     if max_p > 0:
         P = ladder.window_bucket(max_p)
-        valid = lane[:, :P] < prefix_len[:, None] if P <= W else \
-            np.concatenate(
-                [lane < prefix_len[:, None],
-                 np.zeros((D, P - W), np.bool_)], axis=1)
-        pref = {}
+        n = min(P, W)
+        valid = lane[:, :n] < prefix_len[:, None]
+        # every field is written into its row of the one packed buffer
+        packed = np.empty((len(PREFIX_FIELDS), D, P), np.int32)
+        pref = dict(zip(PREFIX_FIELDS, packed))     # [D, P] views
         for f in OpBatch._fields:
-            src = raw[f][:, :P] if P <= W else np.concatenate(
-                [raw[f], np.zeros((D, P - W), np.int32)], axis=1)
-            fill = KIND_NOOP if f == "kind" else 0
-            pref[f] = np.where(valid, src, fill).astype(np.int32)
-        chunk_start = np.zeros((D, P), np.int32)
-        pred = np.full((D, P), -1, np.int32)
-        ev_cover = np.zeros((D, P), np.int32)
+            pref[f].fill(KIND_NOOP if f == "kind" else 0)
+            np.copyto(pref[f][:, :n], raw[f][:, :n], where=valid)
+        chunk_start = pref["chunk_start"]
+        pred = pref["pred"]
+        ev_cover = pref["ev_cover"]
+        chunk_start.fill(0)
+        pred.fill(-1)
+        ev_cover.fill(0)
         has_real = (pref["kind"] != KIND_NOOP).any(axis=1)
         # idle rows need no chain analysis: boundary every k_max lanes
         chunk_start[~has_real, ::k_max] = 1
         for d in np.flatnonzero(has_real):
             _compile_span_row(pref, chunk_start, pred, ev_cover,
                               span_splits, int(d), k_max)
-        pref["chunk_start"] = chunk_start
-        pref["pred"] = pred
-        pref["ev_cover"] = ev_cover
-        program["prefix"] = pref
+        program["prefix"] = packed
         program["walker_steps"] = walker_steps(chunk_start, k_max)
 
     suf_len = (W - prefix_len).astype(np.int64)
     max_s = int(suf_len.max()) if D else 0
     if max_s > 0:
         S = ladder.window_bucket(max_s)
-        suffix = {f: np.zeros((D, S), np.int32)
-                  for f in OpBatch._fields}
-        suffix["kind"][:] = KIND_NOOP
+        suffix = np.zeros((len(OpBatch._fields), D, S), np.int32)
+        suf = dict(zip(OpBatch._fields, suffix))    # [D, S] views
+        suf["kind"].fill(KIND_NOOP)
         for d in np.flatnonzero(suf_len > 0):
             p = int(prefix_len[d])
             n = W - p
             for f in OpBatch._fields:
-                suffix[f][d, :n] = raw[f][d, p:W]
+                suf[f][d, :n] = raw[f][d, p:W]
         program["suffix"] = suffix
     return program
 
@@ -590,7 +596,7 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 from jax import lax  # noqa: E402
 
-from .merge_kernel import apply_window  # noqa: E402
+from .merge_kernel import apply_window_impl  # noqa: E402
 from .merge_step import state_to_table, table_to_state  # noqa: E402
 from .segment_table import (  # noqa: E402
     NOT_REMOVED,
@@ -1060,6 +1066,15 @@ def _walker_loop(st: dict, ops_w: dict, K: int) -> dict:
     return st
 
 
+def _walker_table(table: SegmentTable, prefix, K: int) -> SegmentTable:
+    """The walker over a packed prefix, table in and table out: the
+    state conversion runs inside the trace, where XLA fuses it into
+    the walker program, and the prefix splits by static index."""
+    ops_w = {f: prefix[i] for i, f in enumerate(PREFIX_FIELDS)}
+    st = _walker_loop(_chunk_state(table), ops_w, K)
+    return _chunk_unstate(dict(st))
+
+
 _jit_cache: dict = {}
 
 
@@ -1068,7 +1083,7 @@ def _get_jit(K: int):
     compile counting)."""
     if K not in _jit_cache:
         _jit_cache[K] = jax.jit(
-            lambda st, ops: _walker_loop(st, ops, K)
+            lambda table, prefix: _walker_table(table, prefix, K)
         )
     return _jit_cache[K]
 
@@ -1079,33 +1094,30 @@ _jit_pingpong_cache: dict = {}
 def _get_jit_pingpong(K: int):
     if K not in _jit_pingpong_cache:
 
-        def run(dead: dict, st: dict, ops: dict) -> dict:
-            # ``dead`` is donation fodder (a retired same-shape
-            # state): its buffers may back this span's output. Never
-            # read.
+        def run(dead: SegmentTable, table: SegmentTable,
+                prefix) -> SegmentTable:
+            # ``dead`` is donation fodder (a retired table of the
+            # output's shapes): all twelve of its buffers may back the
+            # output. Never read.
             del dead
-            return _walker_loop(st, ops, K)
+            return _walker_table(table, prefix, K)
 
         _jit_pingpong_cache[K] = jax.jit(run, donate_argnums=(0,))
     return _jit_pingpong_cache[K]
 
 
-def apply_window_egwalker(table: SegmentTable, prefix: dict,
+def apply_window_egwalker(table: SegmentTable, prefix: np.ndarray,
                           K: int = EG_K) -> SegmentTable:
-    """Apply a compiled critical-prefix program (the ``prefix`` half
-    of :func:`build_event_graph`'s output) to the table. ``K`` must
-    equal the build k_max."""
-    st = _chunk_state(table)
-    ops_w = {
-        f: jnp.asarray(prefix[f])
-        for f in OpBatch._fields + CHUNK_FIELDS
-    }
-    st = _get_jit(K)(st, ops_w)
-    return _chunk_unstate(dict(st))
+    """Apply a compiled critical-prefix program (the packed ``prefix``
+    of :func:`build_event_graph`'s output) to the table: one
+    host->device copy and one program launch. ``K`` must equal the
+    build k_max."""
+    return _get_jit(K)(table, jax.device_put(prefix))
 
 
 def apply_window_egwalker_pingpong(dead: SegmentTable | None,
-                                   table: SegmentTable, prefix: dict,
+                                   table: SegmentTable,
+                                   prefix: np.ndarray,
                                    K: int = EG_K) -> SegmentTable:
     """Double-buffered twin of :func:`apply_window_egwalker`: DONATES
     ``dead`` (a retired table of the same shape) as the output buffer
@@ -1118,13 +1130,28 @@ def apply_window_egwalker_pingpong(dead: SegmentTable | None,
     output — live, never donatable)."""
     if dead is None or jax.default_backend() == "cpu":
         return apply_window_egwalker(table, prefix, K=K)
-    st = _chunk_state(table)
-    ops_w = {
-        f: jnp.asarray(prefix[f])
-        for f in OpBatch._fields + CHUNK_FIELDS
-    }
-    st = _get_jit_pingpong(K)(_chunk_state(dead), st, ops_w)
-    return _chunk_unstate(dict(st))
+    return _get_jit_pingpong(K)(dead, table, jax.device_put(prefix))
+
+
+def _packed_scan(table: SegmentTable, suffix) -> SegmentTable:
+    return apply_window_impl(table, OpBatch(*suffix))
+
+
+# the concurrent suffix's scan over one packed buffer, a jit of this
+# module so the scan route and the pools keep ``apply_window`` as is.
+# Named as the scan it runs: XLA calls the program
+# ``jit_apply_window_impl``, as it calls the scan route's window.
+_packed_scan.__name__ = _packed_scan.__qualname__ = "apply_window_impl"
+_jit_suffix = jax.jit(_packed_scan)
+
+
+def apply_suffix(table: SegmentTable,
+                 suffix: np.ndarray) -> SegmentTable:
+    """Apply a program's packed concurrent ``suffix`` (the scan over
+    each document's ops from its first non-critical one on): one
+    host->device copy and one program launch. Never a donating form:
+    its input is the walker stage's live output."""
+    return _jit_suffix(table, jax.device_put(suffix))
 
 
 def apply_batch_egwalker(table: SegmentTable, batch: OpBatch,
@@ -1142,20 +1169,13 @@ def apply_batch_egwalker(table: SegmentTable, batch: OpBatch,
         table = apply_window_egwalker(table, program["prefix"],
                                       K=k_max)
     if program["suffix"] is not None:
-        table = apply_window(table, OpBatch(**{
-            f: jnp.asarray(program["suffix"][f])
-            for f in OpBatch._fields
-        }))
+        table = apply_suffix(table, program["suffix"])
     return table
 
 
-def compiled_window(table: SegmentTable, prefix: dict, K: int = EG_K):
+def compiled_window(table: SegmentTable, prefix: np.ndarray,
+                    K: int = EG_K):
     """PUBLIC handle for AOT cost analysis of the walker: the SAME jit
     object ``apply_window_egwalker`` dispatches at this K, with the
     traced argument structure."""
-    args = (
-        _chunk_state(table),
-        {f: jnp.asarray(prefix[f])
-         for f in OpBatch._fields + CHUNK_FIELDS},
-    )
-    return _get_jit(K), args
+    return _get_jit(K), (table, jnp.asarray(prefix))

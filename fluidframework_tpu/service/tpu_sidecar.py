@@ -76,6 +76,7 @@ from ..ops import (
 from ..ops.bucket_ladder import BucketLadder
 from ..ops.event_graph import (
     EG_K,
+    apply_suffix,
     apply_window_egwalker,
     apply_window_egwalker_pingpong,
     build_event_graph,
@@ -657,13 +658,17 @@ class TpuMergeSidecar:
         # (real + pooled ops), active_docs (rows with a real op),
         # walker_steps (the prefix walker's while trip count),
         # scan_steps (lane width of each scan dispatched), lane_slots
-        # (walker_steps x docs x K + scan_steps x docs).
+        # (walker_steps x docs x K + scan_steps x docs). Programs the
+        # egwalker route enqueues (every _apply_program, prewarm's and
+        # a regrow re-apply's included): walker_launches (one walker
+        # program per critical prefix), suffix_launches (one scan
+        # program per concurrent suffix).
         self.stats = {
             "pack_s": 0.0, "settle_s": 0.0, "rounds": 0,
             "ingest_s": 0.0, "ingest_msgs": 0, "graph_s": 0.0,
             "enqueue_s": 0.0, "compact_s": 0.0, "real_ops": 0,
             "active_docs": 0, "walker_steps": 0, "scan_steps": 0,
-            "lane_slots": 0,
+            "lane_slots": 0, "walker_launches": 0, "suffix_launches": 0,
         }
         # device-trace switch as the last apply/sync read it, and the
         # round the in-flight dispatch belongs to (its settle span)
@@ -899,13 +904,16 @@ class TpuMergeSidecar:
                         if self.donate else None)
                 table = self._apply_program(table, program, dead)
                 if self.executor == "egwalker":
-                    # the egwalker route's concurrent SUFFIX rides the
-                    # plain scan jit (never the ping-pong form — its
+                    # the egwalker route's concurrent SUFFIX rides its
+                    # own plain scan jit (never a donating form — its
                     # input is the walker stage's live output), so the
                     # prewarm walk must compile that program per
                     # rung x bucket too; an all-noop prewarm window is
                     # fully critical and would never reach it
-                    table = self._apply_program(table, arrays)
+                    suffix = np.stack([arrays[f] for f in OpBatch._fields])
+                    table = self._apply_program(table, {
+                        "egwalker": True, "prefix": None,
+                        "suffix": suffix})
             table = compact(table)
             if dummy_prev is not None:
                 pad_capacity(dummy_prev, rung)
@@ -953,6 +961,7 @@ class TpuMergeSidecar:
         # rides the WALKER stage; the suffix input is that stage's
         # live output, so it always dispatches plain.
         if program["prefix"] is not None:
+            self.stats["walker_launches"] += 1
             if dead is not None:
                 table = apply_window_egwalker_pingpong(
                     dead, table, program["prefix"], K=EG_K
@@ -962,9 +971,8 @@ class TpuMergeSidecar:
                     table, program["prefix"], K=EG_K
                 )
         if program["suffix"] is not None:
-            table = apply_window(table, OpBatch(**{
-                f: program["suffix"][f] for f in OpBatch._fields
-            }))
+            self.stats["suffix_launches"] += 1
+            table = apply_suffix(table, program["suffix"])
         return table
 
     def _dispatch(self) -> int:
@@ -1057,7 +1065,7 @@ class TpuMergeSidecar:
         if program.get("egwalker"):
             walker = program["walker_steps"]
             suffix = program["suffix"]
-            scan = 0 if suffix is None else suffix["kind"].shape[1]
+            scan = 0 if suffix is None else suffix.shape[2]
         else:
             walker, scan = 0, arrays["kind"].shape[1]
         stats = self.stats

@@ -76,6 +76,9 @@ _JIT_ATTRS = {
         "fluidframework_tpu.ops.merge_kernel", "pad_capacity"),
     "compact": (
         "fluidframework_tpu.ops.merge_kernel", "compact"),
+    # the egwalker route's concurrent-suffix scan (one packed buffer)
+    "egwalker_suffix": (
+        "fluidframework_tpu.ops.event_graph", "_jit_suffix"),
     # the mesh pool's migration gather (ops/shard_moves.py): plain
     # form (CPU / prewarm) and the donating handoff form (TPU)
     "mesh_move": (

@@ -27,6 +27,7 @@ from fluidframework_tpu.ops import build_batch, encode_stream, make_table
 from fluidframework_tpu.ops.event_graph import (
     EG_K,
     EXECUTOR_ROUTES,
+    PREFIX_FIELDS,
     _merge_rows,
     apply_batch_egwalker,
     apply_window_egwalker,
@@ -79,6 +80,11 @@ def assert_live_equal(seq_tab, eg_tab, ctx=""):
 def _arrays(batch: OpBatch) -> dict:
     return {f: np.array(getattr(batch, f), np.int32)
             for f in OpBatch._fields}
+
+
+def _pref(program, f: str) -> np.ndarray:
+    """One field's [D, P] row of the program's packed prefix."""
+    return program["prefix"][PREFIX_FIELDS.index(f)]
 
 
 def run_both(streams, capacity=512):
@@ -207,9 +213,9 @@ def test_cross_client_visible_dependency_shares_a_span():
     program = build_event_graph(_arrays(batch))
     assert program["suffix"] is None
     # ONE span: no chunk_start past lane 0
-    assert program["prefix"]["chunk_start"][0, :3].tolist() == [1, 0, 0]
+    assert _pref(program, "chunk_start")[0, :3].tolist() == [1, 0, 0]
     # the remove covers both in-span events via the host bitmask
-    assert program["prefix"]["ev_cover"][0, 2] == 0b11
+    assert _pref(program, "ev_cover")[0, 2] == 0b11
     seq_tab = apply_window_impl(make_table(1, 64), batch)
     eg_tab = apply_batch_egwalker(make_table(1, 64), batch)
     assert_live_equal(seq_tab, eg_tab, "cross-client span")
@@ -246,7 +252,7 @@ def test_anchor_inside_foreign_event_text_breaks_the_span():
     ]
     batch = _raw(rows)
     program = build_event_graph(_arrays(batch))
-    assert program["prefix"]["chunk_start"][0, :2].tolist() == [1, 1]
+    assert _pref(program, "chunk_start")[0, :2].tolist() == [1, 1]
     assert_live_equal(
         apply_window_impl(make_table(1, 64), batch),
         apply_batch_egwalker(make_table(1, 64), batch),
@@ -273,7 +279,7 @@ def test_open_span_remove_aging_splits_the_event():
     program = build_event_graph(_arrays(batch))
     # the aging boundary no longer breaks the span (only the
     # anchor-inside-event break at w=1 remains)
-    assert program["prefix"]["chunk_start"][0, 2] == 0
+    assert _pref(program, "chunk_start")[0, 2] == 0
     assert program["span_splits"][0] == 1
     assert_live_equal(
         apply_window_impl(make_table(1, 64), batch),
@@ -326,7 +332,7 @@ def test_committed_tombstone_aging_collision_still_breaks():
     ]
     batch = _raw(rows)
     program = build_event_graph(_arrays(batch))
-    assert program["prefix"]["chunk_start"][0, 3] == 1
+    assert _pref(program, "chunk_start")[0, 3] == 1
     seq_tab = apply_window_impl(make_table(1, 64), batch)
     eg_tab = apply_batch_egwalker(make_table(1, 64), batch)
     assert_live_equal(seq_tab, eg_tab, "committed aging")
@@ -352,7 +358,7 @@ def test_remove_heavy_sequential_spans_shrink():
                          min_seq=max(0, seq - 2)))
     batch = _raw(rows)
     program = build_event_graph(_arrays(batch))
-    starts = int(program["prefix"]["chunk_start"][0].sum())
+    starts = int(_pref(program, "chunk_start")[0].sum())
     # 12 ops at EG_K=16: one span, several absorbed aging breaks
     assert starts == 1, starts
     assert program["span_splits"][0] >= 3
@@ -460,7 +466,7 @@ def test_walker_prefix_applies_without_the_convenience_wrapper():
     batch = build_batch([encode_stream(stream)])
     program = build_event_graph(_arrays(batch))
     assert program["suffix"] is None
-    P = program["prefix"]["kind"].shape[1]
+    P = _pref(program, "kind").shape[1]
     eg_tab = apply_window_egwalker(make_table(1, 256), program["prefix"])
     # pad the batch to the prefix bucket so shapes line up
     padded = {f: np.zeros((1, P), np.int32) for f in OpBatch._fields}

@@ -136,7 +136,7 @@ def test_compile_counts_within_ladder_bounds_egwalker_route(sanitizer):
     """The TPU's executor route: a prewarmed egwalker sidecar driven
     through real traffic — including an overflow regrow — compiles at
     most the shapes shapecheck derives per root. The walker jits AND
-    the plain scan jit (the concurrent-suffix program) both stay
+    the route's own scan jit (the concurrent-suffix program) both stay
     within bounds."""
     ladder = BucketLadder(window_floor=16, max_bucket=32)
     sidecar = TpuMergeSidecar(
@@ -204,13 +204,14 @@ def test_prewarm_covers_egwalker_serving_compiles(sanitizer):
 def test_egwalker_bounds_arithmetic():
     """The egwalker route's bound shape: walker roots get the full
     (window bucket x capacity rung) ladder, the suffix rides the
-    PLAIN scan root (never its ping-pong form) — and the scan route
-    pins the egwalker roots to zero."""
+    route's own plain scan root (never a donating form, never the
+    scan route's apply_window) — and the scan route pins the egwalker
+    roots to zero."""
     b = ladder_bounds(16, 32, 16, 64, executor="egwalker")
     shapes = b["egwalker"]
     assert shapes > 0
-    assert b["apply_window"] == shapes
-    assert b["apply_window_pingpong"] == 0
+    assert b["egwalker_suffix"] == shapes
+    assert b["apply_window"] == b["apply_window_pingpong"] == 0
     assert b["egwalker_pingpong"] == 0  # donate off
     donating = ladder_bounds(16, 32, 16, 64, executor="egwalker",
                              donate=True)
@@ -218,6 +219,7 @@ def test_egwalker_bounds_arithmetic():
     assert donating["apply_window_pingpong"] == 0  # suffix stays plain
     cold = ladder_bounds(16, 32, 16, 64, executor="scan")
     assert cold["egwalker"] == cold["egwalker_pingpong"] == 0
+    assert cold["egwalker_suffix"] == 0 and cold["apply_window"] == shapes
     # a POOLED sidecar's pool applies the plain scan on a degenerate
     # mesh whatever the route — the bound must grant the pool's scan
     # programs instead of reading a correctly laddered sidecar as a
@@ -458,31 +460,44 @@ def test_static_signatures_match_eval_shape_pad_capacity(rung):
         "pad_capacity", spec, new_capacity=rung * 2) == _sig_of(out)
 
 
-@pytest.mark.parametrize("rung", RUNGS)
-@pytest.mark.parametrize("bucket", BUCKETS)
-def test_static_signatures_match_eval_shape_egwalker(rung, bucket):
-    """Differential (b) for the walker root: shapecheck's abstract
+def _egwalker_signatures(root: str, rung: int, bucket: int) -> None:
+    """Differential (b) for a walker root: shapecheck's abstract
     (shape, dtype) signature == jax.eval_shape for the egwalker
-    macro-step loop across every rung x bucket."""
-    import jax.numpy as jnp
-
+    program, table in and table out."""
     from fluidframework_tpu.ops.event_graph import (
-        CHUNK_FIELDS,
         EG_K,
-        _chunk_state,
-        _walker_loop,
+        _get_jit,
+        _get_jit_pingpong,
         build_event_graph,
     )
 
-    st = _chunk_state(make_table(4, rung))
-    spec = {f: (tuple(a.shape), str(a.dtype)) for f, a in st.items()}
+    table = make_table(4, rung)
+    spec = _sig_of(table)
     arrays = {f: np.array(getattr(_batch(4, bucket), f), np.int32)
               for f in OpBatch._fields}
     prefix = build_event_graph(arrays)["prefix"]
-    ops_w = {f: jnp.asarray(prefix[f])
-             for f in OpBatch._fields + CHUNK_FIELDS}
-    out = jax.eval_shape(lambda s, o: _walker_loop(s, o, EG_K), st, ops_w)
-    assert infer_kernel_output("egwalker", spec) == _sig_of(out)
+    if root == "egwalker":
+        out = jax.eval_shape(_get_jit(EG_K), table, prefix)
+    else:
+        out = jax.eval_shape(_get_jit_pingpong(EG_K), table, table,
+                             prefix)
+    assert infer_kernel_output(root, spec) == _sig_of(out)
+
+
+@pytest.mark.parametrize("rung", RUNGS)
+@pytest.mark.parametrize("bucket", BUCKETS)
+def test_static_signatures_match_eval_shape_egwalker(rung, bucket):
+    """The plain walker program across every rung x bucket."""
+    _egwalker_signatures("egwalker", rung, bucket)
+
+
+@pytest.mark.parametrize("rung", RUNGS)
+@pytest.mark.parametrize("bucket", BUCKETS)
+def test_static_signatures_match_eval_shape_egwalker_pingpong(rung,
+                                                              bucket):
+    """The ping-pong walker program: the donated dead table has the
+    output's signature."""
+    _egwalker_signatures("egwalker_pingpong", rung, bucket)
 
 
 @pytest.mark.parametrize("rung", RUNGS)

@@ -7,6 +7,7 @@ import builtins
 import functools
 import glob
 import random
+import warnings
 
 import jax
 import jax.numpy as jnp
@@ -24,8 +25,8 @@ from fluidframework_tpu.obs.profiler import SELF_TIME_EVERY
 from fluidframework_tpu.ops import OpBatch, make_table
 from fluidframework_tpu.ops.bucket_ladder import BucketLadder
 from fluidframework_tpu.ops.event_graph import (
-    CHUNK_FIELDS,
     EG_K,
+    PREFIX_FIELDS,
     _chunk_state,
     _gather_ops,
     _walker_step,
@@ -38,7 +39,7 @@ from fluidframework_tpu.ops.merge_kernel import compact
 from fluidframework_tpu.ops.merge_kernel import (
     compiled_window as scan_window,
 )
-from fluidframework_tpu.ops.segment_table import KIND_NOOP
+from fluidframework_tpu.ops.segment_table import KIND_INSERT, KIND_NOOP
 from fluidframework_tpu.protocol.messages import (
     ClientDetail,
     DocumentMessage,
@@ -194,8 +195,7 @@ def test_host_walker_steps_equal_the_device_trip_count(seed):
         if prefix is None:
             assert program["walker_steps"] == 0
             continue
-        ops_w = {f: jnp.asarray(prefix[f])
-                 for f in OpBatch._fields + CHUNK_FIELDS}
+        ops_w = {f: jnp.asarray(a) for f, a in zip(PREFIX_FIELDS, prefix)}
         trips = int(_counted_walker(_chunk_state(table), ops_w, EG_K))
         assert program["walker_steps"] == trips
     assert suffixes >= 1                    # the concurrent round split
@@ -245,7 +245,7 @@ def test_sidecar_stats_advance_through_every_stage():
     assert st["enqueue_s"] > 0.0
     assert st["real_ops"] == real and st["active_docs"] == active
     walker = sum(p["walker_steps"] for p in programs)
-    scan = sum(p["suffix"]["kind"].shape[1] for p in programs
+    scan = sum(p["suffix"].shape[2] for p in programs
                if p["suffix"] is not None)
     assert st["walker_steps"] == walker > 0
     assert st["scan_steps"] == scan > 0
@@ -253,6 +253,161 @@ def test_sidecar_stats_advance_through_every_stage():
     recorded = [f["walker_steps"] for _i, _t, kind, f in sc.flight.events()
                 if kind == "dispatch"]
     assert recorded == [p["walker_steps"] for p in programs]
+
+
+def test_launch_counters_count_the_programs_a_round_enqueues():
+    """One walker program per round with a critical prefix, one scan
+    program per concurrent suffix, one more walker program per regrow
+    re-apply, which sends the round's own packed prefix again."""
+    sc = _egwalker(capacity=32, max_capacity=256)
+    w = _Writers(sc, 8, 3, 13)
+    programs, applied = _recorded(sc)
+    steps = list(_rounds(w)) + [lambda: w.typed(4, 60, 3)]  # a regrow
+    per_round = []
+    for step in steps:
+        step()
+        before = dict(sc.stats)
+        sc.apply()
+        sc.sync()
+        per_round.append(tuple(sc.stats[k] - before[k] for k in (
+            "walker_launches", "suffix_launches")))
+    assert sc.grow_count >= 1
+    walker = [int(p["prefix"] is not None) for p in programs]
+    suffix = [int(p["suffix"] is not None) for p in programs]
+    regrow = [sum(1 for _t, p in applied if p is program) - 1
+              for program in programs]
+    assert per_round == [(wl * (1 + g), sf * (1 + g))
+                         for wl, sf, g in zip(walker, suffix, regrow)]
+    assert sum(suffix) >= 1 and sum(walker) >= 4 and sum(regrow) >= 1
+    # each re-apply sent the very buffer its round packed
+    for program, g in zip(programs, regrow):
+        sent = [p["prefix"] for _t, p in applied if p is program]
+        assert all(x is program["prefix"] for x in sent)
+        assert len(sent) == 1 + g
+
+
+def _compiled_names(fn) -> list:
+    """The jit programs XLA compiles while ``fn`` runs: the
+    backend-compile event, as the benchmark's CompileMeter counts it."""
+    names = []
+
+    def on_compile(event, duration, **kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            names.append(kw.get("fun_name"))
+
+    jax.monitoring.register_event_duration_secs_listener(on_compile)
+    try:
+        fn()
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_compile)
+    return names
+
+
+def _fresh_program(sc, concurrent: bool) -> dict:
+    """A round's program for ``sc``: a few critical inserts, and with
+    ``concurrent`` two writers at one refSeq (a suffix)."""
+    rows = {0: [], 1: []}
+    for k in range(3):
+        rows[0].append(dict(kind=KIND_INSERT, pos1=0, pos2=0, seq=k + 1,
+                            refseq=k, client=0, op_id=k, length=1,
+                            is_marker=0, prop_key=0, prop_val=0,
+                            min_seq=0))
+    if concurrent:
+        for c in (0, 1):
+            rows[1].append(dict(rows[0][0], seq=c + 1, refseq=0,
+                                client=c))
+    arrays = pack_rows(sc.max_docs, rows, bucket_floor=16)
+    program = sc._compile_program(arrays)
+    assert (program["suffix"] is not None) == concurrent
+    return program
+
+
+@pytest.mark.parametrize("form", ["plain", "pingpong"])
+@pytest.mark.parametrize("concurrent", [False, True])
+def test_first_apply_at_a_fresh_shape_compiles_only_its_programs(
+        monkeypatch, form, concurrent):
+    """No eager array op around the walker: the first ``_apply_program``
+    at a table shape nothing else uses compiles the walker program and,
+    with a suffix, the suffix's scan (named as the scan route's window,
+    so traces and the roofline read it as the scan); no slice, squeeze,
+    broadcast or concatenate program. ``pingpong`` takes the chip's
+    path (the donated dead table) with the backend reported as a TPU."""
+    docs = {("plain", False): 3, ("plain", True): 5,
+            ("pingpong", False): 6, ("pingpong", True): 7}[form, concurrent]
+    sc = TpuMergeSidecar(max_docs=docs, capacity=40, executor="egwalker",
+                         donate=False)
+    program = _fresh_program(sc, concurrent)
+    table = make_table(docs, 40)
+    dead = make_table(docs, 40) if form == "pingpong" else None
+    jax.block_until_ready((table, dead))
+    if form == "pingpong":
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with warnings.catch_warnings():
+        warnings.filterwarnings("ignore", "Some donated buffers")
+        names = _compiled_names(
+            lambda: jax.block_until_ready(
+                sc._apply_program(table, program, dead)))
+    walker = "jit(run)" if form == "pingpong" else "jit(<lambda>)"
+    scan = ["jit(apply_window_impl)"] if concurrent else []
+    assert names == [walker] + scan
+
+
+@pytest.mark.parametrize("form", ["plain", "pingpong"])
+def test_prefix_stage_makes_no_implicit_transfer(monkeypatch, form):
+    """The walker stage sends its prefix with one explicit copy: under
+    ``jax.transfer_guard("disallow")`` it runs, and gives the table the
+    unguarded call gives."""
+    sc = TpuMergeSidecar(max_docs=4, capacity=32, executor="egwalker",
+                         donate=False)
+    program = _fresh_program(sc, concurrent=False)
+    table = make_table(4, 32)
+    want = sc._apply_program(table, program)
+    if form == "pingpong":
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    dead = make_table(4, 32) if form == "pingpong" else None
+    with warnings.catch_warnings(), jax.transfer_guard("disallow"):
+        warnings.filterwarnings("ignore", "Some donated buffers")
+        got = sc._apply_program(table, program, dead)
+        jax.block_until_ready(got)
+    for f in want._fields:
+        assert np.array_equal(np.asarray(getattr(want, f)),
+                              np.asarray(getattr(got, f))), f
+
+
+def test_suffix_stage_makes_no_implicit_transfer():
+    """A round with a concurrent suffix sends the packed suffix with one
+    explicit copy too: the whole ``_apply_program`` runs under
+    ``jax.transfer_guard("disallow")`` and gives the unguarded table."""
+    sc = TpuMergeSidecar(max_docs=4, capacity=32, executor="egwalker",
+                         donate=False)
+    program = _fresh_program(sc, concurrent=True)
+    table = make_table(4, 32)
+    want = sc._apply_program(table, program)
+    with jax.transfer_guard("disallow"):
+        got = sc._apply_program(table, program)
+        jax.block_until_ready(got)
+    for f in want._fields:
+        assert np.array_equal(np.asarray(getattr(want, f)),
+                              np.asarray(getattr(got, f))), f
+
+
+def test_suffix_program_is_named_as_the_scan_window():
+    """The egwalker suffix's own jit lowers to a module of the same
+    name as the scan route's window, so a device trace files the
+    suffix's time under the scan."""
+    from fluidframework_tpu.ops.event_graph import _jit_suffix
+    from fluidframework_tpu.ops.merge_kernel import _apply_window_xla
+
+    table = make_table(2, 16)
+    suffix = np.zeros((len(OpBatch._fields), 2, 16), np.int32)
+    suffix[OpBatch._fields.index("kind")] = KIND_NOOP
+
+    def module(lowered) -> str:
+        return lowered.compiler_ir("hlo").as_hlo_module().name
+
+    scan = module(_apply_window_xla.lower(table, OpBatch(*suffix)))
+    assert module(_jit_suffix.lower(table, suffix)) == scan
+    assert scan == "jit_apply_window_impl"
 
 
 def test_scan_route_counts_its_window_as_lanes():

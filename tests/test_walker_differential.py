@@ -9,15 +9,32 @@ bit-for-bit (garbage rows beyond `count` may differ: the walker's row
 merge parks different garbage than the scan's shift). These are the
 route's largest sweeps; tests/test_event_graph.py owns the graph
 semantics and the span compiler's break conditions."""
+import functools
+import warnings
+
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from fluidframework_tpu.ops import build_batch, encode_stream, make_table
 from fluidframework_tpu.ops.event_graph import (
+    EG_K,
+    PREFIX_FIELDS,
+    _chunk_state,
+    _chunk_unstate,
+    _get_jit_pingpong,
+    _walker_loop,
     apply_batch_egwalker,
+    apply_suffix,
+    apply_window_egwalker,
     build_event_graph,
 )
-from fluidframework_tpu.ops.merge_kernel import apply_window_impl
+from fluidframework_tpu.ops.merge_kernel import (
+    apply_window,
+    apply_window_impl,
+    pad_capacity,
+)
 from fluidframework_tpu.ops.segment_table import (
     KIND_INSERT,
     KIND_NOOP,
@@ -152,7 +169,8 @@ def _raw(ops_rows):
 def _spans(rows):
     """The walker's span starts over the window's critical prefix."""
     program = build_event_graph(_raw(rows))
-    return program["prefix"]["chunk_start"][0].tolist()[:len(rows)]
+    starts = program["prefix"][PREFIX_FIELDS.index("chunk_start")]
+    return starts[0].tolist()[:len(rows)]
 
 
 def _run_raw(rows, capacity=64):
@@ -344,3 +362,120 @@ def test_differential_fuzz_deep(steps, seed0):
         streams.append(s)
     seq_tab, eg_tab = run_both(streams, capacity=2048)
     assert_live_equal(seq_tab, eg_tab, f"deep {steps}/{seed0}")
+
+
+# ======================================================================
+# the walker program takes and returns the table: the same tables, bit
+# for bit, as the conversion done op by op around a state-dict program
+
+
+@functools.lru_cache(maxsize=None)
+def _state_walker(K: int):
+    return jax.jit(lambda st, ops: _walker_loop(st, ops, K))
+
+
+def _conversion_path(table, prefix, K=EG_K):
+    """The walker stage as it ran before the conversion moved into the
+    program: eager ``_chunk_state``, the prefix's fields sent one by one,
+    the loop over state dicts, eager ``_chunk_unstate``."""
+    ops_w = {f: jnp.asarray(a) for f, a in zip(PREFIX_FIELDS, prefix)}
+    st = _state_walker(K)(_chunk_state(table), ops_w)
+    return _chunk_unstate(dict(st))
+
+
+def _folded(form: str, table, prefix):
+    if form == "plain":
+        return apply_window_egwalker(table, prefix)
+    # the donating jit itself (on the CPU the wrapper takes the plain
+    # form): a fresh dead table of the output's shapes as fodder
+    dead = make_table(table.docs, table.capacity)
+    with warnings.catch_warnings():
+        warnings.filterwarnings("ignore", "Some donated buffers")
+        return _get_jit_pingpong(EG_K)(dead, table,
+                                       jax.device_put(prefix))
+
+
+def assert_tables_identical(want, got, ctx=""):
+    """Every field of the whole table, rows past ``count`` included."""
+    for f in want._fields:
+        w, g = np.asarray(getattr(want, f)), np.asarray(getattr(got, f))
+        assert w.dtype == g.dtype and w.shape == g.shape, (ctx, f)
+        assert np.array_equal(w, g), f"{ctx}: field {f}"
+
+
+def _windows(streams, width=16):
+    """The streams' ops as consecutive [D, width] rounds."""
+    batch = build_batch([encode_stream(s) for s in streams])
+    full = {f: np.array(getattr(batch, f), np.int32)
+            for f in OpBatch._fields}
+    for w0 in range(0, full["kind"].shape[1], width):
+        yield {f: np.ascontiguousarray(a[:, w0:w0 + width])
+               for f, a in full.items()}
+
+
+def _apply_rounds(form, streams, capacity):
+    """Both paths round by round over the same programs: the walker
+    folded or converted op by op, the suffix's scan fed one packed
+    buffer or twelve arrays; the tables compared after each stage.
+    Returns (rounds with a suffix, regrows)."""
+    D = len(streams)
+    want = got = make_table(D, capacity)
+    head = np.zeros(D, np.int64)
+    suffixes = grows = 0
+    for r, arrays in enumerate(_windows(streams)):
+        program = build_event_graph(arrays, base_head=head)
+        real = arrays["kind"] != KIND_NOOP
+        head = np.maximum(head, np.where(real, arrays["seq"], 0).max(1))
+        prefix, suffix = program["prefix"], program["suffix"]
+        if prefix is not None:
+            assert prefix.dtype == np.int32 and prefix.flags.c_contiguous
+            assert prefix.shape[:2] == (len(PREFIX_FIELDS), D)
+        suffixes += suffix is not None
+        base_w, base_g = want, got
+        while True:
+            want, got = base_w, base_g
+            if prefix is not None:
+                want = _conversion_path(want, prefix)
+                got = _folded(form, got, prefix)
+                assert_tables_identical(want, got, f"{form} round {r}")
+            if suffix is not None:
+                want = apply_window(want, OpBatch(*suffix))
+                got = apply_suffix(got, suffix)
+                assert_tables_identical(want, got, f"{form} suffix {r}")
+            if not np.asarray(want.overflow).any():
+                break
+            # the sidecar's regrow: pad the pre-dispatch snapshot and
+            # re-apply the same program at twice the capacity
+            grows += 1
+            base_w = pad_capacity(base_w, 2 * base_w.capacity)
+            base_g = pad_capacity(base_g, 2 * base_g.capacity)
+    return suffixes, grows
+
+
+def _fuzz_streams(seed, n=6, steps=60):
+    return [record_op_stream(FuzzConfig(
+        n_clients=1 + (seed + i) % 4, n_steps=steps + 8 * i,
+        seed=7000 + 10 * seed + i,
+        insert_weight=0.5, remove_weight=0.25,
+        annotate_weight=0.1, process_weight=0.15,
+    ))[1] for i in range(n)]
+
+
+@pytest.mark.parametrize("form", ["plain", "pingpong"])
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_folded_walker_equals_the_conversion_path(form, seed):
+    """Seeded multi-document rounds, concurrent ones among them (a
+    suffix rides the scan after the walker): the program that takes
+    and returns the table gives the tables the eager conversion gave,
+    every field bit for bit."""
+    suffixes, grows = _apply_rounds(form, _fuzz_streams(seed), 256)
+    assert suffixes >= 1 and grows == 0
+
+
+@pytest.mark.parametrize("form", ["plain", "pingpong"])
+def test_folded_walker_regrow_reapply_equals_the_conversion_path(form):
+    """Rounds that overflow a small slab: the pre-dispatch snapshot is
+    padded and the SAME packed prefix re-applied, as the sidecar's
+    regrow does with its ``_last_program``."""
+    suffixes, grows = _apply_rounds(form, _fuzz_streams(5, 4, 70), 16)
+    assert grows >= 1
