@@ -165,6 +165,8 @@ WALL_CLOCK_SINKS: dict[tuple[str, str], str] = {
     ("service/tpu_sidecar.py", "TpuMergeSidecar._settle"):
         "settle_ms histogram + sidecar:settle trace timestamp (obs "
         "only; never feeds ordering)",
+    ("service/tpu_sidecar.py", "TpuMergeSidecar._place"):
+        "stats place_s counter (obs only; never feeds ordering)",
     ("service/tree_sidecar.py", "TreeSidecar.prewarm"):
         "prewarm returns measured warmup wall seconds (obs only)",
     ("service/tree_sidecar.py", "TreeSidecar._dispatch"):

@@ -50,6 +50,7 @@ import os
 import time
 from typing import Callable, Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -76,6 +77,9 @@ from ..ops import (
 from ..ops.bucket_ladder import BucketLadder
 from ..ops.event_graph import (
     EG_K,
+    _get_jit,
+    _get_jit_pingpong,
+    _packed_scan,
     apply_suffix,
     apply_window_egwalker,
     apply_window_egwalker_pingpong,
@@ -87,7 +91,12 @@ from ..ops.host_bridge import (
     pack_rows as _pack_rows,
     replay_chunked as _replay_chunked,
 )
-from ..ops.merge_kernel import apply_window_pingpong
+from ..ops.merge_kernel import (
+    _pingpong_impl,
+    apply_window_impl,
+    apply_window_pingpong,
+    pad_capacity,
+)
 from ..ops.segment_table import KIND_NOOP
 from ..protocol.messages import MessageType, SequencedMessage
 from ..qos.faults import (
@@ -171,6 +180,13 @@ _SITE_DISPATCH = _CHAOS.site(
 _SITE_POOL_DISPATCH = _CHAOS.site("sidecar.pool_dispatch", (KIND_DEFER,))
 _SITE_POOL_ADMIT = _CHAOS.site("sidecar.pool_admit", (KIND_ERROR,))
 _SITE_POOL_MIGRATE = _CHAOS.site("sidecar.pool_migrate", (KIND_DEFER,))
+
+
+@jax.jit
+def _overflow_any(overflow):
+    """The settle's read on a document mesh: one flag reduced on the
+    devices (a scalar collective), not the [docs] flags gathered."""
+    return jnp.any(overflow != 0)
 
 
 def default_executor() -> str:
@@ -499,11 +515,21 @@ class TpuMergeSidecar:
     envelope stream; ``apply`` flushes accumulated windows to the
     device in a single pipelined dispatch (see the module docstring
     for the pipeline/settle contract).
+
+    ``doc_mesh`` (a ``jax.sharding.Mesh`` with the one axis ``docs``)
+    splits the primary plane by document over its devices, as deli's
+    documents are partitioned: slot ``s`` lives on device ``s // (
+    max_docs / mesh size)``. Every table the sidecar holds and every
+    packed buffer it sends is placed with ``parallel.mesh.doc_sharding``
+    and every program runs each device's own documents (no collective
+    but the settle's one reduced flag). ``None`` keeps the plane on one
+    device.
     """
 
     def __init__(self, max_docs: int = 1024, capacity: int = 1024,
                  compact_every: int = 8, max_capacity: int = 16384,
-                 seq_mesh=None, pool_capacity: Optional[int] = None,
+                 seq_mesh=None, doc_mesh=None,
+                 pool_capacity: Optional[int] = None,
                  pool_route: Optional[str] = None,
                  executor: Optional[str] = None,
                  pipeline: Optional[bool] = None,
@@ -518,6 +544,21 @@ class TpuMergeSidecar:
         self.max_docs = max_docs
         self.capacity = capacity
         self.max_capacity = max_capacity
+        if doc_mesh is not None:
+            from ..parallel.mesh import DOC_AXIS
+
+            if tuple(doc_mesh.axis_names) != (DOC_AXIS,):
+                raise ValueError(
+                    f"doc_mesh needs the one axis {DOC_AXIS!r} (got "
+                    f"{doc_mesh.axis_names})")
+            if max_docs % doc_mesh.size:
+                raise ValueError(
+                    f"max_docs={max_docs} does not split over the "
+                    f"{doc_mesh.size} devices of doc_mesh: each device "
+                    "holds max_docs / mesh size documents, so max_docs "
+                    "must be a multiple of the mesh size")
+        self.doc_mesh = doc_mesh
+        self._shards = 1 if doc_mesh is None else doc_mesh.size
         # per-op trace stamping (sidecar:pack / sidecar:settle hops on
         # the ingested messages' trace lists). OPT-IN: it costs one
         # Python append per op per round on the serving path, so the
@@ -606,7 +647,7 @@ class TpuMergeSidecar:
                 max_capacity=max_capacity,
             )
         self.pool_admit_count = 0
-        self._table = make_table(max_docs, capacity)
+        self._table = self._new_table(capacity)
         self._slots: dict[tuple[str, str, str], int] = {}
         # per-document slot index: ingest is called once per sequenced
         # message per document — scanning every tracked channel there
@@ -662,13 +703,20 @@ class TpuMergeSidecar:
         # egwalker route enqueues (every _apply_program, prewarm's and
         # a regrow re-apply's included): walker_launches (one walker
         # program per critical prefix), suffix_launches (one scan
-        # program per concurrent suffix).
+        # program per concurrent suffix). place_s: on a document mesh,
+        # the packed buffers' device_put inside each _apply_program
+        # (part of enqueue_s; 0 on one device, where the kernels' own
+        # calls copy them). Per device of the document mesh (one entry without one), as
+        # lists replaced each round so a shallow copy of stats is a
+        # snapshot: shard_real_ops, shard_active_docs.
         self.stats = {
             "pack_s": 0.0, "settle_s": 0.0, "rounds": 0,
             "ingest_s": 0.0, "ingest_msgs": 0, "graph_s": 0.0,
             "enqueue_s": 0.0, "compact_s": 0.0, "real_ops": 0,
             "active_docs": 0, "walker_steps": 0, "scan_steps": 0,
             "lane_slots": 0, "walker_launches": 0, "suffix_launches": 0,
+            "place_s": 0.0, "shard_real_ops": [0] * self._shards,
+            "shard_active_docs": [0] * self._shards,
         }
         # device-trace switch as the last apply/sync read it, and the
         # round the in-flight dispatch belongs to (its settle span)
@@ -846,7 +894,7 @@ class TpuMergeSidecar:
         if self._applies % self._compact_every == 0:
             t0 = time.perf_counter()
             with self._span("compact", self.stats["rounds"]):
-                self._table = compact(self._table)
+                self._table = self._compact(self._table)
             self.stats["compact_s"] += time.perf_counter() - t0
         if not self.pipeline:
             self._settle()
@@ -879,8 +927,6 @@ class TpuMergeSidecar:
         processes skip the cost entirely). Warms
         the ACTIVE executor route, including the donated ping-pong
         form when donation is on. Returns seconds spent."""
-        from ..ops.merge_kernel import pad_capacity
-
         t0 = time.perf_counter()
         noop = dict(
             kind=KIND_NOOP, pos1=0, pos2=0, seq=0, refseq=0,
@@ -890,7 +936,7 @@ class TpuMergeSidecar:
         dummy_prev = None
         for rung in BucketLadder.capacity_rungs(
                 self.capacity, self.max_capacity):
-            table = make_table(self.max_docs, rung)
+            table = self._new_table(rung)
             for bucket in self.ladder.window_buckets(max_bucket):
                 arrays = _pack_rows(
                     self.max_docs, {0: [noop]}, bucket_floor=bucket
@@ -900,8 +946,7 @@ class TpuMergeSidecar:
                 # is a distinct program per window shape, and steady
                 # serving dispatches through it — every rung x bucket
                 # must compile here, not mid-serve
-                dead = (make_table(self.max_docs, rung)
-                        if self.donate else None)
+                dead = self._new_table(rung) if self.donate else None
                 table = self._apply_program(table, program, dead)
                 if self.executor == "egwalker":
                     # the egwalker route's concurrent SUFFIX rides its
@@ -914,12 +959,14 @@ class TpuMergeSidecar:
                     table = self._apply_program(table, {
                         "egwalker": True, "prefix": None,
                         "suffix": suffix})
-            table = compact(table)
+            table = self._compact(table)
             if dummy_prev is not None:
-                pad_capacity(dummy_prev, rung)
+                self._pad(dummy_prev, rung)
             dummy_prev = table
         if self._pool is not None:
             self._warm_pool()
+        if self.doc_mesh is not None:
+            _overflow_any(table.overflow)  # the settle's read
         np.asarray(table.count)  # force completion
         return time.perf_counter() - t0
 
@@ -929,6 +976,47 @@ class TpuMergeSidecar:
         reached through the attribute-held pool, so both edges are
         declared in shapecheck.PREWARM_INDIRECT."""
         self._pool.prewarm()
+
+    def _on_mesh(self, fn, arg_axes: tuple, donate_argnums: tuple = (),
+                  static: tuple = ()):
+        """A kernel's function (or its jit's) as one program over the
+        documents of ``doc_mesh`` (``parallel.mesh.doc_program``): the
+        sidecar is the one place that maps the kernels onto the mesh."""
+        from ..parallel.mesh import doc_program
+
+        return doc_program(getattr(fn, "__wrapped__", fn), self.doc_mesh,
+                           arg_axes, donate_argnums, static)
+
+    def _new_table(self, capacity: int):
+        """A fresh table of the plane: on one device, or made split by
+        document on the mesh (never whole on one device first)."""
+        if self.doc_mesh is None:
+            return make_table(self.max_docs, capacity)
+        return self._on_mesh(make_table, (), static=(
+            self.max_docs // self._shards, capacity))()
+
+    def _compact(self, table):
+        if self.doc_mesh is None:
+            return compact(table)
+        return self._on_mesh(compact, (0,))(table)
+
+    def _pad(self, table, capacity: int):
+        if self.doc_mesh is None:
+            return pad_capacity(table, capacity)
+        return self._on_mesh(pad_capacity, (0,), static=(capacity,))(table)
+
+    def _place(self, buffers, axis: int):
+        """One device_put of a packed buffer, split by document over
+        the mesh along ``axis``: a ``sidecar:place`` span of the round
+        and ``stats["place_s"]``."""
+        from ..parallel.mesh import doc_sharding
+
+        t0 = time.perf_counter()
+        with self._span("place", self.stats["rounds"]):
+            placed = jax.device_put(buffers,
+                                    doc_sharding(self.doc_mesh, axis))
+        self.stats["place_s"] += time.perf_counter() - t0
+        return placed
 
     def _compile_program(self, arrays: dict, base_head=None) -> dict:
         """Host half of one dispatch: raw packed arrays for the scan
@@ -949,6 +1037,8 @@ class TpuMergeSidecar:
             dead.capacity != table.capacity or dead.docs != table.docs
         ):
             dead = None  # shape changed (regrow): fodder is useless
+        if self.doc_mesh is not None:
+            return self._apply_on_mesh(table, program, dead)
         if not program.get("egwalker"):
             batch = OpBatch(**{f: program[f] for f in OpBatch._fields})
             if dead is not None:
@@ -973,6 +1063,35 @@ class TpuMergeSidecar:
         if program["suffix"] is not None:
             self.stats["suffix_launches"] += 1
             table = apply_suffix(table, program["suffix"])
+        return table
+
+    def _apply_on_mesh(self, table, program: dict, dead=None):
+        """``_apply_program`` on the document mesh: the same kernels,
+        in the same order (a buffer is placed just before the program
+        that reads it), each run by every device on its own documents.
+        As on one device, the CPU takes the plain forms (it ignores
+        donation)."""
+        if jax.default_backend() == "cpu":
+            dead = None
+        if not program.get("egwalker"):
+            batch = self._place(
+                OpBatch(**{f: program[f] for f in OpBatch._fields}), 0)
+            if dead is not None:
+                return self._on_mesh(_pingpong_impl, (0, 0, 0), (0,))(
+                    dead, table, batch)
+            return self._on_mesh(apply_window_impl, (0, 0))(table, batch)
+        if program["prefix"] is not None:
+            self.stats["walker_launches"] += 1
+            prefix = self._place(program["prefix"], 1)
+            if dead is not None:
+                table = self._on_mesh(_get_jit_pingpong(EG_K), (0, 0, 1),
+                                      (0,))(dead, table, prefix)
+            else:
+                table = self._on_mesh(_get_jit(EG_K), (0, 1))(table, prefix)
+        if program["suffix"] is not None:
+            self.stats["suffix_launches"] += 1
+            suffix = self._place(program["suffix"], 1)
+            table = self._on_mesh(_packed_scan, (0, 1))(table, suffix)
         return table
 
     def _dispatch(self) -> int:
@@ -1077,6 +1196,19 @@ class TpuMergeSidecar:
         stats["walker_steps"] += walker
         stats["scan_steps"] += scan
         stats["lane_slots"] += docs * (walker * EG_K + scan)
+        if self.doc_mesh is None:
+            shard_real, shard_active = [real], [active]
+        else:
+            # real ops per document, then per device of the mesh
+            per_doc = np.count_nonzero(real_lanes, axis=1).reshape(
+                self._shards, -1)
+            shard_real = per_doc.sum(axis=1)
+            shard_active = np.count_nonzero(per_doc, axis=1)
+        stats["shard_real_ops"] = [
+            a + int(b) for a, b in zip(stats["shard_real_ops"], shard_real)]
+        stats["shard_active_docs"] = [
+            a + int(b)
+            for a, b in zip(stats["shard_active_docs"], shard_active)]
         _M_ROUNDS.inc()
         _M_OPS.inc(real + pool_real)
         _M_PACK_MS.observe(pack_s * 1000.0)
@@ -1104,7 +1236,7 @@ class TpuMergeSidecar:
             # compiles that one (per rung x bucket), and falling back
             # to the never-warmed plain program here would hit a
             # 20-40s serve-time compile on the real chip
-            dead = make_table(self.max_docs, self.capacity)
+            dead = self._new_table(self.capacity)
         # free pre-dispatch snapshot (immutable arrays): if this window
         # overflows, recovery pads THIS table and re-applies THIS
         # window instead of re-replaying history
@@ -1146,7 +1278,7 @@ class TpuMergeSidecar:
             self._unsettled = False
             with self._span("settle", self._inflight_round):
                 t0 = time.perf_counter()
-                overflowed = bool(np.asarray(self._table.overflow).any())
+                overflowed = self._overflow_set()
                 settle_s = time.perf_counter() - t0
                 self.stats["settle_s"] += settle_s
                 _M_SETTLE_MS.observe(settle_s * 1000.0)
@@ -1236,25 +1368,20 @@ class TpuMergeSidecar:
         this is exact; with ``prewarm`` the
         new-capacity shapes are already compiled and a warm regrow
         costs about one steady apply."""
-        from ..ops.merge_kernel import pad_capacity
-
         self.grow_count += 1
         _M_GROW.inc()
         self.capacity = new_capacity
         _M_CAPACITY.set(new_capacity)
         self.flight.record("recover-grow", capacity=new_capacity)
         if self._prev_table is None:  # pragma: no cover - first flush
-            self._prev_table = make_table(self.max_docs, new_capacity)
+            self._prev_table = self._new_table(new_capacity)
         else:
-            self._prev_table = pad_capacity(
-                self._prev_table, new_capacity
-            )
+            self._prev_table = self._pad(self._prev_table, new_capacity)
         # fresh fodder at the NEW capacity: the re-apply must ride the
         # same (prewarmed) ping-pong program the steady path uses
         self._table = self._apply_program(
             self._prev_table, self._last_program,
-            make_table(self.max_docs, new_capacity)
-            if self.donate else None,
+            self._new_table(new_capacity) if self.donate else None,
         )
 
     def _retire_rows(self, slots: list) -> None:
@@ -1270,9 +1397,14 @@ class TpuMergeSidecar:
         for slot in slots:
             count[slot] = 0
             overflow[slot] = 0
-        self._table = self._table._replace(
-            count=jnp.asarray(count), overflow=jnp.asarray(overflow),
-        )
+        if self.doc_mesh is None:
+            count, overflow = jnp.asarray(count), jnp.asarray(overflow)
+        else:
+            from ..parallel.mesh import doc_sharding
+
+            count, overflow = jax.device_put(
+                (count, overflow), doc_sharding(self.doc_mesh))
+        self._table = self._table._replace(count=count, overflow=overflow)
 
     def _admit_to_pool(self, slots: list) -> list:
         """Move slots to the sequence-sharded pool; retire their
@@ -1400,4 +1532,12 @@ class TpuMergeSidecar:
         """True only if a document is CURRENTLY wrong (should never
         happen: recovery runs inside the settle boundary)."""
         self._settle()
-        return bool(np.asarray(self._table.overflow).any())
+        return self._overflow_set()
+
+    def _overflow_set(self) -> bool:
+        """Whether any document's overflow flag is set: the [docs]
+        flags read from the one device, or on a mesh one flag reduced
+        on the devices."""
+        if self.doc_mesh is None:
+            return bool(np.asarray(self._table.overflow).any())
+        return bool(_overflow_any(self._table.overflow))

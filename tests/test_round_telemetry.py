@@ -322,23 +322,32 @@ def _fresh_program(sc, concurrent: bool) -> dict:
     return program
 
 
+@pytest.mark.parametrize("sharded", [False, True])
 @pytest.mark.parametrize("form", ["plain", "pingpong"])
 @pytest.mark.parametrize("concurrent", [False, True])
 def test_first_apply_at_a_fresh_shape_compiles_only_its_programs(
-        monkeypatch, form, concurrent):
+        monkeypatch, form, concurrent, sharded):
     """No eager array op around the walker: the first ``_apply_program``
     at a table shape nothing else uses compiles the walker program and,
     with a suffix, the suffix's scan (named as the scan route's window,
     so traces and the roofline read it as the scan); no slice, squeeze,
     broadcast or concatenate program. ``pingpong`` takes the chip's
-    path (the donated dead table) with the backend reported as a TPU."""
+    path (the donated dead table) with the backend reported as a TPU.
+    ``sharded``: the plane split by document over 4 devices compiles
+    the same programs under the same names, and no resharding one."""
     docs = {("plain", False): 3, ("plain", True): 5,
             ("pingpong", False): 6, ("pingpong", True): 7}[form, concurrent]
+    mesh = None
+    if sharded:
+        from fluidframework_tpu.parallel.mesh import make_mesh
+
+        docs *= 4
+        mesh = make_mesh(jax.devices()[:4])
     sc = TpuMergeSidecar(max_docs=docs, capacity=40, executor="egwalker",
-                         donate=False)
+                         donate=False, doc_mesh=mesh)
     program = _fresh_program(sc, concurrent)
-    table = make_table(docs, 40)
-    dead = make_table(docs, 40) if form == "pingpong" else None
+    table = sc._new_table(40)
+    dead = sc._new_table(40) if form == "pingpong" else None
     jax.block_until_ready((table, dead))
     if form == "pingpong":
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")

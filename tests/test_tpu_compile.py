@@ -156,3 +156,52 @@ def test_seq_pool_program_compiles_with_its_collectives(quad):
     # all-reduce too) and the ppermute boundary exchange
     assert "all-reduce" in hlo
     assert "collective-permute" in hlo
+
+
+def test_doc_sharded_plane_compiles_on_four_chips(quad):
+    """The sidecar's primary plane split by document over a v5e-4: the
+    donated walker, the suffix scan and compaction each compile with no
+    collective (every chip runs its own documents)."""
+    from fluidframework_tpu.ops import event_graph
+    from fluidframework_tpu.ops.merge_kernel import compact
+    from fluidframework_tpu.parallel.mesh import DOC_AXIS, doc_program
+
+    mesh = Mesh(np.array(quad), (DOC_AXIS,))
+    table, arrays = _window(64, 1024, 16)
+    program = event_graph.build_event_graph(arrays, k_max=event_graph.EG_K)
+    prefix = jax.ShapeDtypeStruct(program["prefix"].shape, jnp.int32)
+    suffix = jax.ShapeDtypeStruct(
+        (len(OpBatch._fields), 64, 16), jnp.int32)
+    walker = event_graph._get_jit_pingpong(event_graph.EG_K).__wrapped__
+    for jitted, args in (
+            (doc_program(walker, mesh, (0, 0, 1), (0,)),
+             (table, table, prefix)),
+            (doc_program(event_graph._packed_scan, mesh, (0, 1)),
+             (table, suffix)),
+            (doc_program(compact.__wrapped__, mesh, (0,)), (table,))):
+        hlo = jitted.lower(*args).compile().as_text()
+        for op in ("all-gather", "all-to-all", "collective-permute",
+                   "all-reduce"):
+            assert op not in hlo, op
+
+
+def test_doc_sharded_pingpong_aliases_the_dead_table(quad):
+    """The donated walker on a v5e-4 document mesh writes its output
+    into the dead table's buffers: the compiled program aliases a whole
+    table's bytes per chip, though its body never reads that table."""
+    from fluidframework_tpu.ops import event_graph
+    from fluidframework_tpu.parallel.mesh import DOC_AXIS, doc_program
+
+    mesh = Mesh(np.array(quad), (DOC_AXIS,))
+    table, arrays = _window(64, 1024, 16)
+    program = event_graph.build_event_graph(arrays, k_max=event_graph.EG_K)
+    prefix = jax.ShapeDtypeStruct(program["prefix"].shape, jnp.int32)
+    walker = event_graph._get_jit_pingpong(event_graph.EG_K).__wrapped__
+    memory = doc_program(walker, mesh, (0, 0, 1), (0,)).lower(
+        table, table, prefix).compile().memory_analysis()
+    # the chip pads the [docs] fields to its tiles: a little over the
+    # table's logical bytes, never more than the output
+    per_chip = sum(a.size * a.dtype.itemsize
+                   for a in jax.tree_util.tree_leaves(table)) // 4
+    assert per_chip <= memory.alias_size_in_bytes \
+        <= memory.output_size_in_bytes
